@@ -13,99 +13,6 @@ for the component-by-component mapping.
 
 import logging
 
-import jax as _jax
-
-if not hasattr(_jax.lax, "axis_size"):
-    # The container's jax (0.4.37) predates jax.lax.axis_size; the tree,
-    # its examples and tests call it pervasively inside shard_map bodies.
-    # psum of a Python scalar is statically resolved to value*axis_size
-    # (no collective is emitted), which is exactly axis_size's semantics
-    # — including raising NameError outside a bound axis context.
-    def _axis_size(axis_name):
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size
-
-if not hasattr(_jax, "shard_map"):
-    # jax.shard_map was promoted out of jax.experimental after 0.4.37;
-    # every caller here uses keyword mesh/in_specs/out_specs, which the
-    # experimental entry point accepts identically. The promotion also
-    # renamed check_rep -> check_vma (the rep tracker became the vma
-    # type system); translate so post-rename callers run unchanged.
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @_functools.wraps(_shard_map)
-    def _shard_map_compat(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "pvary"):
-    # pvary annotates varying-over-mesh-axes types for the post-0.4.37
-    # check_vma system; under pre-vma jax the value is unchanged and the
-    # annotation has no checker to feed, so identity is the exact analog.
-    _jax.lax.pvary = lambda x, axis_names=(): x
-
-if not hasattr(_jax.sharding, "set_mesh"):
-    # jax.sharding.set_mesh became public after 0.4.37. Its two effects —
-    # binding the abstract mesh (so bare-PartitionSpec sharding
-    # constraints and get_abstract_mesh resolve) and binding the concrete
-    # mesh for dispatch — map onto 0.4.37's internal set_abstract_mesh
-    # plus the classic `with mesh:` thread-resources context. The
-    # internal helper's sharding_in_types flip is deliberately NOT
-    # replicated: 0.4.37's sharding-in-types was pre-release and changes
-    # unrelated jit semantics.
-    import contextlib as _contextlib
-
-    try:
-        from jax._src.mesh import set_abstract_mesh as _set_abstract_mesh
-    except ImportError:  # pragma: no cover - future jax without this path
-        _set_abstract_mesh = None
-
-    @_contextlib.contextmanager
-    def _set_mesh(mesh):
-        if mesh is None:
-            yield None
-            return
-        with _contextlib.ExitStack() as stack:
-            abstract = getattr(mesh, "abstract_mesh", None)
-            if _set_abstract_mesh is not None and abstract is not None:
-                stack.enter_context(_set_abstract_mesh(abstract))
-            stack.enter_context(mesh)
-            yield mesh
-
-    _jax.sharding.set_mesh = _set_mesh
-
-if not hasattr(_jax, "typeof"):
-    # jax.typeof (the public aval reader, post-0.4.37) is how vma-aware
-    # code asks "which mesh axes does this value vary over". 0.4.37
-    # avals carry no .vma, so callers written as
-    # getattr(jax.typeof(x), "vma", frozenset()) degrade to "invariant"
-    # — the right answer under pre-vma shard_map, where replicated-param
-    # grads arrive already psummed. Without the shim those callers
-    # (parallel.distributed.sync_autodiff_gradients and friends) die on
-    # AttributeError instead.
-    def _typeof(x):
-        import jax.core as _core
-
-        return _core.get_aval(x)
-
-    _jax.typeof = _typeof
-
-if not hasattr(_jax.sharding, "get_abstract_mesh"):
-    # Public alias for the internal reader the set_mesh shim feeds; the
-    # tensor-parallel activation-sharding hints consult it.
-    try:
-        from jax._src.mesh import get_abstract_mesh as _get_abstract_mesh
-    except ImportError:  # pragma: no cover
-        _get_abstract_mesh = None
-    if _get_abstract_mesh is not None:
-        _jax.sharding.get_abstract_mesh = _get_abstract_mesh
-
 
 class RankInfoFormatter(logging.Formatter):
     """ref apex/__init__.py:28 — logging formatter injecting the current

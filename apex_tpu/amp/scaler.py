@@ -21,22 +21,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-
-def _promote_varying(x, axes):
-    """Mark ``x`` varying over the mesh axes in ``axes`` it isn't already
-    (no-op outside shard_map / for already-varying values), with the
-    pcast→pvary fallback for older jax."""
-    try:
-        have = getattr(jax.typeof(x), "vma", frozenset()) or frozenset()
-    except Exception:
-        have = frozenset()
-    missing = tuple(sorted(set(axes) - set(have)))
-    if not missing:
-        return x
-    try:
-        return jax.lax.pcast(x, missing, to="varying")
-    except (AttributeError, TypeError):
-        return jax.lax.pvary(x, missing)
+from apex_tpu.ops.vma import to_varying
 
 
 class LossScaleState(NamedTuple):
@@ -564,8 +549,8 @@ class Fp8DelayedScaler:
         grad = ctx.grad_amax()
         if reduce_axes:
             axes = tuple(reduce_axes)
-            fwd = jax.lax.pmax(_promote_varying(fwd, axes), axes)
-            grad = jax.lax.pmax(_promote_varying(grad, axes), axes)
+            fwd = jax.lax.pmax(to_varying(fwd, axes), axes)
+            grad = jax.lax.pmax(to_varying(grad, axes), axes)
         return Fp8ScalingState(
             fwd=self.fwd_history.update(state.fwd, fwd),
             grad=self.grad_history.update(state.grad, grad),
@@ -618,8 +603,8 @@ def scaled_update(tx, scaler: LossScaler, grads, opt_state, params,
     """
     unscaled, overflow = scaler.unscale(grads, scaler_state)
     if overflow_reduce_axes:
-        ovf = _promote_varying(overflow.astype(jnp.float32),
-                               overflow_reduce_axes)
+        ovf = to_varying(overflow.astype(jnp.float32),
+                         overflow_reduce_axes)
         overflow = jax.lax.psum(ovf, tuple(overflow_reduce_axes)) > 0
 
     def do_update(_):
@@ -629,13 +614,12 @@ def scaled_update(tx, scaler: LossScaler, grads, opt_state, params,
     # branch's zeros from the update branch's output shapes/dtypes (updates
     # may be in grad dtype while params are in model dtype). Under
     # shard_map the update branch's avals can be VARYING over mesh axes
-    # (e.g. grads a custom_vjp kernel left per-device local) — match each
-    # leaf's vma or lax.cond rejects the branches with a type error.
+    # (e.g. tp-sharded parameters) — match each leaf's vma or lax.cond
+    # rejects the branches with a type error.
     out_shapes = jax.eval_shape(do_update, None)
 
     def _match_vma(x, sd):
-        return _promote_varying(x, getattr(sd, "vma", frozenset())
-                                or frozenset())
+        return to_varying(x, sd.vma or ())
 
     def skip(_):
         zeros = jax.tree_util.tree_map(

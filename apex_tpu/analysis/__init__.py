@@ -35,9 +35,9 @@ Two engines, one CLI, one pytest gate:
   live-range-upcast, and offload-candidate, plus the calibrated HBM
   priors (``hbm_priors.json``) the planner prunes on.
 - **AST engine** (:mod:`.ast_checks`): lint driver code (apex_tpu,
-  examples/, tools/, bench.py) for host-sync anti-patterns — the
-  ``block_until_ready``-as-timing bug that produced r5's impossible
-  MFU=330, host pulls and Python RNG inside jit, mutable defaults.
+  examples/, tools/, bench.py, chip_smoke.py) for host-sync
+  anti-patterns — hand-rolled timed regions, host pulls and Python RNG
+  inside jit, mutable defaults.
 
 CLI: ``python -m apex_tpu.analysis`` (see :mod:`.cli`). Gate:
 ``tools/lint.sh`` + ``tests/run_analysis/`` with a checked-in baseline.

@@ -53,7 +53,7 @@ __all__ = ["Lattice", "LatticeRun", "MeshCtx", "NFVal",
 
 # Call-like primitives whose bodies run in the caller's value world.
 CALL_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "custom_jvp_call",
+    "jit", "closed_call", "core_call", "custom_jvp_call",
     "custom_vjp_call", "custom_vjp_call_jaxpr", "remat", "checkpoint",
 })
 
@@ -61,12 +61,12 @@ _SUB_JAXPR_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr")
 
 
 def is_var(v):
-    import jax.core as core
+    import jax.extend.core as core
     return isinstance(v, core.Var)
 
 
 def closed_jaxprs_in(value):
-    import jax.core as core
+    import jax.extend.core as core
     out = []
     if isinstance(value, (core.ClosedJaxpr, core.Jaxpr)):
         out.append(value)
@@ -77,12 +77,12 @@ def closed_jaxprs_in(value):
 
 
 def jaxpr_of(obj):
-    import jax.core as core
+    import jax.extend.core as core
     return obj.jaxpr if isinstance(obj, core.ClosedJaxpr) else obj
 
 
 def consts_of(obj):
-    import jax.core as core
+    import jax.extend.core as core
     return obj.consts if isinstance(obj, core.ClosedJaxpr) else ()
 
 
@@ -127,6 +127,20 @@ class MeshCtx:
         for _prim, axes in self.control:
             out |= axes
         return out
+
+
+def shard_map_names(eqn, which: str) -> tuple:
+    """A shard_map equation's ``in_specs`` / ``out_specs`` (``which`` =
+    "in" / "out") as one ``{dim: (axis, ...)}`` dict per operand — the
+    form every lattice here consumes."""
+    out = []
+    for spec in eqn.params.get(f"{which}_specs", ()):
+        names = {}
+        for dim, entry in enumerate(spec):
+            if entry is not None:
+                names[dim] = entry if isinstance(entry, tuple) else (entry,)
+        out.append(names)
+    return tuple(out)
 
 
 def shard_map_axis_sizes(eqn) -> dict:

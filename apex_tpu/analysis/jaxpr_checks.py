@@ -37,13 +37,14 @@ JAXPR_CHECKS = ("donation", "recompile", "collective-axis", "pallas-block")
 # Call-like primitives inlined for the donation liveness walk: their
 # bodies execute in the caller's buffer world, so reads inside them are
 # reads of the caller's (possibly donated) buffers.
-_INLINE_PRIMS = {"pjit", "closed_call", "core_call", "custom_jvp_call",
+_INLINE_PRIMS = {"jit", "closed_call", "core_call", "custom_jvp_call",
                  "custom_vjp_call", "custom_vjp_call_jaxpr", "remat",
                  "checkpoint"}
 
 # Collective primitives and the param carrying their axis name(s).
 _COLLECTIVE_AXIS_PARAMS = {
-    "psum": "axes", "psum2": "axes", "pmin": "axes", "pmax": "axes",
+    "psum": "axes", "psum_invariant": "axes", "pmin": "axes",
+    "pmax": "axes",
     "ppermute": "axis_name", "pbroadcast": "axes",
     "all_gather": "axis_name", "all_gather_invariant": "axis_name",
     "all_to_all": "axis_name", "reduce_scatter": "axis_name",
@@ -58,7 +59,7 @@ _LANE = 128
 
 def _closed_jaxprs_in(value):
     """Jaxpr-like objects inside an eqn param value."""
-    import jax.core as core
+    import jax.extend.core as core
     out = []
     if isinstance(value, core.ClosedJaxpr):
         out.append(value.jaxpr)
@@ -77,7 +78,7 @@ def _canon(env, v):
 
 
 def _is_var(v):
-    import jax.core as core
+    import jax.extend.core as core
     return isinstance(v, core.Var)
 
 
@@ -348,10 +349,12 @@ def check_pallas_blocks(closed, name, path, vmem_bytes=None):
         kernel = str(eqn.params.get("name_and_src_info", "pallas kernel"))
         resident = 0
         for bm in gm.block_mappings:
-            sd = bm.array_shape_dtype
+            sd = bm.array_aval
             dtype = np.dtype(sd.dtype)
-            block = tuple(bm.block_shape)
-            idims = [d for d in block if isinstance(d, int)]
+            # block dims are Blocked(block_size=n) entries; squeezed
+            # dims carry no size and take no room in the block
+            idims = [d.block_size for d in bm.block_shape
+                     if hasattr(d, "block_size")]
             resident += math.prod(idims or [1]) * dtype.itemsize
             if len(idims) < 2:
                 continue  # scalar/1D blocks: no (sublane, lane) tiling

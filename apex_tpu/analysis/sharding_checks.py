@@ -281,7 +281,7 @@ def _visit_dead_collective(ctx, eqn, ins, outs, mctx):
         f"device already holds the result"
         + (" — psum of a constant is just a scaled copy; use "
            "jax.lax.axis_size for size probes"
-           if prim in ("psum", "psum2") else "")
+           if prim in ("psum", "psum_invariant") else "")
         + ", drop the collective or compute it locally",
         dedup_key=(prim, tuple(axes)))
 

@@ -76,7 +76,8 @@ _PRESERVE_PRIMS = frozenset({
 # allreduce (reduce-scatter + all-gather): 2(n-1)/n. all_gather
 # receives the other n-1 shards. ppermute moves the whole block once.
 COLLECTIVE_PRIMS = {
-    "psum": "axes", "psum2": "axes", "pmin": "axes", "pmax": "axes",
+    "psum": "axes", "psum_invariant": "axes", "pmin": "axes",
+    "pmax": "axes",
     "all_gather": "axis_name", "all_gather_invariant": "axis_name",
     "all_to_all": "axis_name", "reduce_scatter": "axis_name",
     "psum_scatter": "axis_name", "ppermute": "axis_name",
@@ -177,7 +178,7 @@ def collective_bytes(prim: str, nbytes: int, axis_sizes) -> int:
         n *= max(1, int(s))
     if n <= 1:
         return 0
-    if prim in ("psum", "psum2", "pmin", "pmax"):
+    if prim in ("psum", "psum_invariant", "pmin", "pmax"):
         return int(2 * nbytes * (n - 1) / n)
     if prim in ("all_gather", "all_gather_invariant"):
         return nbytes * (n - 1)
@@ -368,13 +369,13 @@ def _transfer(eqn, ins, out_avals, ctx: MeshCtx):
                               from_axis_index=frozenset({axis}))
                      for _ in out_avals)
 
-    if prim in ("psum", "psum2", "pmin", "pmax"):
+    if prim in ("psum", "psum_invariant", "pmin", "pmax"):
         axes = frozenset(_axis_names_of(eqn.params.get("axes")))
         base = _join(ins, out_avals[0])
         return tuple(base.with_(
             pending=base.pending - axes,
             distinct=base.distinct - axes,
-            psum_axes=axes if prim in ("psum", "psum2") else frozenset(),
+            psum_axes=axes if prim in ("psum", "psum_invariant") else frozenset(),
             from_axis_index=frozenset(),
         ) for _ in out_avals)
 
@@ -537,7 +538,7 @@ class ShardingLattice(interp.Lattice):
         return val
 
     def shard_map_enter(self, eqn, ins, sub, ctx):
-        in_names = eqn.params.get("in_names", ())
+        in_names = interp.shard_map_names(eqn, "in")
         mapped = []
         for i, var in enumerate(sub.invars):
             ndim = len(getattr(var.aval, "shape", ()) or ())
@@ -553,7 +554,7 @@ class ShardingLattice(interp.Lattice):
         return mapped
 
     def shard_map_exit(self, eqn, inner_outs, ctx):
-        out_names = eqn.params.get("out_names", ())
+        out_names = interp.shard_map_names(eqn, "out")
         outs = []
         for i, var in enumerate(eqn.outvars):
             ndim = len(getattr(var.aval, "shape", ()) or ())
@@ -765,7 +766,7 @@ def compute_liveness(closed, in_vals, donated=frozenset(),
         ins = tuple(vals.get(r) if r is not None else None
                     for r in reads)
         if prim == "shard_map":
-            out_names = eqn.params.get("out_names", ())
+            out_names = interp.shard_map_names(eqn, "out")
             outs = []
             for k, ov in enumerate(eqn.outvars):
                 ndim = len(getattr(ov.aval, "shape", ()) or ())

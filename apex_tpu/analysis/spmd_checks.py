@@ -101,7 +101,7 @@ SPMD_CHECKS = (
 #: collectives that make their output IDENTICAL across the ridden axes
 #: (every rank holds the same reduced/gathered result)
 _REDUCING_COLLECTIVES = frozenset({
-    "psum", "psum2", "pmin", "pmax", "all_gather",
+    "psum", "psum_invariant", "pmin", "pmax", "all_gather",
     "all_gather_invariant",
 })
 
@@ -279,7 +279,7 @@ class RankConsistencyLattice(interp.Lattice):
     # ---- shard_map boundary -----------------------------------------
 
     def shard_map_enter(self, eqn, ins, sub, ctx):
-        in_names = eqn.params.get("in_names", ())
+        in_names = interp.shard_map_names(eqn, "in")
         sizes = interp.shard_map_axis_sizes(eqn)
         mapped = []
         for i, _var in enumerate(sub.invars):
@@ -298,7 +298,7 @@ class RankConsistencyLattice(interp.Lattice):
         return mapped
 
     def shard_map_exit(self, eqn, inner_outs, ctx):
-        out_names = eqn.params.get("out_names", ())
+        out_names = interp.shard_map_names(eqn, "out")
         mesh_axes = frozenset(interp.shard_map_axis_sizes(eqn))
         outs = []
         for i, _var in enumerate(eqn.outvars):

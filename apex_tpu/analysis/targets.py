@@ -173,7 +173,7 @@ def _tp_collectives():
     mesh — the collective-axis check's first customer."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from apex_tpu.transformer import parallel_state
@@ -1347,7 +1347,7 @@ def _spmd_llama_o4_step():
 def _spmd_simple_distributed():
     """examples/simple_distributed.py's own train step (the satellite:
     the example now does its DDP reduction explicitly under
-    check_rep=False, and THIS target is what keeps that pmean in
+    check_vma=False, and THIS target is what keeps that pmean in
     place — remove it and tier-1 fails as a rank-divergent-update)."""
     import os
     import sys

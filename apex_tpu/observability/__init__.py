@@ -3,14 +3,14 @@
 The single layer the whole stack reports through:
 
 - :mod:`~apex_tpu.observability.registry` — thread-safe metrics
-  (counter/gauge/histogram/corrected-sync timer), structured events,
+  (counter/gauge/histogram/device-synced timer), structured events,
   JSONL export and the merge/summary reader;
 - :mod:`~apex_tpu.observability.scope` — named trace scopes on both the
   host (``TraceAnnotation``) and device (``named_scope`` → HLO metadata)
   timelines, wired into the pipeline/tensor-parallel/DDP/optimizer hot
   paths;
 - :mod:`~apex_tpu.observability.recompile` — runtime compile/retrace
-  accounting via ``jax.monitoring`` + ``jax_log_compiles``, with a
+  accounting via ``jax.monitoring`` compile events, with a
   budget guard that fails a run on steady-state retraces;
 - :mod:`~apex_tpu.observability.step_report` — per-training-step
   records (step time, tokens/s, MFU, loss scale, overflow count);

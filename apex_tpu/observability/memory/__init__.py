@@ -34,8 +34,7 @@ Consumers: ``StepReporter`` records carry a ``memory`` block, flight
 records grow a ``memory`` section, ``pallas_config.device_hbm_bytes``
 prefers the live ``bytes_limit``, bench.py emits the ``memory`` JSON
 object (snapshot cadence derived to keep overhead <2% of step time),
-``examples/llama_train.py`` runs the monitor, and
-``tools/relay_hunter.py`` persists a real-TPU calibration snapshot.
+and ``examples/llama_train.py`` runs the monitor.
 Docs: ``docs/observability.md`` ("Memory telemetry").
 
 This package (plus ``ops/pallas_config.py``) is the sanctioned home of

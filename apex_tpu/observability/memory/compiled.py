@@ -8,13 +8,13 @@ read it. :class:`CompiledMemoryCapture` hooks the PR 2 recompile
 listener so every jitted-fn compile records that static view into the
 registry:
 
-- the listener's per-function ``jax_log_compiles`` record fires at
-  compile *start* (name known, executable not yet built) and the
-  ``jax.monitoring`` backend-compile duration event fires *after* the
-  executable exists — the capture remembers the pending name on the
-  first and sweeps ``client.live_executables()`` for new executables
-  on the second, attributing their ``get_compiled_memory_stats()`` to
-  the function that just compiled;
+- the listener turns each ``jax.monitoring`` backend-compile duration
+  event — fired *after* the executable exists — into a named
+  ``"compile"`` notification followed by ``"backend_compile"``; the
+  capture remembers the pending name on the first and sweeps
+  ``client.live_executables()`` for new executables on the second,
+  attributing their ``get_compiled_memory_stats()`` to the function
+  that just compiled;
 - :meth:`CompiledMemoryCapture.capture` is the explicit AOT path
   (``jit(fn).lower(*args).compile()`` + record) the calibration tier
   uses for programs it builds itself.
@@ -25,7 +25,7 @@ biggest-executable view rides every metrics dump, and the full table
 rides ``MemoryMonitor.dump`` / ``memrec_*.json`` OOM artifacts.
 
 jax-lazy like the rest of the package; a failed sweep degrades to a
-counter, never an exception in the logging filter it rides.
+counter, never an exception in the monitoring hook it rides.
 """
 
 from __future__ import annotations

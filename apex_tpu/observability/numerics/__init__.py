@@ -9,7 +9,7 @@ The stack could already time, trace and profile every step (ISSUEs
   zero-fraction / finite-flag for a whole pytree in one fused
   on-device reduction, pulled to host only on the
   :class:`StatsCollector`'s decimated cadence (one fetch per pull,
-  corrected-sync rules — never a per-tensor ``block_until_ready``);
+  never a per-tensor wait);
 - :mod:`~apex_tpu.observability.numerics.history` —
   :class:`AmaxHistory` rings, the fp8 delayed-scaling primitive
   (ROADMAP item 5's substrate); ring state is a pytree that

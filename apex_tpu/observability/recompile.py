@@ -3,18 +3,15 @@
 ``apex_tpu.analysis`` lints recompile *hazards* statically (unhashable
 static args, closure captures); this module counts what actually
 happened at runtime and turns the count into a budget a bench run can
-fail on. Two feeds, both installed by :func:`install`:
-
-- ``jax.monitoring`` duration events (``/jax/core/compile/*``) give the
-  process-total trace/lower/compile counts and seconds — version-stable,
-  but carry no function names.
-- with ``jax_log_compiles`` enabled, jax logs one
-  ``"Compiling <name> with global shapes..."`` record per cache-miss
-  compile; a logging filter on the emitting loggers parses the name for
-  PER-FUNCTION compile counts (retraces = compiles - 1) and swallows
-  the records so enabling the flag doesn't spray stderr. When jax's
-  logger layout changes the per-function table degrades to empty while
-  the monitoring totals keep working.
+fail on. One feed, installed by :func:`install`: ``jax.monitoring``
+duration events (``/jax/core/compile/*``). Each carries the jitted
+function's name as ``fun_name`` (``"jit(train_step)"``), so the
+``backend_compile_duration`` event — fired once per jit-cache miss,
+whether XLA compiled or the persistent cache answered — gives both the
+process totals and the PER-FUNCTION compile counts (retraces =
+compiles - 1). An event that arrives without a name is counted in
+``unnamed_compiles``, and every per-function read then raises: a guard
+that cannot see must not report zero.
 
 Counts also land in a :class:`~apex_tpu.observability.registry
 .MetricRegistry`: counter ``jax/compiles{fn=...}``, histogram
@@ -25,7 +22,6 @@ from __future__ import annotations
 
 import collections
 import contextlib
-import logging
 import re
 import threading
 
@@ -36,20 +32,9 @@ __all__ = [
     "current", "retrace_guard",
 ]
 
-# jax loggers that emit the per-compile records under jax_log_compiles
-# (jax 0.4.x: pxla logs "Compiling <name> with global shapes and types
-# ...", dispatch logs the "Finished tracing/compilation ..." lines).
-_JAX_LOG_COMPILE_LOGGERS = ("jax._src.interpreters.pxla",
-                            "jax._src.dispatch")
-_COMPILING_RE = re.compile(r"^Compiling ([\w<>.\-]+) ")
-_FINISHED_RE = re.compile(r"^Finished (tracing \+ transforming|"
-                          r"jaxpr to MLIR module conversion|"
-                          r"XLA compilation)")
-
-# monitoring event names (jax 0.4.37 _src/dispatch.py)
 _EV_TRACE = "/jax/core/compile/jaxpr_trace_duration"
-_EV_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _EV_COMPILE = "/jax/core/compile/backend_compile_duration"
+_JIT_NAME_RE = re.compile(r"^\w+\((.*)\)$")     # "jit(step)" -> "step"
 
 
 class RetraceBudgetExceeded(RuntimeError):
@@ -65,36 +50,40 @@ class RecompileListener:
         self.compiles_by_fn = collections.Counter()
         self.totals = collections.Counter()      # event name -> count
         self.seconds = collections.defaultdict(float)
+        # compile events that carried no function name (see module doc)
+        self.unnamed_compiles = 0
         # compile observers (ISSUE 15): callbacks cb(kind, name) fired
-        # on "compile" (a per-function jax_log_compiles record — name
-        # known, executable not yet built) and "backend_compile" (the
-        # monitoring duration event AFTER the executable exists — the
-        # moment the memory tier sweeps live_executables for its
-        # per-executable memory_analysis view)
+        # on every backend-compile event, first as "compile" with the
+        # function's name, then as "backend_compile" — the executable
+        # exists by then, the moment the memory tier sweeps
+        # live_executables for its per-executable memory_analysis view
         self._observers: list = []
         self.observer_errors = 0
 
     # ---- feed: jax.monitoring duration events
 
-    def _on_duration(self, name: str, secs: float) -> None:
+    def _on_duration(self, name: str, secs: float, fun_name=None) -> None:
         if not name.startswith("/jax/core/compile/"):
             return
         with self._lock:
             self.totals[name] += 1
             self.seconds[name] += secs
-        if self.registry is not None and name == _EV_COMPILE:
-            self.registry.histogram("jax/backend_compile_secs").observe(secs)
-        if name == _EV_COMPILE:
-            self._notify("backend_compile", None)
-
-    # ---- feed: jax_log_compiles records
-
-    def _on_compile_record(self, fn_name: str) -> None:
-        with self._lock:
-            self.compiles_by_fn[fn_name] += 1
+        if name != _EV_COMPILE:
+            return
         if self.registry is not None:
-            self.registry.counter("jax/compiles", fn=fn_name).inc()
-        self._notify("compile", fn_name)
+            self.registry.histogram("jax/backend_compile_secs").observe(secs)
+        if fun_name is None:
+            with self._lock:
+                self.unnamed_compiles += 1
+        else:
+            m = _JIT_NAME_RE.match(fun_name)
+            fn_name = m.group(1) if m else fun_name
+            with self._lock:
+                self.compiles_by_fn[fn_name] += 1
+            if self.registry is not None:
+                self.registry.counter("jax/compiles", fn=fn_name).inc()
+            self._notify("compile", fn_name)
+        self._notify("backend_compile", None)
 
     # ---- compile observers (ISSUE 15)
 
@@ -125,9 +114,18 @@ class RecompileListener:
 
     # ---- read side
 
+    def _check_named(self) -> None:
+        if self.unnamed_compiles:
+            raise RuntimeError(
+                f"{self.unnamed_compiles} compile event(s) arrived from "
+                f"jax.monitoring without a fun_name: per-function compile "
+                f"counts are unavailable under this jax, so no retrace "
+                f"count can be trusted")
+
     def compiles(self, fn: "str | None" = None):
         """Per-function compile counts (dict), or one function's count."""
         with self._lock:
+            self._check_named()
             if fn is not None:
                 return self.compiles_by_fn.get(fn, 0)
             return dict(self.compiles_by_fn)
@@ -136,6 +134,7 @@ class RecompileListener:
         """Compiles beyond the first per function — the recompiles a
         steady-state training loop should never see."""
         with self._lock:
+            self._check_named()
             table = {name: n - 1 for name, n in self.compiles_by_fn.items()
                      if n > 1}
             if fn is not None:
@@ -153,6 +152,7 @@ class RecompileListener:
 
     def snapshot(self) -> dict:
         with self._lock:
+            self._check_named()
             return {
                 "compiles_by_fn": dict(self.compiles_by_fn),
                 "retraces_by_fn": {n: c - 1 for n, c in
@@ -164,44 +164,20 @@ class RecompileListener:
             }
 
 
-class _CompileLogFilter(logging.Filter):
-    """Captures per-function compile records; swallows the log spam we
-    induced by enabling jax_log_compiles (records pass through untouched
-    when the user had the flag on themselves)."""
-
-    def __init__(self, state):
-        super().__init__()
-        self._state = state
-
-    def filter(self, record: logging.LogRecord) -> bool:
-        try:
-            msg = record.getMessage()
-        except Exception:  # noqa: BLE001 — never break logging
-            return True
-        m = _COMPILING_RE.match(msg)
-        if m and self._state.listener is not None:
-            self._state.listener._on_compile_record(m.group(1))
-        if self._state.we_enabled_flag and (m or _FINISHED_RE.match(msg)):
-            return False
-        return True
-
-
 class _State:
     def __init__(self):
         self.listener: "RecompileListener | None" = None
         self.monitoring_registered = False
-        self.filters: list = []
-        self.we_enabled_flag = False
         self.lock = threading.Lock()
 
 
 _STATE = _State()
 
 
-def _monitoring_callback(name, secs, **_kw):
+def _monitoring_callback(name, secs, fun_name=None, **_kw):
     listener = _STATE.listener
     if listener is not None:
-        listener._on_duration(name, secs)
+        listener._on_duration(name, secs, fun_name)
 
 
 def install(registry=None) -> RecompileListener:
@@ -226,32 +202,14 @@ def install(registry=None) -> RecompileListener:
             jax.monitoring.register_event_duration_secs_listener(
                 _monitoring_callback)
             _STATE.monitoring_registered = True
-        _STATE.we_enabled_flag = not jax.config.jax_log_compiles
-        if _STATE.we_enabled_flag:
-            jax.config.update("jax_log_compiles", True)
-        for lname in _JAX_LOG_COMPILE_LOGGERS:
-            filt = _CompileLogFilter(_STATE)
-            logging.getLogger(lname).addFilter(filt)
-            _STATE.filters.append((lname, filt))
         _STATE.listener = listener
         return listener
 
 
 def uninstall() -> None:
-    """Detach the log filters, restore jax_log_compiles, and deactivate
-    the monitoring hook. Counts on the returned-by-install listener stop
-    growing but remain readable."""
-    import jax
-
+    """Deactivate the monitoring hook. Counts on the returned-by-install
+    listener stop growing but remain readable."""
     with _STATE.lock:
-        if _STATE.listener is None:
-            return
-        for lname, filt in _STATE.filters:
-            logging.getLogger(lname).removeFilter(filt)
-        _STATE.filters.clear()
-        if _STATE.we_enabled_flag:
-            jax.config.update("jax_log_compiles", False)
-        _STATE.we_enabled_flag = False
         _STATE.listener = None
 
 

@@ -41,11 +41,17 @@ PEAK_FLOPS_BY_KIND = (
 
 def peak_flops(device_kind: str) -> Optional[float]:
     """Peak bf16 FLOP/s for a ``jax.devices()[0].device_kind`` string
-    (substring match), or None for unknown/CPU devices."""
+    (substring match); None for a device that is not a TPU. A TPU kind
+    the table does not list is an error — a utilisation computed against
+    no peak, or a guessed one, is not a measurement."""
     kind = (device_kind or "").lower()
     for key, peak in PEAK_FLOPS_BY_KIND:
         if key in kind:
             return peak
+    if "tpu" in kind:
+        raise ValueError(
+            f"no peak FLOP/s known for TPU device kind {device_kind!r}: "
+            f"add it to PEAK_FLOPS_BY_KIND with its source")
     return None
 
 

@@ -139,11 +139,27 @@ def _fwd_kernel(causal, scale, block_q, block_k, sq, sk, varlen, p_drop,
         lse_ref[0, :, 0] = (m_sc[:, 0] + jnp.log(l)).astype(jnp.float32)
 
 
-def _pick_block(s, target):
+def _tile(s, target):
+    """``(block, padded_len)`` for a sequence of ``s`` rows and a wanted
+    block of ``target``: halve the block while it does not divide ``s``,
+    but never below ``pallas_config.FLASH_MIN_BLOCK`` rows — from there
+    the SEQUENCE is padded up to a block multiple instead. (Halving all
+    the way down gave a 520-token prefill 8-row blocks and a prime length
+    1-row blocks: under the bf16 (16, 128) tile, and a grid step per
+    row.) A target at or below the floor is taken as is, so a short
+    sequence stays one full-extent block."""
     b = min(target, s)
-    while s % b:
+    while b > pallas_config.FLASH_MIN_BLOCK and s % b:
         b //= 2
-    return max(b, 1)
+    return b, -(-s // b) * b
+
+
+def _pad_rows(x, rows):
+    """Zero-pad dim 1 of ``x`` up to ``rows``."""
+    if x.shape[1] == rows:
+        return x
+    return jnp.pad(x, ((0, 0), (0, rows - x.shape[1]))
+                   + ((0, 0),) * (x.ndim - 2))
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
@@ -170,9 +186,13 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     bh, sq, d = q.shape
     bh_kv, sk, _ = k.shape
     rep = bh // bh_kv
-    bq = _pick_block(sq, block_q)
-    bk = _pick_block(sk, block_k)
-    grid = (bh, pl.cdiv(sq, bq), pl.cdiv(sk, bk))
+    bq, sq_p = _tile(sq, block_q)
+    bk, sk_p = _tile(sk, block_k)
+    # padded keys are masked in the kernel (k_pos < sk); padded query
+    # rows compute a harmless uniform softmax and are sliced off below
+    q = _pad_rows(q, sq_p)
+    k, v = _pad_rows(k, sk_p), _pad_rows(v, sk_p)
+    grid = (bh, sq_p // bq, sk_p // bk)
     varlen = kv_lens is not None
 
     kernel = functools.partial(_fwd_kernel, causal, scale, bq, bk, sq, sk,
@@ -201,18 +221,19 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            pallas_config.out_struct((bh, sq, d), q.dtype, q, k, v),
-            pallas_config.out_struct((bh, sq, 1), jnp.float32, q, k, v),
+            pallas_config.out_struct((bh, sq_p, d), q.dtype, q, k, v),
+            pallas_config.out_struct((bh, sq_p, 1), jnp.float32, q, k, v),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
+        name="apex_flash_fwd",
         interpret=interpret,
     )(*args)
     # public lse stays [bh, sq]; the singleton is a kernel-layout detail
-    return o, lse[:, :, 0]
+    return o[:, :sq], lse[:, :sq, 0]
 
 
 def _reference_attention(q, k, v, causal, scale, kv_lens=None, p_drop=0.0,
@@ -258,7 +279,7 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None, p_drop=0.0,
 # ever exists in HBM (ref csrc/fmha dgrad kernels).
 
 
-def _bwd_dq_kernel(causal, scale, bq, bk, varlen, p_drop,
+def _bwd_dq_kernel(causal, scale, bq, bk, sk, varlen, p_drop,
                    q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                    *refs):
     refs = list(refs)
@@ -290,13 +311,15 @@ def _bwd_dq_kernel(causal, scale, bq, bk, varlen, p_drop,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bq, bk]
         p = jnp.exp(s - lse_ref[0])
-        if causal or varlen or p_drop:
+        if causal or varlen or p_drop or sk % bk:
             q_pos = qi * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 0)
             k_pos = ki * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 1)
         if causal:
             p = jnp.where(k_pos <= q_pos, p, 0.0)
+        if sk % bk:  # key padding (see _tile)
+            p = jnp.where(k_pos < sk, p, 0.0)
         if varlen:
             p = jnp.where(k_pos < kvlen_ref[0, 0, 0], p, 0.0)
         dp = jax.lax.dot_general(
@@ -319,7 +342,7 @@ def _bwd_dq_kernel(causal, scale, bq, bk, varlen, p_drop,
         dq_ref[0] = acc_sc[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(causal, scale, bq, bk, rep, nq, varlen, p_drop,
+def _bwd_dkv_kernel(causal, scale, bq, bk, sk, rep, nq, varlen, p_drop,
                     q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                     *refs):
     refs = list(refs)
@@ -352,13 +375,15 @@ def _bwd_dkv_kernel(causal, scale, bq, bk, rep, nq, varlen, p_drop,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [bq, bk]
         p = jnp.exp(s - lse_ref[0])
-        if causal or varlen or p_drop:
+        if causal or varlen or p_drop or sk % bk:
             q_pos = qi * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 0)
             k_pos = ki * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 1)
         if causal:
             p = jnp.where(k_pos <= q_pos, p, 0.0)
+        if sk % bk:  # key padding (see _tile)
+            p = jnp.where(k_pos < sk, p, 0.0)
         if varlen:
             p = jnp.where(k_pos < kvlen_ref[0, 0, 0], p, 0.0)
         if p_drop:
@@ -395,9 +420,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     bh, sq, d = q.shape
     bh_kv, sk, _ = k.shape
     rep = bh // bh_kv
-    bq = _pick_block(sq, block_q)
-    bk = _pick_block(sk, block_k)
-    nq, nk = sq // bq, sk // bk
+    bq, sq_p = _tile(sq, block_q)
+    bk, sk_p = _tile(sk, block_k)
+    nq, nk = sq_p // bq, sk_p // bk
     varlen = kv_lens is not None
 
     # D_i = rowsum(dO * O): elementwise, O(s·d) — fine as fused XLA.
@@ -406,6 +431,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, :, None]
     lse3 = lse.reshape(bh, sq, 1)
+    # padded query rows carry do = delta = 0, so they add nothing to
+    # dk/dv; padded keys are masked in the kernels (k_pos < sk)
+    q, do, lse3, delta = (_pad_rows(x, sq_p) for x in (q, do, lse3, delta))
+    k, v = _pad_rows(k, sk_p), _pad_rows(v, sk_p)
 
     dq_in_specs = [
         pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -442,20 +471,21 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
         dkv_args = dkv_args + (sd,)
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal, scale, bq, bk, varlen,
+        functools.partial(_bwd_dq_kernel, causal, scale, bq, bk, sk, varlen,
                           p_drop),
         grid=(bh, nq, nk),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=pallas_config.out_struct((bh, sq, d), q.dtype, q, k, v,
+        out_shape=pallas_config.out_struct((bh, sq_p, d), q.dtype, q, k, v,
                                            do),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="apex_flash_bwd_dq",
         interpret=interpret,
     )(*dq_args)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal, scale, bq, bk, rep, nq,
-                          varlen, p_drop),
+        functools.partial(_bwd_dkv_kernel, causal, scale, bq, bk, sk, rep,
+                          nq, varlen, p_drop),
         grid=(bh_kv, nk, rep, nq),
         in_specs=dkv_in_specs,
         out_specs=[
@@ -463,16 +493,19 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q, block_k,
             pl.BlockSpec((1, bk, d), lambda g, j, r, i: (g, j, 0)),
         ],
         out_shape=[
-            pallas_config.out_struct((bh_kv, sk, d), k.dtype, q, k, v, do),
-            pallas_config.out_struct((bh_kv, sk, d), v.dtype, q, k, v, do),
+            pallas_config.out_struct((bh_kv, sk_p, d), k.dtype, q, k, v,
+                                     do),
+            pallas_config.out_struct((bh_kv, sk_p, d), v.dtype, q, k, v,
+                                     do),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
+        name="apex_flash_bwd_dkv",
         interpret=interpret,
     )(*dkv_args)
-    return dq, dk, dv
+    return dq[:, :sq], dk[:, :sk], dv[:, :sk]
 
 
 def _use_pallas() -> bool:

@@ -37,8 +37,10 @@ def _cast_scale_kernel(fmax, x_ref, s_ref, y_ref, amax_ref):
         amax_ref[...] = jnp.zeros_like(amax_ref)
 
     # pre-scale amax of the REAL values; padding rows are zeros and
-    # amax is >= 0, so they never vote
-    amax_ref[0, 0] = jnp.maximum(amax_ref[0, 0], jnp.max(jnp.abs(x)))
+    # amax is >= 0, so they never vote. Stored as a (1, 1) vector: Mosaic
+    # refuses a scalar store to VMEM ("Cannot store scalars to VMEM")
+    amax_ref[...] = jnp.maximum(
+        amax_ref[...], jnp.max(jnp.abs(x), keepdims=True))
     y = jnp.clip(x * s_ref[0, 0], -fmax, fmax)  # saturate, never inf/nan
     y_ref[...] = y.astype(y_ref.dtype)
 
@@ -72,6 +74,7 @@ def _cast_and_scale_pallas(x, scale, *, dtype, fmax, block_rows, cols,
             pallas_config.out_struct((rows, cols), dtype, x, scale),
             pallas_config.out_struct((1, 1), jnp.float32, x, scale),
         ],
+        name="apex_fp8_cast",
         interpret=interpret,
     )(x2, sc)
     return y2.ravel()[:n].reshape(x.shape), amax[0, 0]

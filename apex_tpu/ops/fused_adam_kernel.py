@@ -17,7 +17,7 @@ TUNER-SUPPLIED (apex_tpu.tuning): callers either pass ``(block_rows,
 cols)`` explicitly (the sweep does) or leave them None and get the
 tuned/default pick for the actual buffer size — the fixed (rows, 1024)
 slab with a constant 512-row block was the prime suspect for the
-measured 3.2x TPU inversion (BENCH_r05_live.json), and the old
+3.2x inversion measured on a v5e on 2026-07-31, and the old
 small-tensor path padded a scalar bias to 8x1024 fp32 x4 buffers.
 """
 
@@ -138,6 +138,7 @@ def _adam_flat_pallas(g, p, m, v, lr_t, step, *, b1, b2, eps,
             pallas_config.out_struct((rows, cols), jnp.float32, g, p, m, v),
             pallas_config.out_struct((rows, cols), jnp.float32, g, p, m, v),
         ],
+        name="apex_flat_adam",
         interpret=interpret,
     )(scalars, g2, p2, m2, v2)
     return (d2.ravel()[:n], mo2.ravel()[:n], vo2.ravel()[:n])

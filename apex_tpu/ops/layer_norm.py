@@ -28,6 +28,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops import pallas_config
+from apex_tpu.ops.vma import cotangent_like
 
 
 def _use_pallas(kernel: str = "layer_norm") -> bool:
@@ -119,6 +120,7 @@ def _ln_fwd_pallas(x2, w, b, eps):
             pallas_config.out_struct((rows, 1), jnp.float32, *args),
             pallas_config.out_struct((rows, 1), jnp.float32, *args),
         ],
+        name="apex_ln_fwd",
         interpret=pallas_config.interpret(),
     )(*args)
     return y[:n], mu[:n], rstd[:n]
@@ -152,6 +154,7 @@ def _rms_fwd_pallas(x2, w, eps):
             pallas_config.out_struct((rows, h), x2.dtype, *args),
             pallas_config.out_struct((rows, 1), jnp.float32, *args),
         ],
+        name="apex_rms_fwd",
         interpret=pallas_config.interpret(),
     )(*args)
     return y[:n], rstd[:n]
@@ -271,6 +274,7 @@ def _ln_bwd_pallas(x2, w, mu, rstd, dy):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        name="apex_ln_bwd",
         interpret=pallas_config.interpret(),
     )(*args)
     if affine:
@@ -311,6 +315,7 @@ def _rms_bwd_pallas(x2, w, rstd, dy):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        name="apex_rms_bwd",
         interpret=pallas_config.interpret(),
     )(*args)
     if affine:
@@ -361,9 +366,9 @@ def _layer_norm_affine_fwd(x2, w, b, eps):
 
 def _layer_norm_affine_bwd(eps, res, dy):
     x2, w, mu, rstd = res
-    if _use_pallas():
-        return _ln_bwd_pallas(x2, w, mu, rstd, dy)
-    return _ln_bwd_jnp(x2, w, mu, rstd, dy)
+    bwd = _ln_bwd_pallas if _use_pallas() else _ln_bwd_jnp
+    dx, dw, db = bwd(x2, w, mu, rstd, dy)
+    return dx, cotangent_like(dw, w), cotangent_like(db, w)
 
 
 _layer_norm_affine.defvjp(_layer_norm_affine_fwd, _layer_norm_affine_bwd)
@@ -405,9 +410,9 @@ def _rms_norm_affine_fwd(x2, w, eps):
 
 def _rms_norm_affine_bwd(eps, res, dy):
     x2, w, rstd = res
-    if _use_pallas("rms_norm"):
-        return _rms_bwd_pallas(x2, w, rstd, dy)
-    return _rms_bwd_jnp(x2, w, rstd, dy)
+    bwd = _rms_bwd_pallas if _use_pallas("rms_norm") else _rms_bwd_jnp
+    dx, dw = bwd(x2, w, rstd, dy)
+    return dx, cotangent_like(dw, w)
 
 
 _rms_norm_affine.defvjp(_rms_norm_affine_fwd, _rms_norm_affine_bwd)

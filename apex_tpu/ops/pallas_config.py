@@ -6,7 +6,7 @@ asks :func:`use_pallas` whether to take its Pallas path and passes
 Pallas on TPU and takes the jnp fallback elsewhere; tests use
 ``force('interpret')`` to execute the actual kernel bodies on the CPU mesh
 through the Pallas interpreter, so kernel logic is exercised in CI rather
-than only on real hardware (round-1 gap: VERDICT.md weak #2).
+than only on real hardware.
 """
 
 from __future__ import annotations
@@ -17,6 +17,8 @@ import os
 
 import jax
 
+from apex_tpu.ops.vma import vma
+
 _MODE = "auto"  # auto | off | on | interpret
 
 # Flash-attention tile sizes, keyed by pass. ``None`` = per-shape auto
@@ -25,6 +27,11 @@ _MODE = "auto"  # auto | off | on | interpret
 # 512/256 were hardcoded at flash_attention.py:389,405).
 _FLASH_BLOCKS = {"fwd": None, "bwd": None}
 _FLASH_DEFAULTS = {"fwd": (512, 512), "bwd": (256, 256)}
+# Below this many rows a flash block is not shrunk further to divide the
+# sequence; the sequence is padded instead (flash_attention._tile). One
+# lane-width: every block the kernels then see is a whole number of
+# (8, 128) / (16, 128) tiles on both the row and the score-lane side.
+FLASH_MIN_BLOCK = 128
 
 # Per-kernel verdicts for 'auto' mode, set from the bench.py kernel race
 # on real hardware (VERDICT r2 item 2 / r4 next-step 2: a kernel slower
@@ -248,32 +255,46 @@ def _validate_tuning_evidence(path: str, repo_root: str) -> list:
     return []
 
 
+def _by_kind(table, kind: str, default: int, what: str) -> int:
+    """Look ``kind`` (a device_kind string) up in a substring-keyed
+    ``table``. A TPU kind the table does not list is an error — a
+    planning figure guessed for a chip nobody measured mis-sizes every
+    tile and budget built on it; anything else (the CPU) gets
+    ``default``."""
+    low = kind.lower()
+    for key, value in table:
+        if key in low:
+            return value
+    if "tpu" in low:
+        raise ValueError(
+            f"no {what} known for TPU device kind {kind!r}: add it to "
+            f"apex_tpu.ops.pallas_config with its source")
+    return default
+
+
 # Per-core VMEM by device generation, matched by substring against
-# jax.devices()[0].device_kind (same scheme as bench._PEAK_FLOPS). The
-# Pallas guide's planning figure is ~16 MiB/core across current
-# generations; entries here override when a generation differs. Used by
-# the pallas-block VMEM-budget check in apex_tpu.analysis and available
-# to kernels for tile planning.
+# jax.devices()[0].device_kind (same scheme as step_report's peak
+# table). The Pallas guide's planning figure is ~16 MiB/core; v6 has
+# twice that. Used by the pallas-block VMEM-budget check in
+# apex_tpu.analysis and available to kernels for tile planning.
 _VMEM_BYTES_DEFAULT = 16 << 20
 _VMEM_BYTES = (
     ("v6", 32 << 20), ("trillium", 32 << 20),
+    ("v5p", 16 << 20), ("v5 lite", 16 << 20), ("v5e", 16 << 20),
+    ("v4", 16 << 20), ("v3", 16 << 20), ("v2", 16 << 20),
 )
 
 
 def device_vmem_bytes(kind: "str | None" = None) -> int:
     """Per-core VMEM budget in bytes for ``kind`` (a device_kind string;
-    default: the current backend's first device, or the conservative
-    16 MiB planning figure off-TPU)."""
+    default: the current backend's first device). Off-TPU: the 16 MiB
+    planning figure; an unlisted TPU kind raises."""
     if kind is None:
         dev = jax.devices()[0]
         if dev.platform != "tpu":
             return _VMEM_BYTES_DEFAULT
         kind = dev.device_kind
-    kind = kind.lower()
-    for key, nbytes in _VMEM_BYTES:
-        if key in kind:
-            return nbytes
-    return _VMEM_BYTES_DEFAULT
+    return _by_kind(_VMEM_BYTES, kind, _VMEM_BYTES_DEFAULT, "VMEM size")
 
 
 # Per-device HBM by generation, same substring scheme as _VMEM_BYTES.
@@ -293,7 +314,7 @@ _HBM_BYTES = (
 def device_hbm_bytes(kind: "str | None" = None) -> int:
     """Per-device HBM budget in bytes for ``kind`` (a device_kind
     string; default: the current backend's first device, or the
-    conservative 16 GiB planning figure off-TPU). The
+    16 GiB planning figure off-TPU; an unlisted TPU kind raises). The
     ``APEX_TPU_HBM_BYTES`` env var overrides everything — the knob the
     hbm-budget analysis check documents in docs/runtime.md.
 
@@ -322,11 +343,7 @@ def device_hbm_bytes(kind: "str | None" = None) -> int:
         if limit is not None:
             return limit
         kind = dev.device_kind
-    kind = kind.lower()
-    for key, nbytes in _HBM_BYTES:
-        if key in kind:
-            return nbytes
-    return _HBM_BYTES_DEFAULT
+    return _by_kind(_HBM_BYTES, kind, _HBM_BYTES_DEFAULT, "HBM size")
 
 
 def _live_hbm_limit(dev) -> "int | None":
@@ -357,23 +374,11 @@ def _live_hbm_limit(dev) -> "int | None":
 
 def out_struct(shape, dtype, *like):
     """``jax.ShapeDtypeStruct`` for a ``pallas_call`` out_shape that works
-    inside ``shard_map``: with jax's check_vma on, pallas outputs must
-    declare which mesh axes they vary over — the union of the inputs'
-    vma (``like``) is the right answer for every elementwise/blockwise
-    kernel here. Outside shard_map (or on older jax) this reduces to a
-    plain ShapeDtypeStruct."""
-    vma: frozenset = frozenset()
-    for x in like:
-        try:
-            vma = vma | jax.typeof(x).vma
-        except (AttributeError, TypeError):
-            pass
-    if vma:
-        try:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-        except TypeError:  # jax without the vma kwarg
-            pass
-    return jax.ShapeDtypeStruct(shape, dtype)
+    inside ``shard_map``: under ``check_vma`` pallas outputs must declare
+    which mesh axes they vary over — the union of the inputs' vma
+    (``like``) is the right answer for every elementwise/blockwise
+    kernel here. Outside shard_map the set is empty."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma(*like))
 
 
 def mode() -> str:

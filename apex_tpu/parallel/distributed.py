@@ -21,22 +21,24 @@ What remains of DDP is therefore:
 Use inside ``shard_map``/``pmap`` with the mesh axis named ``data`` (or pass
 ``axis_name``).
 
-IMPORTANT (jax ≥0.8 shard_map semantics): inside ``shard_map``, ``jax.grad``
-w.r.t. *replicated* (unvaried, ``P()``) params already inserts the cross-
-replica ``psum`` — the transpose of the implicit broadcast. In that pattern
-grads arrive globally **summed**; use :func:`average_reduced` (divide by
-world size), NOT :func:`sync_gradients`, or you double-reduce. Explicit
-:func:`sync_gradients` is for genuinely per-replica grads: pmap-style
-per-device param copies, or params made varying with ``jax.lax.pvary``.
+IMPORTANT (shard_map semantics under ``check_vma``): inside ``shard_map``,
+``jax.grad`` w.r.t. *replicated* (unvaried, ``P()``) params already inserts
+the cross-replica ``psum`` — the transpose of the implicit broadcast. In
+that pattern grads arrive globally **summed**; use :func:`average_reduced`
+(divide by world size), NOT :func:`sync_gradients`, or you double-reduce.
+Explicit :func:`sync_gradients` is for genuinely per-replica grads:
+pmap-style per-device param copies, params made varying with
+``jax.lax.pcast(..., to="varying")``, or any body run with
+``check_vma=False`` (no types, so nothing is summed for you).
 
-CAVEAT to the auto-psum: a ``jax.custom_vjp`` in the model (every Pallas
-fused kernel — layer_norm, rms_norm, flash attention) hides the broadcast
-from transposition, so the grads of params feeding ONLY through custom_vjp
-ops arrive per-device **local** (varying) while everything else arrives
-summed (invariant) — a mixed tree that :func:`average_reduced` silently
-mis-scales. :func:`sync_autodiff_gradients` inspects each leaf's varying
-set and repairs both kinds; it is the safe default for replicated-param
-DDP over real models.
+The fused kernels follow the same rule: every ``jax.custom_vjp`` in the
+tree returns cotangents typed like its primals
+(:func:`apex_tpu.ops.vma.cotangent_like`), so a replicated norm weight's
+gradient leaves the kernel summed and invariant like every other
+replicated parameter's. :func:`sync_autodiff_gradients` reads each leaf's
+type rather than assuming it, so it is the safe default for
+replicated-param DDP: an invariant leaf is divided, a leaf the caller
+made varying gets a real ``pmean``.
 """
 
 from __future__ import annotations
@@ -182,16 +184,15 @@ def average_reduced(grads, axis_name: str = "data"):
 
 def sync_autodiff_gradients(grads, axis_name: str = "data"):
     """Per-leaf vma-aware gradient averaging for the replicated-params
-    pattern (see the module-note CAVEAT): autodiff auto-psums the grads of
-    replicated params — EXCEPT those flowing only through ``custom_vjp``
-    ops (the fused kernels), which arrive per-device local. Inspecting
-    ``jax.typeof(leaf).vma``: a leaf still varying over ``axis_name`` gets
-    an explicit ``pmean``; an invariant (already-summed) leaf is divided
-    by the axis size. Either way the result is the invariant global-batch
-    -mean gradient, safe for ``lax.cond``-based overflow skips."""
+    pattern (see the module note). Under ``check_vma`` autodiff — and
+    every fused kernel's VJP rule — hands back the grads of replicated
+    params already psummed (invariant over ``axis_name``); those are
+    divided by the axis size. A leaf still varying over ``axis_name``
+    (params the caller made varying) gets an explicit ``pmean``. Either
+    way the result is the invariant global-batch-mean gradient, safe for
+    ``lax.cond``-based overflow skips, and nothing is reduced twice."""
     def one(g):
-        vma = getattr(jax.typeof(g), "vma", frozenset())
-        if axis_name in vma:
+        if axis_name in jax.typeof(g).vma:
             return jax.lax.pmean(g, axis_name)
         n = jax.lax.axis_size(axis_name)
         return (g / jnp.asarray(n, g.dtype)).astype(g.dtype)
@@ -299,7 +300,7 @@ class DistributedDataParallel:
     def average_reduced(self, grads):
         """Average grads that were already psummed by autodiff (the
         replicated-params pattern — see module docstring). vma-aware:
-        leaves a custom_vjp kernel left unsummed get a real pmean."""
+        leaves the caller made varying get a real pmean."""
         if not self.gradient_average:
             return grads
         return sync_autodiff_gradients(grads, self.axis_name)

@@ -121,7 +121,20 @@ def launch(script_args, nprocs: int, devices_per_proc: int = 1,
     <script_args>`` against a localhost coordinator; returns the first
     nonzero worker exit code (0 when all succeed). Workers inherit the
     caller's env plus the coordinator variables (and the CPU forcing
-    knobs when ``cpu``)."""
+    knobs when ``cpu``).
+
+    More than one LOCAL worker is a CPU-only arrangement: a chip belongs
+    to one process at a time and one process drives every chip of its
+    host, so N local workers without ``cpu`` would each open all of
+    them and all but the first would fail or hang. On accelerators start
+    one worker per host (``nprocs=1`` here, or the bare worker form with
+    the coordinator variables set)."""
+    if nprocs > 1 and not cpu:
+        raise ValueError(
+            f"launch(nprocs={nprocs}) without cpu=True would start "
+            f"{nprocs} processes that each claim every local chip; one "
+            f"process drives all chips of a host — pass cpu=True "
+            f"(--cpu) for a simulated fleet, or run one worker per host")
     addr = f"127.0.0.1:{_free_port()}"
     base = dict(os.environ if env is None else env)
     base.update(COORDINATOR_ADDRESS=addr, NUM_PROCESSES=str(nprocs))

@@ -23,7 +23,7 @@ it deterministically on CPU.
   saves, amp-overflow skip integration, and the skip → rollback →
   abort degradation ladder.
 
-See docs/resilience.md for the fault taxonomy, cookbook, exit-code
+See docs/resilience.md for the fault kinds, cookbook, exit-code
 contract and resume guarantees.
 """
 

@@ -8,10 +8,10 @@ kinds a preemptible TPU fleet actually produces:
                    commit marker (the classic torn write);
 - ``ckpt_enospc``  a checkpoint write refused at open (disk full);
 - ``step_exc``     a transient exception out of the train step (the
-                   flaky-collective / tunnel-hiccup class);
+                   flaky-collective / transient-RPC class);
 - ``nan_grads``    a NaN/overflow storm poisoning the step's output;
 - ``stall``        a step that hangs far past its normal duration (a
-                   wedged collective / tunnel lease): the loop sleeps
+                   wedged collective or a lost device): the loop sleeps
                    ``stall_s`` inside the step, which is what the
                    observability flight recorder's watchdog exists to
                    catch (docs/profiling.md);

@@ -232,9 +232,8 @@ class ResilientTrainLoop:
         """Periodic save; a failure (after retries) degrades to a
         counter + event — training continues on the last good save.
 
-        ISSUE 17: the save is timed through the registry Timer (the
-        corrected-sync clock — resilience code never reads a raw
-        clock) and the elapsed host seconds ride the event as
+        ISSUE 17: the save is timed through the registry Timer
+        (resilience code never reads a raw clock) and the elapsed host seconds ride the event as
         ``duration_s``, the run ledger's ``ckpt_save`` interval. With
         ``async_save`` this is the host-blocking enqueue time, which
         is exactly the wall time the training loop lost."""

@@ -1,8 +1,8 @@
 """Retry/backoff policy (ISSUE 5 tentpole piece 2).
 
 One policy object wraps every host-side call that can transiently fail
-on a real fleet — checkpoint I/O, compile/dispatch RPCs over the tunnel
-— with exponential backoff + jitter, a total attempt budget,
+on a real fleet — checkpoint I/O, compile/dispatch RPCs — with
+exponential backoff + jitter, a total attempt budget,
 per-exception-class budgets, and an optional wall-clock
 :class:`Deadline`. Every retry and give-up lands as a ``resilience/*``
 counter in the shared :mod:`apex_tpu.observability` registry, so a

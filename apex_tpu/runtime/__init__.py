@@ -2,7 +2,7 @@
 
 ctypes loader for ``csrc/libapex_tpu_host.so`` plus pure-Python fallbacks
 so the package works before ``make -C csrc`` has run. ``timing`` holds
-the corrected-sync device timing helpers shared by bench.py and tools/.
+the device timing helpers shared by bench.py and tools/.
 """
 
 from apex_tpu.runtime import timing

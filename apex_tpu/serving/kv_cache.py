@@ -11,12 +11,16 @@ scatter their (masked, never-read) k/v writes there, which keeps the
 decode step total — no ``lax.cond`` per slot, no out-of-bounds scatter.
 The allocator never hands it out.
 
-The page *budget* is derived from the calibrated memory tier rather than
-guessed: usable HBM = ``device_hbm_bytes()`` × safety − the live
-``MemoryMonitor`` watermark, divided by the per-page footprint corrected
-by the ``hbm_priors.json`` measured/modeled ratio (PR 18). On hosts with
-no calibration the priors' default ratio applies, so the budget is
-conservative, not optimistic.
+The page *budget* is derived from the live memory tier rather than
+guessed: usable HBM = ``device_hbm_bytes()`` × safety − what the device
+already holds (the ``MemoryMonitor`` watermark, else the allocator's
+``bytes_in_use`` — the weights), divided by the per-page footprint. The
+``hbm_priors.json`` measured/modeled ratio (PR 18) corrects the
+footprint only where it was measured on the backend in use: a ratio
+below 1 taken on another backend would add pages that do not fit. On a
+TPU v5 lite the page buffers occupy their modeled bytes (ratio 1.0003
+measured for ``[8, 1153, 8, 8, 128]`` bf16 — the (8, 128) minor dims are
+not padded).
 """
 
 from __future__ import annotations
@@ -53,9 +57,9 @@ class PageBudget:
 
     pages: int
     page_bytes: int          # modeled bytes per page
-    ratio: float             # hbm_priors measured/modeled correction
+    ratio: float             # measured/modeled correction (1.0 = none)
     hbm_bytes: int           # device HBM limit used
-    watermark_bytes: int     # live MemoryMonitor watermark subtracted
+    watermark_bytes: int     # bytes the device already holds, subtracted
     usable_bytes: int        # hbm * safety - watermark (floored at 0)
     safety: float
 
@@ -66,16 +70,19 @@ def derive_page_budget(cfg, page_size: int, *,
                        priors: Optional[dict] = None,
                        safety: float = 0.90,
                        dtype=None) -> PageBudget:
-    """Page budget from the calibrated memory tier.
+    """Page budget from the live memory tier.
 
-    ``pages = floor((hbm × safety − watermark) / (page_bytes × ratio))``
-    where ``ratio`` is the hbm_priors measured/modeled correction (the
-    default ratio when no serving-specific prior exists yet). Every
-    input is overridable for tests; defaults read the live tier:
-    ``device_hbm_bytes()``, the active ``MemoryMonitor`` watermark (0
-    when none is attached), and the committed ``hbm_priors.json``.
+    ``pages = floor((hbm × safety − watermark) / (page_bytes × ratio))``.
+    Every input is overridable for tests; defaults read the live tier:
+    ``device_hbm_bytes()``; the active ``MemoryMonitor`` watermark, or
+    with no monitor attached the allocator's ``bytes_in_use`` (0 where
+    the backend reports none, i.e. the CPU); and the committed
+    ``hbm_priors.json`` — whose ratio (the serving prior, else its
+    default) is applied only when it was measured on the backend in
+    use, and is 1.0 otherwise.
     """
     from apex_tpu.analysis.memory_checks import load_hbm_priors, prior_for
+    from apex_tpu.observability.memory import hbm
     from apex_tpu.ops.pallas_config import device_hbm_bytes
 
     if not 0.0 < safety <= 1.0:
@@ -83,12 +90,15 @@ def derive_page_budget(cfg, page_size: int, *,
     if hbm_bytes is None:
         hbm_bytes = device_hbm_bytes()
     if watermark_bytes is None:
-        from apex_tpu.observability.memory.hbm import active_monitor
-        mon = active_monitor()
-        watermark_bytes = mon.watermark_bytes if mon is not None else 0
+        mon = hbm.active_monitor()
+        watermark_bytes = (
+            mon.watermark_bytes if mon is not None
+            else hbm.device_memory_stats().get("bytes_in_use", 0))
     if priors is None:
         priors = load_hbm_priors()
-    ratio = prior_for("serving_decode_step", priors, default=True)
+    ratio = 1.0
+    if priors.get("backend") == jax.default_backend():
+        ratio = prior_for("serving_decode_step", priors, default=True)
     page_bytes = page_hbm_bytes(cfg, page_size, dtype=dtype)
     usable = max(0, int(hbm_bytes * safety) - int(watermark_bytes))
     pages = int(usable // max(1, int(math.ceil(page_bytes * ratio))))

@@ -290,6 +290,10 @@ class ContinuousBatchScheduler:
         self._prefills: Dict[int, object] = {}
         self.decode_steps = 0
         self.prefill_count = 0
+        # installed before anything compiles, so the guard below can tell
+        # "no retrace" from "the listener never saw a compile"
+        from apex_tpu.observability import recompile
+        self._recompiles = recompile.install()
         # compile count of "_decode_step" right after OUR first compile
         # — the zero-retrace guard's baseline (delta, so other engines'
         # earlier compiles of the same-named step don't count here)
@@ -312,9 +316,7 @@ class ContinuousBatchScheduler:
         first compile — steady-state must report 0."""
         if self._decode_compiles0 is None:
             return 0
-        from apex_tpu.observability import recompile
-        listener = recompile.install()
-        return max(0, listener.compiles("_decode_step")
+        return max(0, self._recompiles.compiles("_decode_step")
                    - self._decode_compiles0)
 
     # ------------------------------------------------------- admission
@@ -406,9 +408,12 @@ class ContinuousBatchScheduler:
             jnp.asarray(self._pos), jnp.asarray(self._active))
         self.decode_steps += 1
         if self._decode_compiles0 is None:
-            from apex_tpu.observability import recompile
-            self._decode_compiles0 = recompile.install().compiles(
+            self._decode_compiles0 = self._recompiles.compiles(
                 "_decode_step")
+            if self._decode_compiles0 < 1:
+                raise RuntimeError(
+                    "the recompile listener did not see _decode_step "
+                    "compile: the zero-retrace guard is blind")
         nxt = np.asarray(nxt)
         finished = []
         for slot, req in enumerate(self.slots):

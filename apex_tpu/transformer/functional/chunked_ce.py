@@ -23,6 +23,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.ops.vma import cotangent_like, to_varying, vma
+
 __all__ = ["chunked_lm_cross_entropy"]
 
 
@@ -48,20 +50,10 @@ def _carry_axes(tp_axis, *operands):
     """Mesh axes the scan carries become varying over: every axis any
     operand already varies over (e.g. 'cp'-sharded hidden states), plus
     the explicit vocab-parallel axis."""
-    from apex_tpu.transformer.tensor_parallel.mappings import tree_vma
-
-    axes = set(tree_vma(*operands))
+    axes = set(vma(*operands))
     if tp_axis is not None:
         axes.add(tp_axis)
     return sorted(axes)
-
-
-def _vary(x, axes):
-    from apex_tpu.transformer.tensor_parallel.mappings import make_varying
-
-    for ax in axes:
-        x = make_varying(x, ax)
-    return x
 
 
 def chunked_lm_cross_entropy(hidden, weight, labels, num_chunks=8,
@@ -111,9 +103,9 @@ def _fwd(hidden, weight, bias, labels, num_chunks, tp_axis):
         tgt = jnp.where(in_c, tl, tgt)
         return (m_new, s, tgt), None
 
-    init = (_vary(jnp.full((n,), -jnp.inf, jnp.float32), axes),
-            _vary(jnp.zeros((n,), jnp.float32), axes),
-            _vary(jnp.zeros((n,), jnp.float32), axes))
+    init = (to_varying(jnp.full((n,), -jnp.inf, jnp.float32), axes),
+            to_varying(jnp.zeros((n,), jnp.float32), axes),
+            to_varying(jnp.zeros((n,), jnp.float32), axes))
     (m, s, tgt), _ = jax.lax.scan(body, init, (w, bch, los))
     if tp_axis is not None:
         # vocab-parallel merge of the per-rank streams (the stable
@@ -151,15 +143,16 @@ def _bwd(num_chunks, tp_axis, res, g):
         return dx, (dw_c, db_c)
 
     dx, (dws, dbs) = jax.lax.scan(
-        body, _vary(jnp.zeros_like(x32), axes), (w, bch, los))
+        body, to_varying(jnp.zeros_like(x32), axes), (w, bch, los))
     if tp_axis is not None:
         # each rank's dx covers only its vocab shard's columns — the
         # column-parallel transpose is an allreduce
         dx = jax.lax.psum(dx, tp_axis)
     dweight = dws.transpose(1, 0, 2).reshape(weight.shape)
     dbias = dbs.reshape(bias.shape).astype(bias.dtype)
-    return (dx.astype(hidden.dtype), dweight.astype(weight.dtype), dbias,
-            None)
+    return (cotangent_like(dx.astype(hidden.dtype), hidden),
+            cotangent_like(dweight.astype(weight.dtype), weight),
+            cotangent_like(dbias, bias), None)
 
 
 _ce.defvjp(_fwd, _bwd)

@@ -138,6 +138,7 @@ def _pallas_causal(x, scale):
         grid=(b, sq // rows),
         in_specs=[pl.BlockSpec(blk, idx)],
         out_specs=pl.BlockSpec(blk, idx),
+        name="apex_causal_softmax",
         interpret=pallas_config.interpret(),
     )(x)
 
@@ -234,6 +235,7 @@ def _pallas_blocked(x, mask, scale, causal):
         out_specs=[rowspec, rowspec],
         out_shape=[pallas_config.out_struct((b, sq), jnp.float32, *args)] * 2,
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32)] * 2,
+        name="apex_softmax_stats",
         interpret=pallas_config.interpret(),
     )(*args)
     return pl.pallas_call(
@@ -243,6 +245,7 @@ def _pallas_blocked(x, mask, scale, causal):
         in_specs=in_specs + [rowspec, rowspec],
         out_specs=xspec,
         out_shape=pallas_config.out_struct(x.shape, x.dtype, *args, m, l),
+        name="apex_softmax_apply",
         interpret=pallas_config.interpret(),
     )(*args, m, l)
 
@@ -269,6 +272,7 @@ def _pallas_masked(x, mask, scale):
         grid=(x3.shape[0], sq // rows),
         in_specs=[pl.BlockSpec(blk, idx), pl.BlockSpec(blk, idx)],
         out_specs=pl.BlockSpec(blk, idx),
+        name="apex_masked_softmax",
         interpret=pallas_config.interpret(),
     )(x3, mask3)
     return out.reshape(lead + (sq, sk))

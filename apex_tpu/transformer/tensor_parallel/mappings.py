@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.observability import span
+from apex_tpu.ops import vma as _vma
 from apex_tpu.transformer import parallel_state
 
 
@@ -58,7 +59,7 @@ def make_varying(x, axis: str):
     (transpose: psum). Idempotent: values already varying over ``axis``
     pass through. Public — model code, examples, and other subsystems
     need it whenever fresh values must match the vma of computed ones."""
-    return _to_varying(x, axis)
+    return _vma.to_varying(x, (axis,))
 
 
 def tree_vma(*trees) -> set:
@@ -67,28 +68,10 @@ def tree_vma(*trees) -> set:
     The standard companion to :func:`make_varying`: fresh zeros for scan
     carries / cond branches must be marked varying over exactly these
     axes to type-match values computed from the real inputs."""
-    axes: set = set()
-    for tree in trees:
-        for leaf in jax.tree_util.tree_leaves(tree):
-            try:
-                axes |= set(jax.typeof(leaf).vma)
-            except (AttributeError, TypeError):
-                pass
-    return axes
+    return set(_vma.vma(*trees))
 
 
-def _to_varying(x, axis: str):
-    """Mark a replicated value as device-varying (transpose: psum).
-    Idempotent: values already varying over ``axis`` pass through."""
-    try:
-        if axis in jax.typeof(x).vma:
-            return x
-    except (AttributeError, TypeError):
-        pass
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axis, to="varying")
-    return jax.lax.pvary(x, (axis,))
+_to_varying = make_varying
 
 
 def _to_invariant(x, axis: str):
@@ -96,10 +79,7 @@ def _to_invariant(x, axis: str):
     (e.g. an all_gather output, identical on every rank). jax has no claim
     primitive, so this divides by the axis size and psums — psum is the
     variant→invariant collective. XLA folds the scale into the reduce."""
-    try:
-        if axis not in jax.typeof(x).vma:
-            return x
-    except (AttributeError, TypeError):
+    if axis not in _vma.vma(x):
         return x
     n = jax.lax.axis_size(axis)
     return jax.lax.psum(x / n, axis)

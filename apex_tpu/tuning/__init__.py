@@ -3,7 +3,7 @@
 Every Pallas kernel *earns* its tiling and its dispatch verdict per
 device: search spaces are declared (VMEM-bounded) in
 :mod:`~apex_tpu.tuning.search_space`, candidates are raced against the
-XLA fallback by :mod:`~apex_tpu.tuning.measure` (real corrected-sync
+XLA fallback by :mod:`~apex_tpu.tuning.measure` (real on-device
 races on TPU, the kernel-cost-study roofline model as the deterministic
 CPU fallback), and winners persist in a schema-versioned JSON cache
 (:mod:`~apex_tpu.tuning.cache`) keyed by ``(device_kind, kernel,

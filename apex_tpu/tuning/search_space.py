@@ -119,9 +119,10 @@ def _flash_bwd_vmem(bq: int, bk: int, d: int) -> int:
 def flash_candidates(kind: str, sq: int, sk: int, d: int,
                      device_kind=None) -> list:
     """(block_q, block_kv) sweep for the flash ``kind`` pass. The kernel
-    clamps any tile to a divisor of the sequence (``_pick_block``), so a
-    candidate can never produce a non-dividing block at runtime; the
-    VMEM filter here keeps the sweep compile-safe."""
+    halves a tile toward a divisor of the sequence and pads the sequence
+    past that (``flash_attention._tile``), so a candidate can never
+    produce a non-dividing block at runtime; the VMEM filter here keeps
+    the sweep compile-safe."""
     if kind not in ("fwd", "bwd"):
         raise ValueError(f"flash kind must be fwd/bwd, got {kind!r}")
     vmem = _flash_fwd_vmem if kind == "fwd" else _flash_bwd_vmem

@@ -1,0 +1,726 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that apex_tpu still starts on the chip.
+
+    python chip_smoke.py
+
+One process, one TPU host. Drives the system's main paths once, through the
+entry points a user calls, at the full width of the models the repo
+supports (weights random, from a seed), and checks what comes out:
+
+- *kernels*  every Pallas kernel compiled by Mosaic against its jnp path,
+             at the shapes the two models below use;
+- *train*    GPT-2 345M (hidden 1024, 24 layers, vocab 50,304, S=1024,
+             B=8): ``amp.initialize("O2")`` -> ``policy.cast_model`` ->
+             ``gpt2.loss_fn(vocab_chunks=8)`` -> ``scaled_update(fused_adam)``,
+             donated state, 8 steps on one fixed batch;
+- *serve*    ``ServingEngine`` on ``llama.flagship_0p9b()`` with the live
+             page budget, eight requests of 64/520/1024-token prompts,
+             checked against ``models.generate.generate()``;
+- *mesh*     (4+ chips) the same GPT-2 step on a dp=2 x tp=2 mesh, and a
+             dp=4 DDP step through ``sync_autodiff_gradients``.
+
+Any phase that raises, or whose check fails, ends the run with a non-zero
+exit code. It refuses to run when jax selects anything but the TPU. The
+last line of standard output is one JSON object naming the device. The
+per-step wall times it prints are for information; none is a metric.
+"""
+
+from __future__ import annotations
+
+import functools
+import importlib.metadata
+import json
+import os
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from apex_tpu import amp
+from apex_tpu.models import generate as gen
+from apex_tpu.models import gpt2, llama
+from apex_tpu.observability import recompile
+from apex_tpu.ops import pallas_config
+from apex_tpu.optimizers import fused_adam, opt_partition_specs
+from apex_tpu.parallel import sync_autodiff_gradients
+from apex_tpu.runtime.timing import sync
+from apex_tpu.serving import ServingEngine, build_prefill
+from apex_tpu.transformer.tensor_parallel.mappings import make_varying
+
+_MOSAIC_CALL = "tpu_custom_call"
+# exit code of a refusal to run (no TPU; a tuning cache in the way) — apart
+# from 1 (a phase raised) and from the chip tool's own 2 and 3
+EXIT_REFUSED = 4
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+def _kernel_mode() -> str:
+    """How the kernels run here: compiled ('on') unless a test put the
+    process in interpret mode, which is how tier-1 runs these phases on
+    the CPU. The kernels phase forces this mode against 'off'; the model
+    phases run in the ambient mode ('auto' takes the compiled kernels on
+    a TPU) and prove by HLO that it took them."""
+    return "interpret" if pallas_config.mode() == "interpret" else "on"
+
+
+def _expect_mosaic(text: str, *kernels: str) -> None:
+    """The named Pallas kernels must appear in ``text`` (lowered or
+    compiled HLO) as Mosaic custom calls exactly when the kernels are
+    compiled — so a silent jnp path cannot pass, and the interpreter is
+    not mistaken for the chip."""
+    compiled = _kernel_mode() == "on"
+    for kernel in kernels:
+        found = _MOSAIC_CALL in text and kernel in text
+        if found != compiled:
+            raise AssertionError(
+                f"Mosaic custom call {kernel!r} "
+                f"{'missing from' if compiled else 'unexpectedly in'} the "
+                f"HLO (kernel mode {_kernel_mode()!r})")
+
+
+# ------------------------------------------------------------------ kernels
+
+# Shapes the two models below hand the kernels (flash: B, S, q heads, kv
+# heads, head dim — GQA 16/8 x 128 is flagship_0p9b; LayerNorm 8192 x 1024
+# and the [128, 1024, 1024] causal softmax are GPT-2 345M at B=8).
+KERNEL_SHAPES = dict(
+    flash=(2, 2048, 16, 8, 128), prefill_len=520,
+    norm_rows=8192, ln_hidden=1024, rms_hidden=2048,
+    causal_softmax=(128, 1024), masked_softmax=(64, 512),
+    adam_n=1_000_000, fp8=(2048, 2048))
+
+
+def _pallas_vs_jnp(fn, *args):
+    """``fn(*args)`` through the Pallas kernels and through the jnp path.
+    Fresh lambdas each time: jit's trace cache is keyed on the function
+    object, and the dispatch mode is read while tracing."""
+    with pallas_config.force(_kernel_mode()):
+        pallas_fn = jax.jit(lambda *a: fn(*a))
+        _expect_mosaic(pallas_fn.lower(*args).as_text(), "apex_")
+        got = sync(pallas_fn(*args))
+    with pallas_config.force("off"):
+        want = sync(jax.jit(lambda *a: fn(*a))(*args))
+    return got, want
+
+
+def _close(got, want, rtol=2e-2, atol=2e-2, what=""):
+    # bf16 kernel vs fp32-ish jnp path: loose but real tolerance
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        rtol=rtol, atol=atol, err_msg=what)
+
+
+def _close_flash_bwd(got, want, what, tol=5e-2, max_abs=0.5, frac=5e-4):
+    """Flash bwd vs autodiff of the jnp path, delta-cancellation aware.
+
+    The kernel uses the standard flash convention delta = sum(do * o) with
+    o saved in bf16; autodiff of the materialized softmax cancels
+    p*(dp - sum(p*dp)) EXACTLY for near-degenerate rows (causal row 0 sees
+    one key -> softmax == [1]). The kernel's residual there is bounded by
+    |do|*|o|*bf16_eps*sqrt(D), the same property as the CUDA flash kernels.
+    So: elementwise tol for ~all elements, a bounded violating fraction,
+    and a hard abs cap."""
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    diff = np.abs(got - want)
+    n_viol = int((diff > tol + tol * np.abs(want)).sum())
+    if n_viol > frac * diff.size or float(diff.max()) > max_abs:
+        raise AssertionError(
+            f"{what}: {n_viol}/{diff.size} elements beyond tol (allowed "
+            f"{int(frac * diff.size)}), max abs {float(diff.max()):.4f} "
+            f"(cap {max_abs})")
+
+
+def _norm_grads_close(got, want, rows, what):
+    """dx elementwise; dw/db are sums over ``rows`` of bf16-quantized
+    grads — the two paths round y to different bf16 ulps, and
+    sqrt(rows)-scaled quantization noise survives the reduction."""
+    _close(got[0], want[0], rtol=5e-2, atol=5e-1, what=f"{what} dx")
+    noise = float(4.0 * np.sqrt(rows) * 0.0078)
+    for name, g, w in zip(("dw", "db"), got[1:], want[1:]):
+        _close(g, w, rtol=5e-2, atol=noise, what=f"{what} {name}")
+
+
+def _qkv(shape, seed, dtype=jnp.bfloat16):
+    b, s, h, h_kv, d = shape
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(kq, (b, s, h, d), dtype),
+            jax.random.normal(kk, (b, s, h_kv, d), dtype),
+            jax.random.normal(kv, (b, s, h_kv, d), dtype))
+
+
+def _sq_loss(fn):
+    return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2)
+
+
+def _kernel_checks(shapes):
+    """(name, check) pairs; each check raises on a mismatch."""
+    from apex_tpu.ops.flash_attention import flash_attention
+    from apex_tpu.ops.fp8_cast_kernel import cast_and_scale_stats
+    from apex_tpu.ops.layer_norm import layer_norm, rms_norm
+    from apex_tpu.transformer.functional.fused_softmax import (
+        scaled_masked_softmax,
+        scaled_upper_triang_masked_softmax,
+    )
+
+    flash = shapes["flash"]
+    b, s, h, h_kv, d = flash
+    rows = shapes["norm_rows"]
+    causal = functools.partial(flash_attention, causal=True)
+    grad3 = lambda f: jax.grad(_sq_loss(f), argnums=(0, 1, 2))
+
+    def flash_fwd():
+        _close(*_pallas_vs_jnp(causal, *_qkv(flash, 0)), what="flash fwd")
+
+    def flash_bwd():
+        got, want = _pallas_vs_jnp(grad3(causal), *_qkv(flash, 1))
+        for name, g, w in zip("qkv", got, want):
+            _close_flash_bwd(g, w, f"flash d{name}")
+
+    def flash_prefill():
+        # a prompt bucket that is no multiple of any block: the kernel
+        # pads it to 128-row blocks and masks the padded keys
+        shape = (1, shapes["prefill_len"], h, h_kv, d)
+        _close(*_pallas_vs_jnp(causal, *_qkv(shape, 2)),
+               what="flash prefill")
+
+    def flash_varlen():
+        lens = jnp.asarray([s] + [max(1, s // (i + 2)) for i in range(b - 1)],
+                           jnp.int32)
+        _close(*_pallas_vs_jnp(
+            lambda q, k, v: flash_attention(q, k, v, kv_lens=lens),
+            *_qkv((b, s, h, h, d), 3)), what="flash varlen")
+
+    def flash_dropout():
+        # the same counter-based mask on both paths -> grads must agree
+        key = jax.random.PRNGKey(7)
+        drop = lambda q, k, v: flash_attention(
+            q, k, v, causal=True, dropout_p=0.25, dropout_key=key)
+        got, want = _pallas_vs_jnp(grad3(drop), *_qkv(flash, 4))
+        for name, g, w in zip("qkv", got, want):
+            _close_flash_bwd(g, w, f"flash dropout d{name}")
+
+    def ln_fwd_bwd():
+        hid = shapes["ln_hidden"]
+        x = jax.random.normal(jax.random.PRNGKey(5), (rows, hid),
+                              jnp.bfloat16)
+        w, bias = jnp.ones((hid,), jnp.float32), jnp.zeros((hid,),
+                                                           jnp.float32)
+        ln = lambda x, w, bias: layer_norm(x, w, bias, (hid,))
+        _norm_grads_close(*_pallas_vs_jnp(grad3(ln), x, w, bias), rows,
+                          "layer_norm")
+
+    def rms_fwd_bwd():
+        hid = shapes["rms_hidden"]
+        x = jax.random.normal(jax.random.PRNGKey(6), (rows, hid),
+                              jnp.bfloat16)
+        w = jnp.ones((hid,), jnp.float32)
+        rms = lambda x, w: rms_norm(x, w, (hid,))
+        _close(*_pallas_vs_jnp(rms, x, w), what="rms fwd")
+        _norm_grads_close(*_pallas_vs_jnp(
+            jax.grad(_sq_loss(rms), argnums=(0, 1)), x, w), rows, "rms_norm")
+
+    def ln_odd_rows():
+        hid = shapes["ln_hidden"]
+        x = jax.random.normal(jax.random.PRNGKey(8), (13, hid), jnp.bfloat16)
+        w, bias = jnp.ones((hid,), jnp.float32), jnp.zeros((hid,),
+                                                           jnp.float32)
+        _close(*_pallas_vs_jnp(
+            lambda x: layer_norm(x, w, bias, (hid,)), x), what="ln odd rows")
+
+    def causal_softmax():
+        bh, sm = shapes["causal_softmax"]
+        x = jax.random.normal(jax.random.PRNGKey(9), (bh, sm, sm),
+                              jnp.bfloat16)
+        _close(*_pallas_vs_jnp(
+            lambda x: scaled_upper_triang_masked_softmax(x, None, 1.0), x),
+            what="causal softmax")
+
+    def masked_softmax():
+        bh, sm = shapes["masked_softmax"]
+        x = jax.random.normal(jax.random.PRNGKey(10),
+                              (4, bh // 4, sm, sm), jnp.bfloat16)
+        mask = jax.random.uniform(jax.random.PRNGKey(11),
+                                  (4, 1, sm, sm)) > 0.8
+        _close(*_pallas_vs_jnp(
+            lambda x: scaled_masked_softmax(x, mask, 0.5), x),
+            what="masked softmax")
+
+    def flat_adam():
+        # not the default since the XLA chain won the cost study, but it
+        # must still execute when forced on: scalar (1, 4) block + slab
+        # padding are Mosaic-sensitive
+        params = {"a": jax.random.normal(jax.random.PRNGKey(12),
+                                         (shapes["adam_n"],)),
+                  "b": jax.random.normal(jax.random.PRNGKey(13), (137,))}
+        grads = jax.tree_util.tree_map(lambda p: p * 1e-2, params)
+
+        def one_step(use_kernel):
+            tx = fused_adam(lr=1e-3, weight_decay=0.01, flat=True,
+                            use_kernel=use_kernel)
+            step = jax.jit(lambda g, s, p: tx.update(g, s, p)[0])
+            state = tx.init(params)
+            if use_kernel:
+                _expect_mosaic(step.lower(grads, state, params).as_text(),
+                               "apex_flat_adam")
+            return sync(step(grads, state, params))
+
+        with pallas_config.force(_kernel_mode()):
+            got = one_step(True)
+        want = one_step(False)
+        for k in params:
+            _close(got[k], want[k], rtol=1e-5, atol=1e-6, what=f"adam {k}")
+
+    def fp8_cast():
+        x = jax.random.normal(jax.random.PRNGKey(14), shapes["fp8"],
+                              jnp.bfloat16) * 3.0
+        cast = lambda x: cast_and_scale_stats(
+            x, jnp.float32(17.0), jnp.float8_e4m3fn, 448.0)
+        (got_y, got_amax), (want_y, want_amax) = _pallas_vs_jnp(cast, x)
+        if got_y.dtype != jnp.float8_e4m3fn:
+            raise AssertionError(f"fp8 cast produced {got_y.dtype}")
+        # one E4M3 ulp (3 mantissa bits) wherever the two converters round
+        # a tie differently; amax is a max of exact bf16 values
+        _close(got_y, want_y, rtol=0.13, atol=2.0 ** -9, what="fp8 y")
+        _close(got_amax, want_amax, rtol=0, atol=0, what="fp8 amax")
+
+    return [("flash_fwd_causal_gqa", flash_fwd),
+            ("flash_bwd_causal_gqa", flash_bwd),
+            ("flash_fwd_prefill_len", flash_prefill),
+            ("flash_varlen", flash_varlen),
+            ("flash_dropout_fwd_bwd", flash_dropout),
+            ("layer_norm_fwd_bwd", ln_fwd_bwd),
+            ("rms_norm_fwd_bwd", rms_fwd_bwd),
+            ("layer_norm_odd_rows", ln_odd_rows),
+            ("causal_softmax", causal_softmax),
+            ("masked_softmax", masked_softmax),
+            ("flat_adam_kernel", flat_adam),
+            ("fp8_cast_kernel", fp8_cast)]
+
+
+def phase_kernels(shapes=KERNEL_SHAPES) -> dict:
+    """Every Pallas kernel, compiled, against its jnp path."""
+    checks = _kernel_checks(shapes)
+    for name, check in checks:
+        t0 = time.perf_counter()
+        check()
+        say(f"  PASS {name} ({time.perf_counter() - t0:.1f}s)")
+    return {"checks": len(checks)}
+
+
+# -------------------------------------------------------------------- train
+
+TRAIN_LR = 1e-4
+VOCAB_CHUNKS = 8
+
+
+def _gpt2_setup(cfg, batch):
+    """(handle, tx, init_state, batch): the O2 composition of
+    tests/L1/l1_harness.py at ``cfg``. ``init_state()`` builds
+    ``(params, opt_state, scaler_state)`` — fp32 master weights (the
+    model copy is cast to bf16 inside the step), from a fixed seed."""
+    handle = amp.initialize(opt_level="O2", verbosity=0)
+    tx = fused_adam(lr=TRAIN_LR)
+
+    def init_state():
+        params = jax.tree_util.tree_map(
+            lambda p: p.astype(jnp.float32),
+            gpt2.init_params(jax.random.PRNGKey(0), cfg))
+        return params, tx.init(params), handle.scaler.init()
+
+    tokens = jax.random.randint(jax.random.PRNGKey(1),
+                                (batch, cfg.max_seq_len), 0, cfg.vocab_size)
+    return handle, tx, init_state, (tokens, jnp.roll(tokens, -1, axis=-1))
+
+
+def _gpt2_step(handle, tx, cfg, tp_axis=None, reduction=None,
+               overflow_axes=()):
+    """``(params, opt_state, scaler_state, batch) -> (..., loss)``: one
+    O2 step. ``reduction`` is the mesh's gradient scheme (``prepare`` the
+    parameters, ``reduce`` gradients and loss); None on one chip."""
+
+    def step(params, opt_state, sstate, batch):
+        def scaled(p):
+            if reduction is not None:
+                p = reduction.prepare(p)
+            loss = gpt2.loss_fn(handle.policy.cast_model(p), batch, cfg,
+                                tp_axis=tp_axis, vocab_chunks=VOCAB_CHUNKS)
+            return handle.scaler.scale_loss(loss, sstate), loss
+
+        grads, loss = jax.grad(scaled, has_aux=True)(params)
+        if reduction is not None:
+            grads, loss = reduction.reduce(grads, loss)
+        updates, opt_state, sstate, _ = handle.scaled_update(
+            tx, grads, opt_state, params, sstate,
+            overflow_reduce_axes=overflow_axes)
+        return optax.apply_updates(params, updates), opt_state, sstate, loss
+
+    return step
+
+
+def _run_steps(compiled, state, batch, steps):
+    """Drive ``steps`` donated steps of an AOT-compiled train step; returns
+    (final state, losses). Nothing may compile after step 1."""
+    listener = recompile.install()
+    losses, compiles_after_first = [], None
+    for i in range(steps):
+        t0 = time.perf_counter()
+        *state, loss = compiled(*state, batch)
+        loss = float(sync(loss))
+        losses.append(loss)
+        say(f"  step {i}: loss {loss:.4f}  "
+            f"({(time.perf_counter() - t0) * 1e3:.0f} ms wall, "
+            f"for information)")
+        if i == 0:
+            compiles_after_first = listener.backend_compiles()
+    extra = listener.backend_compiles() - compiles_after_first
+    if extra:
+        raise AssertionError(
+            f"{extra} program(s) compiled after step 1: "
+            f"{listener.compiles()}")
+    return state, losses
+
+
+def _check_losses(losses, lo, hi):
+    if not all(np.isfinite(losses)):
+        raise AssertionError(f"non-finite loss: {losses}")
+    if not lo <= losses[0] <= hi:
+        raise AssertionError(
+            f"first loss {losses[0]:.4f} outside [{lo}, {hi}]")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(
+            f"loss did not fall: {losses[0]:.4f} -> {losses[-1]:.4f}")
+
+
+def phase_train(cfg=None, batch=8, steps=8) -> dict:
+    """GPT-2 345M, amp O2 + FusedAdam, donated state, one fixed batch."""
+    cfg = cfg or gpt2.gpt2_345m()
+    handle, tx, init_state, data = _gpt2_setup(cfg, batch)
+    state = init_state()
+    n_params = sum(p.size for p in jax.tree_util.tree_leaves(state[0]))
+    say(f"  gpt2: {n_params / 1e6:.1f}M params, hidden {cfg.hidden_size}, "
+        f"{cfg.num_layers} layers, vocab {cfg.vocab_size}, "
+        f"B={batch} S={cfg.max_seq_len}")
+    step = jax.jit(_gpt2_step(handle, tx, cfg), donate_argnums=(0, 1, 2))
+    t0 = time.perf_counter()
+    compiled = step.lower(*state, data).compile()
+    say(f"  train step compiled in {time.perf_counter() - t0:.1f}s")
+    _expect_mosaic(compiled.as_text(), "apex_ln_fwd", "apex_ln_bwd",
+                   "apex_causal_softmax")
+    state, losses = _run_steps(compiled, state, data, steps)
+    # ln(vocab) plus half the init's logit variance (~1)
+    ln_v = float(np.log(cfg.vocab_size))
+    _check_losses(losses, ln_v - 0.35, ln_v + 1.2)
+    skipped = int(state[2].overflows)
+    if skipped >= steps:
+        raise AssertionError(f"the loss scaler skipped all {steps} steps")
+    say(f"  loss {losses[0]:.4f} -> {losses[-1]:.4f}; scaler skipped "
+        f"{skipped}/{steps} steps, scale {float(state[2].loss_scale):.0f}")
+    return {"first_loss": losses[0], "last_loss": losses[-1]}
+
+
+# -------------------------------------------------------------------- serve
+
+# (prompt length, new tokens): three shapes, so generate() compiles three
+# programs; 520 is a prompt bucket no flash block divides
+SERVE_MIX = ((64, 128), (520, 96), (1024, 64))
+# A token may differ from generate()'s where bf16 reduction order decides
+# a near-tie (one [8, h] decode batch against eight [1, h] ones; a page
+# gather against a contiguous cache) — on a v5e 7 of 8 requests do, after
+# 18-60 equal tokens. Then both tokens' logits, from the TRAINING forward
+# on the shared prefix, must lie within this of the best. The logits come
+# out of a bf16 matmul: the winning ones here are 4-8 in magnitude, where
+# one bf16 step is 2^-5; four steps are allowed (two were seen), against a
+# typical gap of ~4 between the best logit and an arbitrary one.
+LOGIT_TIE_TOL = 2.0 ** -3
+
+
+def _reference_logits(params, cfg, length):
+    """Jitted ``(tokens [1, length], n) -> fp32 logits after n tokens`` by
+    the TRAINING forward — the arbiter of a serving/generate() near-tie."""
+
+    # the weights are an argument: closed over, they would be baked into
+    # the executable as a constant too large for the compile cache to write
+    @jax.jit
+    def logits_at(params, tokens, n):
+        logits = llama.forward(params, tokens, cfg, tp_axis=None,
+                               cp_axis=None, remat=False)
+        return jax.lax.dynamic_index_in_dim(logits[0], n - 1, axis=0,
+                                            keepdims=False)
+
+    return lambda prefix: np.asarray(logits_at(
+        params,
+        jnp.asarray(np.pad(prefix, (0, length - len(prefix)))[None]),
+        np.int32(len(prefix))))
+
+
+def phase_serve(cfg=None, mix=SERVE_MIX, requests=8, max_batch=8,
+                page_size=8) -> dict:
+    """Continuous batching on the flagship llama against generate()."""
+    cfg = cfg or llama.flagship_0p9b()
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    max_prompt = max(p for p, _ in mix)
+    max_new = max(n for _, n in mix)
+    listener = recompile.install()
+    decode_compiles0 = listener.compiles("_decode_step")
+    engine = ServingEngine(
+        params, cfg, page_size=page_size, max_batch=max_batch,
+        num_pages=None, max_prompt_len=max_prompt, max_new_cap=max_new)
+    cache = engine.scheduler.cache
+    stats = jax.devices()[0].memory_stats() or {}
+    say(f"  page budget: {engine.page_budget}")
+    say(f"  pages in use {cache.num_pages} (+1 trash), k_pages "
+        f"{tuple(cache.k_pages.shape)} {cache.k_pages.dtype}: modeled "
+        f"{cache.hbm_bytes() // 2} B, on device "
+        f"{cache.k_pages.on_device_size_in_bytes()} B; device "
+        f"bytes_in_use {stats.get('bytes_in_use')} peak "
+        f"{stats.get('peak_bytes_in_use')}")
+    rng = np.random.default_rng(0)
+    prompts = {}
+    for i in range(requests):
+        p_len, new = mix[i % len(mix)]
+        prompt = rng.integers(0, cfg.vocab_size, p_len, dtype=np.int32)
+        prompts[engine.submit(prompt, new)] = (prompt, new)
+    t0 = time.perf_counter()
+    results = engine.run()      # raises if the decode step retraced
+    say(f"  {len(results)} requests served in "
+        f"{time.perf_counter() - t0:.1f}s wall (compiles included, for "
+        f"information): {engine.scheduler.prefill_count} prefills, "
+        f"{engine.scheduler.decode_steps} decode steps")
+    if sorted(results) != sorted(prompts):
+        raise AssertionError(
+            f"finished {sorted(results)}, submitted {sorted(prompts)}")
+    n_decode = listener.compiles("_decode_step") - decode_compiles0
+    if n_decode != 1:
+        raise AssertionError(f"decode step compiled {n_decode}x, not 1")
+    for p_len, _ in mix:
+        bucket = -(-p_len // page_size) * page_size
+        _expect_mosaic(build_prefill(cfg, bucket).lower(
+            params, {}, jnp.zeros((1, bucket), jnp.int32),
+            np.int32(p_len)).as_text(), "apex_flash_fwd")
+
+    # ---- the reference: generate(), one request at a time
+    generate = jax.jit(gen.generate, static_argnums=(2, 3))
+    ref_logits = None
+    exact = ties = 0
+    for rid, (prompt, new) in sorted(prompts.items()):
+        got = results[rid]["tokens"]
+        want = np.asarray(generate(params, jnp.asarray(prompt[None]),
+                                   cfg, new))[0, len(prompt):].tolist()
+        if len(got) != new:
+            raise AssertionError(
+                f"request {rid}: {len(got)} tokens, asked for {new}")
+        if got == want:
+            exact += 1
+            continue
+        t = next(i for i in range(new) if got[i] != want[i])
+        if ref_logits is None:
+            ref_logits = _reference_logits(
+                params, cfg, max_prompt + max_new)
+        logits = ref_logits(np.concatenate(
+            [prompt, np.asarray(want[:t], np.int32)]))
+        gap = float(logits.max() - min(logits[got[t]], logits[want[t]]))
+        say(f"  request {rid} (prompt {len(prompt)}): token {t} differs "
+            f"({got[t]} vs generate's {want[t]}); reference logit gap "
+            f"to the best {gap:.4f} (tolerance {LOGIT_TIE_TOL})")
+        if gap > LOGIT_TIE_TOL:
+            raise AssertionError(
+                f"request {rid}: serving and generate() disagree at "
+                f"token {t} beyond a bf16 near-tie (gap {gap:.4f})")
+        ties += 1
+    say(f"  tokens equal generate() on {exact}/{requests} requests; {ties} "
+        f"diverged at a bf16 near-tie, checked on logits")
+    return {"exact": exact, "near_ties": ties}
+
+
+# --------------------------------------------------------------------- mesh
+
+
+class _DpTpSync:
+    """examples/gpt2_train.py's scheme on a ('dp', 'tp') mesh: every
+    parameter is made varying over both axes, so every gradient arrives
+    per-device and is averaged by hand — over dp always, over tp for the
+    parameters tp does not shard."""
+
+    def __init__(self, specs):
+        self.specs = specs
+
+    @staticmethod
+    def prepare(params):
+        for axis in ("dp", "tp"):
+            params = jax.tree_util.tree_map(
+                lambda a, axis=axis: make_varying(a, axis), params)
+        return params
+
+    def reduce(self, grads, loss):
+        pmean = lambda t, axis: jax.lax.pmean(make_varying(t, axis), axis)
+        grads = jax.tree_util.tree_map(lambda g: pmean(g, "dp"), grads)
+        grads = jax.tree_util.tree_map(
+            lambda g, s: g if "tp" in s else pmean(g, "tp"), grads,
+            self.specs)
+        return grads, pmean(pmean(loss, "dp"), "tp")
+
+
+class _DdpSync:
+    """Replicated parameters over a ('dp',) mesh: autodiff and the fused
+    kernels' VJP rules hand back summed gradients, and
+    ``sync_autodiff_gradients`` turns them into the global-batch mean."""
+
+    @staticmethod
+    def prepare(params):
+        return params
+
+    @staticmethod
+    def reduce(grads, loss):
+        return (sync_autodiff_gradients(grads, axis_name="dp"),
+                jax.lax.pmean(loss, "dp"))
+
+
+def _mesh_step_loss(mesh, cfg, batch, param_specs, reduction, tp_axis,
+                    overflow_axes):
+    """Build the state SHARDED over ``mesh`` (never whole on device 0),
+    take one step under shard_map, and return the loss after checking the
+    parameters' placement and every device's memory."""
+    handle, tx, init_state, data = _gpt2_setup(cfg, batch)
+    shapes = jax.eval_shape(init_state)
+    specs = (param_specs, opt_partition_specs(tx, shapes[0], param_specs),
+             jax.tree_util.tree_map(lambda _: P(), shapes[2]))
+    named = lambda tree: jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), tree,
+        is_leaf=lambda x: isinstance(x, P))
+    state = jax.jit(init_state, out_shardings=named(specs))()
+    data_spec = (P("dp", None), P("dp", None))
+    data = jax.device_put(data, named(data_spec))
+    step = jax.jit(shard_map(
+        _gpt2_step(handle, tx, cfg, tp_axis=tp_axis, reduction=reduction,
+                   overflow_axes=overflow_axes),
+        mesh=mesh, in_specs=(*specs, data_spec), out_specs=(*specs, P())),
+        donate_argnums=(0, 1, 2))
+    *state, loss = step(*state, data)
+    loss = float(sync(loss))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(state[0]):
+        if leaf.sharding.device_set != set(mesh.devices.flat):
+            raise AssertionError(
+                f"parameter {jax.tree_util.keystr(path)} lives on "
+                f"{len(leaf.sharding.device_set)} of {mesh.size} devices")
+    in_use = [(d.memory_stats() or {}).get("bytes_in_use")
+              for d in mesh.devices.flat]
+    say(f"    bytes_in_use per device: {in_use}")
+    if None not in in_use:       # the CPU reports no allocator stats
+        param_bytes = sum(l.nbytes for l in
+                          jax.tree_util.tree_leaves(state[0]))
+        if min(in_use) < param_bytes // (4 * mesh.size):
+            raise AssertionError(
+                f"a device holds almost nothing: {in_use} (parameters "
+                f"total {param_bytes} B over {mesh.size} devices)")
+    return loss
+
+
+def phase_mesh(reference_loss, cfg=None, batch=8, rtol=2e-2) -> dict:
+    """The GPT-2 step on four chips: dp=2 x tp=2 as examples/gpt2_train.py
+    builds it, and dp=4 DDP. Each first loss must equal the one-chip loss
+    for the same global batch within bf16 tolerance."""
+    cfg = cfg or gpt2.gpt2_345m()
+    devices = np.array(jax.devices()[:4])
+    tp_specs = gpt2.param_specs(cfg)
+    replicated = jax.tree_util.tree_map(
+        lambda _: P(), tp_specs, is_leaf=lambda x: isinstance(x, P))
+    losses = {}
+    for name, mesh, specs, reduction, tp_axis, axes in (
+            ("dp2_tp2", Mesh(devices.reshape(2, 2), ("dp", "tp")), tp_specs,
+             _DpTpSync(tp_specs), "tp", ("dp", "tp")),
+            ("ddp4", Mesh(devices, ("dp",)), replicated, _DdpSync(), None,
+             ())):
+        say(f"  {name}:")
+        loss = _mesh_step_loss(mesh, cfg, batch, specs, reduction, tp_axis,
+                               axes)
+        say(f"    loss {loss:.4f} (one chip {reference_loss:.4f})")
+        if abs(loss - reference_loss) > rtol * abs(reference_loss):
+            raise AssertionError(
+                f"{name} loss {loss:.4f} != one-chip loss "
+                f"{reference_loss:.4f} (rtol {rtol})")
+        losses[name] = loss
+    return losses
+
+
+# --------------------------------------------------------------------- main
+
+
+def _count_cache_events(counts):
+    def on_event(name, **_kw):
+        if name.startswith("/jax/compilation_cache/cache_"):
+            counts[name.rsplit("/", 1)[-1]] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+
+
+def main() -> int:
+    backend = jax.default_backend()
+    if backend != "tpu":
+        print(f"chip_smoke: no TPU — jax selected the {backend!r} backend; "
+              f"nothing was run", file=sys.stderr)
+        return EXIT_REFUSED
+
+    from apex_tpu.runtime import runtime_available
+    from apex_tpu.runtime.compile_cache import enable_compile_cache
+    from apex_tpu.tuning import cache as tuning_cache
+
+    cache_dir = enable_compile_cache()
+    cache_events = {"cache_hits": 0, "cache_misses": 0}
+    _count_cache_events(cache_events)
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    versions = {p: importlib.metadata.version(p)
+                for p in ("jax", "jaxlib", "libtpu")}
+    say(f"chip_smoke: {device['count']} x {device['kind']} "
+        f"({device['platform']}); {versions}")
+    say(f"compile cache: {cache_dir}")
+    say("host runtime: " + (
+        "native (csrc/libapex_tpu_host.so)" if runtime_available()
+        else "numpy fallback (csrc/ did not build or load)"))
+    if os.path.exists(tuning_cache.cache_path()):
+        # a tuning cache changes tiles and Pallas-vs-XLA verdicts; this
+        # run proves the defaults
+        print(f"chip_smoke: tuning cache {tuning_cache.cache_path()} "
+              f"present — move it away, the smoke runs with none",
+              file=sys.stderr)
+        return EXIT_REFUSED
+    say("tuning cache: none (default tiles and kernel verdicts)")
+
+    t_start = time.perf_counter()
+
+    def run(name, phase, *args):
+        say(f"[{name}]")
+        t0 = time.perf_counter()
+        out = phase(*args)
+        say(f"[{name}] ok in {time.perf_counter() - t0:.0f}s")
+        jax.clear_caches()      # drop executables pinning donated buffers
+        return out
+
+    run("kernels", phase_kernels)
+    train = run("train", phase_train)
+    run("serve", phase_serve)
+    if device["count"] >= 4:
+        run("mesh", phase_mesh, train["first_loss"])
+    else:
+        say(f"[mesh] skipped: {device['count']} device(s) visible, the "
+            f"dp=2 x tp=2 and dp=4 meshes need 4")
+    say(f"all phases ok in {time.perf_counter() - t_start:.0f}s; compile "
+        f"cache {cache_events['cache_hits']} hits, "
+        f"{cache_events['cache_misses']} misses")
+    # exactly these keys: the driver parses this line
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -41,10 +41,7 @@ def main():
 
     from jax.sharding import Mesh, PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from apex_tpu.models import gpt2
     from apex_tpu.optimizers import fused_adam

@@ -208,10 +208,7 @@ def main():
 
     from jax.sharding import Mesh, PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     import apex_tpu.amp as amp
     from apex_tpu.checkpoint import CheckpointManager
@@ -286,11 +283,9 @@ def main():
             return scaler.scale_loss(loss, sstate), (loss, mut["batch_stats"])
 
         grads, (loss, new_stats) = jax.grad(loss_fn, has_aux=True)(master)
-        # DDP allreduce: with check_rep=False (jax 0.4.37's replication
-        # checker rejects these out_specs, and disabling it also
-        # disables the auto-psum/vma repair the old
-        # sync_autodiff_gradients path relied on) EVERY grad leaf
-        # arrives per-rank local — reduce them all explicitly
+        # DDP allreduce: this body runs with check_vma=False, so nothing
+        # is typed and autodiff sums nothing for us — EVERY grad leaf
+        # arrives per-rank local; reduce them all explicitly
         grads = jax.tree_util.tree_map(
             lambda g: jax.lax.pmean(g, "data"), grads)
         if args.no_sync_bn:
@@ -314,20 +309,20 @@ def main():
         return (jax.lax.psum(c1, "data"), jax.lax.psum(c5, "data"))
 
     stats_specs = jax.tree_util.tree_map(lambda _: P(), batch_stats)
-    # check_rep=False: 0.4.37's replication checker cannot statically
-    # infer these P() out_specs (the numerics are kept honest by the
-    # explicit pmean above — sync_bn already psums its statistics)
+    # check_vma=False: an untyped body whose P() out_specs are kept
+    # honest by the explicit pmeans above (sync_bn already psums its
+    # statistics) — and by the spmd analysis target that traces it
     step = jax.jit(shard_map(
         train_step, mesh=mesh,
         in_specs=(P(), P(), P(), stats_specs, P("data"), P("data")),
         out_specs=(P(), P(), P(), stats_specs, P(), P()),
-        check_rep=False,
+        check_vma=False,
     ))
     evalf = jax.jit(shard_map(
         eval_step, mesh=mesh,
         in_specs=(P(), stats_specs, P("data"), P("data")),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     ))
 
     # ------------------------------------------------------ resume / ckpt

@@ -81,10 +81,9 @@ def main():
         from apex_tpu.parallel import auto_shard
 
         # min tp=2: this step's vocab-parallel CE / sequence-parallel
-        # collectives assume a bound tp axis, and jax 0.4.37's shard_map
-        # cannot statically infer out_specs replication over a tp=1
-        # mesh — the executability floor rides the plan request so the
-        # search never emits a mesh this runtime cannot execute.
+        # collectives assume a bound tp axis — the executability floor
+        # rides the plan request so the search never emits a mesh this
+        # example cannot execute.
         # The run-derived knobs that shape the cost model's comms and
         # bubble terms ride along (seq scales activation bytes,
         # microbatches the pipeline bubble; batch/layers anchored at
@@ -106,10 +105,7 @@ def main():
 
     from jax.sharding import Mesh, PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from apex_tpu.models import llama
     from apex_tpu.optimizers import fused_adam

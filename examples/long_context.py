@@ -46,10 +46,7 @@ def main():
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from apex_tpu.models import llama
     from apex_tpu.optimizers import fused_adam

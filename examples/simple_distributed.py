@@ -9,12 +9,11 @@ gradients inside the jitted step. The script verifies the synced gradient
 equals the gradient of the global batch computed on one device — the
 invariant the reference's multi-process test asserts.
 
-Numerics note (jax 0.4.37 at HEAD): the container's shard_map replication
-checker rejects ``out_specs=P()`` it cannot statically infer, and with
-``check_rep=False`` the transpose no longer auto-psums grads of
-replicated params — they arrive per-rank LOCAL. The step therefore does
-the DDP reduction explicitly (``lax.pmean`` over 'data'), which is also
-what makes it checkable: the step is a registered
+Numerics note: the step runs with ``check_vma=False`` — an untyped body,
+where the transpose does not auto-psum grads of replicated params; they
+arrive per-rank LOCAL. The step therefore does the DDP reduction
+explicitly (``lax.pmean`` over 'data'), which is also what makes it
+checkable: the step is a registered
 ``apex_tpu.analysis`` spmd-checks target (``spmd_simple_distributed``),
 so dropping the pmean fails tier-1 as a ``rank-divergent-update``.
 """
@@ -36,7 +35,7 @@ def make_train_step(tx):
     'data', fused-adam update, replicated outputs."""
 
     def train_step(w, opt_state, x, y):
-        # w is replicated (in_specs P()); with check_rep=False the
+        # w is replicated (in_specs P()); with check_vma=False the
         # shard_map transpose does NOT auto-psum its grads, so each
         # rank holds the grad of its local shard — reduce explicitly.
         # pmean of per-shard mean-grads == the global-batch mean grad
@@ -58,10 +57,7 @@ def main():
 
     from jax.sharding import Mesh, PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from apex_tpu.optimizers import fused_adam
 
@@ -79,7 +75,7 @@ def main():
         train_step, mesh=mesh,
         in_specs=(P(), P(), P("data"), P("data")),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     ))
 
     # invariant: synced grad == single-device grad of the global batch

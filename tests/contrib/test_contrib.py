@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from apex_tpu.contrib.clip_grad import clip_grad_norm_
 from apex_tpu.contrib.conv_bias_relu import ConvBias, ConvBiasMaskReLU, ConvBiasReLU

@@ -65,7 +65,7 @@ def test_synced_local_grads_equal_global_batch_grads(flat):
     @jax.jit
     def ddp_grads(w, x, y):
         def shard_fn(w, x, y):
-            w_local = jax.lax.pvary(w, ("data",))  # per-replica copy
+            w_local = jax.lax.pcast(w, ("data",), to="varying")  # per-replica copy
             g = jax.grad(local_loss)(w_local, x, y)
             g = sync({"w": g}, axis_name="data")["w"]
             return jax.lax.psum(g, "data") / jax.lax.axis_size("data")  # unvary for P() out
@@ -208,10 +208,13 @@ def test_shared_param_rejected():
 
 
 def test_sync_autodiff_gradients_custom_vjp_mixed_tree():
-    """custom_vjp hides the replicated-param broadcast from transposition,
-    so its param grads arrive per-device LOCAL while plain-op grads arrive
-    auto-psummed (distributed.py module-note caveat). The vma-aware sync
-    must land the identical global-batch-mean gradient for both kinds."""
+    """Under check_vma a custom_vjp must return cotangents typed like its
+    primals, so a rule written with ``cotangent_like`` hands back the
+    replicated param's grad summed and invariant — the same kind plain-op
+    autodiff produces. A param the caller made varying arrives per-device
+    LOCAL. The vma-aware sync must land the identical global-batch-mean
+    gradient for all three kinds, reducing none of them twice."""
+    from apex_tpu.ops.vma import cotangent_like, to_varying
     from apex_tpu.parallel import sync_autodiff_gradients
 
     @jax.custom_vjp
@@ -223,21 +226,25 @@ def test_sync_autodiff_gradients_custom_vjp_mixed_tree():
 
     def bwd(res, g):
         x, w = res
-        return g * w, jnp.sum(g * x, axis=0)
+        return g * w, cotangent_like(jnp.sum(g * x, axis=0), w)
 
     myscale.defvjp(fwd, bwd)
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
-    params = {"plain": jnp.arange(4.0), "cvjp": jnp.arange(4.0) + 1}
+    params = {"plain": jnp.arange(4.0), "cvjp": jnp.arange(4.0) + 1,
+              "local": jnp.arange(4.0) + 2}
     x = jax.random.normal(jax.random.PRNGKey(0), (16, 4))
 
     def loss(p, x):
-        return jnp.mean((x * p["plain"]) ** 2 + myscale(x, p["cvjp"]) ** 2)
+        return jnp.mean((x * p["plain"]) ** 2 + myscale(x, p["cvjp"]) ** 2
+                        + (x * p["local"]) ** 2)
 
     def shard_fn(p, x):
+        p = dict(p, local=to_varying(p["local"], ("data",)))
         g = jax.grad(loss)(p, x)
         # the precondition this helper exists for: mixed vma tree
-        assert "data" in jax.typeof(g["cvjp"]).vma
+        assert "data" in jax.typeof(g["local"]).vma
+        assert "data" not in jax.typeof(g["cvjp"]).vma
         assert "data" not in jax.typeof(g["plain"]).vma
         return sync_autodiff_gradients(g, axis_name="data")
 

@@ -14,6 +14,15 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
+def test_launcher_refuses_local_workers_off_the_cpu():
+    """One process drives every chip of a host: N local workers without
+    --cpu would each claim all of them. Refused before anything spawns."""
+    from apex_tpu.parallel.multiproc import launch
+
+    with pytest.raises(ValueError, match="one worker per host"):
+        launch(["worker.py"], nprocs=2)
+
+
 @pytest.mark.slow
 def test_launcher_two_processes_psum(tmp_path):
     script = tmp_path / "worker.py"

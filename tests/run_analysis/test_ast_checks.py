@@ -588,7 +588,7 @@ for x in xs:
     except Exception:
         continue
 """
-    for path in ("bench.py", "tools/relay_hunter.py", "snippet.py"):
+    for path in ("bench.py", "tools/tpu_profile.py", "snippet.py"):
         assert not _by_check(lint_source(src, path), _SWALLOW)
     assert _by_check(lint_source(src, "train.py",
                                  abspath="/ck/apex_tpu/train.py"),

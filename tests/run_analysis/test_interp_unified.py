@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-import apex_tpu  # noqa: F401  (installs the 0.4.37 shims)
 from apex_tpu.analysis import interp
 from apex_tpu.analysis.dataflow import (
     PRECISION_LATTICE,
@@ -124,7 +123,7 @@ def test_combined_walk_matches_single_engines_on_mixed_program():
                      taints=frozenset({"grad"}) if i == 0 else
                      frozenset())
               for i, a in enumerate(args)]
-    s_vals = [shard_val_for_aval(jax.core.get_aval(a),
+    s_vals = [shard_val_for_aval(jax.typeof(a),
                                  P("tp", None) if i == 0 else
                                  P("dp", None))
               for i, a in enumerate(args)]
@@ -134,7 +133,7 @@ def test_combined_walk_matches_single_engines_on_mixed_program():
 def test_combined_walk_matches_single_engines_through_shard_map():
     closed, args = _shard_map_fn()
     p_vals = [None for _ in args]
-    s_vals = [shard_val_for_aval(jax.core.get_aval(a), P("tp", None))
+    s_vals = [shard_val_for_aval(jax.typeof(a), P("tp", None))
               for a in args]
     _assert_identical(closed, p_vals, s_vals)
 
@@ -154,10 +153,10 @@ def test_estimate_linearization_cache_is_pure():
     second call (same or different in_vals) must not be perturbed by
     the first."""
     closed, args = _mixed_fn()
-    aval = jax.core.get_aval(args[0])
-    sharded = [shard_val_for_aval(jax.core.get_aval(a), P("tp", None))
+    aval = jax.typeof(args[0])
+    sharded = [shard_val_for_aval(jax.typeof(a), P("tp", None))
                for a in args]
-    replicated = [shard_val_for_aval(jax.core.get_aval(a), P())
+    replicated = [shard_val_for_aval(jax.typeof(a), P())
                   for a in args]
     first = estimate_hbm_and_comms(closed, sharded, axis_sizes=SIZES)
     again = estimate_hbm_and_comms(closed, sharded, axis_sizes=SIZES)

@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-import apex_tpu  # noqa: F401  (installs the 0.4.37 shims)
 from apex_tpu.analysis.sharding_checks import (
     SHARDING_CHECKS,
     analyze_sharding,
@@ -173,7 +172,7 @@ def test_psum_scatter_raw_pattern():
         return jax.lax.dynamic_slice_in_dim(y, r * 4, 4, axis=0)
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=P(None, "tp"),
-                       out_specs=P("tp"), check_rep=False)
+                       out_specs=P("tp"), check_vma=False)
     f = analyze_sharding(fn, jnp.zeros((16, 16)), axis_sizes=SIZES)
     hits = _checks(f, "psum-scatter")
     assert len(hits) == 1
@@ -196,7 +195,7 @@ def test_psum_scatter_via_mappings_composition():
         return scatter_to_tensor_model_parallel_region(y, "tp")
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=P(None, "tp"),
-                       out_specs=P(None, "tp"), check_rep=False)
+                       out_specs=P(None, "tp"), check_vma=False)
     f = analyze_sharding(fn, jnp.zeros((16, 16)), axis_sizes=SIZES)
     assert _checks(f, "psum-scatter")
 
@@ -215,7 +214,7 @@ def test_psum_scatter_clean_when_scattered_properly():
         return reduce_scatter_to_tensor_model_parallel_region(x, "tp")
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=P(None, "tp"),
-                       out_specs=P(None, "tp"), check_rep=False)
+                       out_specs=P(None, "tp"), check_vma=False)
     f = analyze_sharding(fn, jnp.zeros((16, 16)), axis_sizes=SIZES)
     assert not _checks(f, "psum-scatter")
     # slicing something that is NOT a psum result is also clean
@@ -224,7 +223,7 @@ def test_psum_scatter_clean_when_scattered_properly():
         return jax.lax.dynamic_slice_in_dim(x, r * 4, 4, axis=0)
 
     fn2 = jax.shard_map(body2, mesh=_mesh(), in_specs=P(None, "tp"),
-                        out_specs=P("tp"), check_rep=False)
+                        out_specs=P("tp"), check_vma=False)
     f = analyze_sharding(fn2, jnp.zeros((16, 16)), axis_sizes=SIZES)
     assert not _checks(f, "psum-scatter")
 
@@ -243,7 +242,7 @@ def test_dead_collective_psum_of_ones_probe():
         return g / n
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
-                       out_specs=P("dp"), check_rep=False)
+                       out_specs=P("dp"), check_vma=False)
     f = analyze_sharding(fn, jnp.zeros((16, 8)), axis_sizes=SIZES)
     hits = _checks(f, "dead-collective")
     assert len(hits) == 1
@@ -260,7 +259,7 @@ def test_dead_collective_all_gather_of_replicated():
         return x + jnp.sum(t)
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=(P(None, "tp"), P()),
-                       out_specs=P(None, "tp"), check_rep=False)
+                       out_specs=P(None, "tp"), check_vma=False)
     f = analyze_sharding(fn, jnp.zeros((8, 16)), jnp.zeros((4, 4)),
                          axis_sizes=SIZES)
     assert _checks(f, "dead-collective")
@@ -273,32 +272,33 @@ def test_dead_collective_clean_on_varying_data():
         return jax.lax.psum(g, "dp")
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
     f = analyze_sharding(fn, jnp.zeros((16, 8)), axis_sizes=SIZES)
     assert not _checks(f, "dead-collective")
 
 
-def test_dead_collective_fused_psum_judged_by_all_operands():
-    """A fused tree psum is alive if ANY leaf varies — judging it by
-    its first operand alone false-flags (ones, x) and misses (x, ones)
-    (review-confirmed)."""
+def test_dead_collective_tree_psum_judged_leaf_by_leaf():
+    """A psum of a pytree is one collective per leaf, so each is judged
+    on its own operand: in (ones, x) the constant leaf is dead and the
+    varying one is not — in either order."""
     mesh = _mesh()
 
-    def body(x):
-        a, b = jax.lax.psum((jnp.ones(()), x), "dp")
-        return x + a * 0 + b * 0
+    for tree in (lambda x: (jnp.ones(()), x), lambda x: (x, jnp.ones(()))):
+        def body(x, tree=tree):
+            a, b = jax.lax.psum(tree(x), "dp")
+            return x + a * 0 + b * 0
 
-    fn = jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
-                       out_specs=P("dp"), check_rep=False)
-    f = analyze_sharding(fn, jnp.zeros((16, 8)), axis_sizes=SIZES)
-    assert not _checks(f, "dead-collective")
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
+                           out_specs=P("dp"), check_vma=False)
+        f = analyze_sharding(fn, jnp.zeros((16, 8)), axis_sizes=SIZES)
+        assert len(_checks(f, "dead-collective")) == 1
 
     def body_all_const(x):
         a, b = jax.lax.psum((jnp.ones(()), jnp.full((), 2.0)), "dp")
         return x + a * 0 + b * 0
 
     fn = jax.shard_map(body_all_const, mesh=mesh, in_specs=P("dp"),
-                       out_specs=P("dp"), check_rep=False)
+                       out_specs=P("dp"), check_vma=False)
     f = analyze_sharding(fn, jnp.zeros((16, 8)), axis_sizes=SIZES)
     assert _checks(f, "dead-collective")
 

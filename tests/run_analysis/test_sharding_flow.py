@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-import apex_tpu  # noqa: F401  (installs the 0.4.37 shims)
 from apex_tpu.analysis.sharding_flow import (
     MeshCtx,
     ShardVal,
@@ -123,7 +122,7 @@ def test_shard_map_boundary_seeds_distinct_and_out_names():
         return y
 
     def visit(eqn, ins, outs, ctx):
-        if eqn.primitive.name in ("psum", "psum2"):
+        if eqn.primitive.name in ("psum", "psum_invariant"):
             seen["in_distinct"] = ins[0].distinct if ins[0] else None
             seen["out_distinct"] = outs[0].distinct
             seen["manual"] = ctx.manual_axes
@@ -158,7 +157,7 @@ def test_psum_provenance_survives_preserve_chain():
                                if v is not None)))
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=P(None, "tp"),
-                       out_specs=P("tp"), check_rep=False)
+                       out_specs=P("tp"), check_vma=False)
     x = jnp.zeros((8, 16), jnp.bfloat16)
     interpret_sharding(_closed(fn, x), _vals([None], x),
                        axis_sizes=SIZES, visit=visit)
@@ -190,7 +189,7 @@ def test_scan_carry_two_pass_fixpoint_propagates_distinct():
             seen.append(ins[0].distinct if ins[0] else frozenset())
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=P(None, "tp"),
-                       out_specs=P(None, "tp"), check_rep=False)
+                       out_specs=P(None, "tp"), check_vma=False)
     x = jnp.zeros((8, 16))
     interpret_sharding(_closed(fn, x), _vals([None], x),
                        axis_sizes=SIZES, visit=visit)
@@ -264,7 +263,7 @@ def test_comms_estimate_multiplies_by_scan_trip_count():
         return out
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=P("tp"),
-                       out_specs=P("tp"), check_rep=False)
+                       out_specs=P("tp"), check_vma=False)
     x = jnp.zeros((16, 4))
     closed = _closed(fn, x)
     stats = estimate_hbm_and_comms(closed, _vals([None], x),

@@ -22,10 +22,7 @@ from apex_tpu.analysis.targets import (
     run_targets,
 )
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -57,7 +54,7 @@ class TestDivergentControl:
                 r > 2, lambda v: jax.lax.psum(v, "dp"), lambda v: v, x)
 
         fn = shard_map(bad, mesh=_mesh(), in_specs=(P("dp"),),
-                       out_specs=P("dp"), check_rep=False)
+                       out_specs=P("dp"), check_vma=False)
         found = analyze_spmd(fn, jnp.zeros((8, 4)), name="bad_cond")
         assert _checks(found) == ["collective-in-divergent-control"]
         assert "deadlock" in found[0].message
@@ -80,7 +77,7 @@ class TestDivergentControl:
             return jax.lax.while_loop(cond, body, (0, x))[1]
 
         fn = shard_map(bad, mesh=_mesh(), in_specs=(P("dp"),),
-                       out_specs=P("dp"), check_rep=False)
+                       out_specs=P("dp"), check_vma=False)
         found = analyze_spmd(fn, jnp.zeros((8, 4)), name="bad_while",
                              checks=("collective-in-divergent-control",))
         assert _checks(found) == ["collective-in-divergent-control"]
@@ -105,7 +102,7 @@ class TestDivergentControl:
             return jax.lax.while_loop(cond, body, (0, x))[1]
 
         fn = shard_map(bad, mesh=_mesh(), in_specs=(P("dp"),),
-                       out_specs=P("dp"), check_rep=False)
+                       out_specs=P("dp"), check_vma=False)
         found = analyze_spmd(fn, jnp.zeros((8, 4)), name="carry_while",
                              checks=("collective-in-divergent-control",))
         assert _checks(found) == ["collective-in-divergent-control"]
@@ -120,7 +117,7 @@ class TestDivergentControl:
                 flag, lambda v: jax.lax.psum(v, "dp"), lambda v: v, x)
 
         fn = shard_map(good, mesh=_mesh(), in_specs=(P("dp"),),
-                       out_specs=P("dp"), check_rep=False)
+                       out_specs=P("dp"), check_vma=False)
         found = analyze_spmd(fn, jnp.zeros((8, 4)), name="good_cond",
                              checks=("collective-in-divergent-control",))
         assert found == []
@@ -138,7 +135,7 @@ class TestDivergentControl:
                 r > 1, lambda v: jax.lax.psum(v, "tp"), lambda v: v, x)
 
         fn = shard_map(fn_body, mesh=mesh, in_specs=(P(("dp", "tp")),),
-                       out_specs=P(("dp", "tp")), check_rep=False)
+                       out_specs=P(("dp", "tp")), check_vma=False)
         found = analyze_spmd(fn, jnp.zeros((8, 4)), name="tp_in_dp_cond",
                              checks=("collective-in-divergent-control",))
         assert found == []
@@ -160,7 +157,7 @@ class TestRankDivergentUpdate:
             return poisoned - 0.1 * g
 
         fn = shard_map(bad, mesh=_mesh(), in_specs=(P(), P("dp")),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         found = analyze_spmd(fn, jnp.zeros((4,)), jnp.zeros((16, 4)),
                              name="one_rank_desync")
         assert _checks(found) == ["rank-divergent-update"]
@@ -175,7 +172,7 @@ class TestRankDivergentUpdate:
             return params - 0.1 * g
 
         fn = shard_map(bad, mesh=_mesh(), in_specs=(P(), P("dp")),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         found = analyze_spmd(fn, jnp.zeros((4,)), jnp.zeros((16, 4)),
                              name="missing_reduce")
         assert _checks(found) == ["rank-divergent-update"]
@@ -187,7 +184,7 @@ class TestRankDivergentUpdate:
             return params - 0.1 * g
 
         fn = shard_map(good, mesh=_mesh(), in_specs=(P(), P("dp")),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         assert analyze_spmd(fn, jnp.zeros((4,)), jnp.zeros((16, 4)),
                             name="good_update") == []
 
@@ -199,7 +196,7 @@ class TestRankDivergentUpdate:
             return x.sum(axis=0)  # stays per-rank
 
         fn = shard_map(good, mesh=_mesh(), in_specs=(P("dp"),),
-                       out_specs=P("dp"), check_rep=False)
+                       out_specs=P("dp"), check_vma=False)
         assert analyze_spmd(fn, jnp.zeros((16, 4)),
                             name="sharded_out") == []
 
@@ -216,7 +213,7 @@ class TestRankDivergentUpdate:
             return params + jnp.where(r == 5, 1e-3, 0.0) - 0.1 * g
 
         fn = shard_map(body, mesh=mesh1, in_specs=(P(), P("dp")),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         found = analyze_spmd(fn, jnp.zeros((4,)), jnp.zeros((16, 4)),
                              name="one_device")
         assert found == []
@@ -253,7 +250,7 @@ class TestUncoordinatedRng:
             return x + jax.random.normal(key, x.shape)
 
         fn = shard_map(bad, mesh=_mesh(), in_specs=(P(), P("dp")),
-                       out_specs=P("dp"), check_rep=False)
+                       out_specs=P("dp"), check_vma=False)
         found = analyze_spmd(fn, jax.random.PRNGKey(0),
                              jnp.zeros((16, 4)), name="shared_stream")
         assert _checks(found) == ["uncoordinated-rng"]
@@ -269,7 +266,7 @@ class TestUncoordinatedRng:
             return params + 0.01 * jax.random.normal(key, params.shape)
 
         fn = shard_map(bad, mesh=_mesh(), in_specs=(P(), P()),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         found = analyze_spmd(fn, jnp.zeros((4,)),
                              jax.random.PRNGKey(0), name="rank_noise")
         assert _checks(found) == ["uncoordinated-rng"]
@@ -285,7 +282,7 @@ class TestUncoordinatedRng:
             return x + jax.random.normal(key, x.shape)
 
         fn = shard_map(good, mesh=_mesh(), in_specs=(P(), P("dp")),
-                       out_specs=P("dp"), check_rep=False)
+                       out_specs=P("dp"), check_vma=False)
         assert analyze_spmd(fn, jax.random.PRNGKey(0),
                             jnp.zeros((16, 4)), name="good_rng") == []
 
@@ -302,7 +299,7 @@ class TestUncoordinatedRng:
             return params + 0.01 * jax.random.normal(key, params.shape)
 
         fn = shard_map(bad, mesh=_mesh(), in_specs=(P(), P()),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         args = (jnp.zeros((4,)), jax.random.PRNGKey(0))
         only_rng = analyze_spmd(fn, *args, name="route_rng",
                                 checks=("uncoordinated-rng",))
@@ -320,7 +317,7 @@ class TestUncoordinatedRng:
             return params + jax.lax.pmean(noise, "dp")
 
         fn = shard_map(good, mesh=_mesh(), in_specs=(P(), P()),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         assert analyze_spmd(fn, jnp.zeros((4,)),
                             jax.random.PRNGKey(0),
                             name="reduced_noise") == []
@@ -339,7 +336,7 @@ class TestUnorderedHostEffect:
             return {"w": w, "b": b}
 
         fn = shard_map(bad, mesh=_mesh(), in_specs=(P("dp"),),
-                       out_specs={"w": P(), "b": P()}, check_rep=False)
+                       out_specs={"w": P(), "b": P()}, check_vma=False)
         found = analyze_spmd(fn, jnp.zeros((64, 16)), name="bad_dbg",
                              checks=("unordered-host-effect",))
         assert _checks(found) == ["unordered-host-effect"]
@@ -357,7 +354,7 @@ class TestUnorderedHostEffect:
             return {"w": w, "b": b}
 
         fn = shard_map(bad, mesh=_mesh(), in_specs=(P("dp"),),
-                       out_specs={"w": P(), "b": P()}, check_rep=False)
+                       out_specs={"w": P(), "b": P()}, check_vma=False)
         found = analyze_spmd(fn, jnp.zeros((64, 16)), name="bad_io",
                              checks=("unordered-host-effect",))
         assert _checks(found) == ["unordered-host-effect"]
@@ -374,7 +371,7 @@ class TestUnorderedHostEffect:
             return {"w": w, "b": b}
 
         fn = shard_map(good, mesh=_mesh(), in_specs=(P("dp"),),
-                       out_specs={"w": P(), "b": P()}, check_rep=False)
+                       out_specs={"w": P(), "b": P()}, check_vma=False)
         assert analyze_spmd(fn, jnp.zeros((64, 16)), name="good_dbg",
                             checks=("unordered-host-effect",)) == []
 
@@ -394,7 +391,7 @@ class TestUnorderedHostEffect:
 
             fn = shard_map(step, mesh=_mesh(), in_specs=(P("dp"),),
                            out_specs={"w": P(), "b": P()},
-                           check_rep=False)
+                           check_vma=False)
             stats = {}
             # 256-wide grads split into >1 bucket at the 0.1 MB cap,
             # so the probe brackets a multi-collective chain
@@ -421,7 +418,7 @@ class TestEntry:
             return jax.lax.psum(x, "dp")
 
         wrapped = shard_map(fn, mesh=_mesh(), in_specs=(P("dp"),),
-                            out_specs=P(), check_rep=False)
+                            out_specs=P(), check_vma=False)
         stats = {}
         analyze_spmd(wrapped, jnp.zeros((8, 4)), name="s",
                      stats_out=stats)

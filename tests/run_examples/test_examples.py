@@ -13,8 +13,8 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 
 
 def _run(script, *args, timeout=420):
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)  # examples must self-force the CPU mesh
+    # examples run on whatever backend JAX selects; the tests select the CPU
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, os.path.join(_REPO, "examples", script), *args],
         capture_output=True, text=True, timeout=timeout, env=env, cwd=_REPO)

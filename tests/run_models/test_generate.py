@@ -79,10 +79,12 @@ def test_moe_top1_switch_decode_runs():
 
 def test_decode_attention_gqa_matches_repeat_reference():
     """The grouped-einsum GQA decode attention (ISSUE 20 satellite)
-    must be BIT-identical to the materialized jnp.repeat reference it
-    replaced — same fp32 contractions over d and T, only the rep×
-    cache copy removed — for scalar pos and for the serving
-    scheduler's per-row [b, 1, 1] pos."""
+    must equal the materialized jnp.repeat reference it replaced —
+    same fp32 contractions over d and T, only the rep× cache copy
+    removed — for scalar pos and for the serving scheduler's per-row
+    [b, 1, 1] pos. Equal to fp32 rounding, not bit for bit: XLA picks
+    its dot algorithm per operand layout, and the two einsums lay the
+    heads out differently (3.6e-7 apart on XLA:CPU under jax 0.9)."""
     key = jax.random.PRNGKey(0)
     b, T, nkv, rep, d = 3, 16, 2, 3, 8
     nq = nkv * rep
@@ -105,9 +107,10 @@ def test_decode_attention_gqa_matches_repeat_reference():
     for pos in (0, 9, T - 1):
         want = np.asarray(reference(q, k_cache, v_cache, pos))
         got = np.asarray(gen._decode_attention(q, k_cache, v_cache, pos))
-        np.testing.assert_array_equal(
-            got, want, err_msg=f"grouped GQA attention diverged from "
-                               f"the repeat reference at pos={pos}")
+        np.testing.assert_allclose(
+            got, want, rtol=2e-5, atol=2e-6,
+            err_msg=f"grouped GQA attention diverged from the repeat "
+                    f"reference at pos={pos}")
 
     # per-row positions (serving packed batch): each row must equal the
     # scalar-pos result for its own position
@@ -116,8 +119,8 @@ def test_decode_attention_gqa_matches_repeat_reference():
         q, k_cache, v_cache, jnp.asarray(rows)[:, None, None]))
     for i, p in enumerate(rows):
         want_i = np.asarray(reference(q, k_cache, v_cache, int(p)))[i]
-        np.testing.assert_array_equal(
-            got[i], want_i,
+        np.testing.assert_allclose(
+            got[i], want_i, rtol=2e-5, atol=2e-6,
             err_msg=f"per-row pos diverged for row {i} (pos {p})")
 
     # bf16 caches exercise the astype path generate() actually runs
@@ -127,7 +130,7 @@ def test_decode_attention_gqa_matches_repeat_reference():
     want16 = np.asarray(reference(
         q.astype(jnp.bfloat16), k_cache.astype(jnp.bfloat16),
         v_cache.astype(jnp.bfloat16), 9))
-    np.testing.assert_array_equal(got16, want16)
+    np.testing.assert_allclose(got16, want16, rtol=2e-5, atol=2e-6)
 
 
 def test_temperature_sampling_runs():

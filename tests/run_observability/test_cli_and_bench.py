@@ -1,9 +1,9 @@
 """The report CLI and bench.py's telemetry glue (ISSUE 2 acceptance:
 bench emits a metrics JSONL that ``python -m apex_tpu.observability
-report`` summarizes; the launcher's tpu_init_error is a structured
-event)."""
+report`` summarizes; a run that finds no TPU refuses)."""
 
 import json
+import os
 import subprocess
 import sys
 
@@ -78,33 +78,35 @@ def test_bench_peak_flops_delegates_to_observability():
     assert bench._peak_flops("cpu") is None
 
 
-def test_launcher_tpu_init_error_event(tmp_path, monkeypatch):
-    """The launcher's fallback path appends a machine-readable
-    tpu_init_error event to the metrics JSONL."""
-    path = tmp_path / "m.jsonl"
-    monkeypatch.setenv("APEX_TPU_METRICS", str(path))
-    from apex_tpu.observability import append_event
-
-    append_event(bench._metrics_path(), "tpu_init_error", attempts=2,
-                 errors=["timeout 2700s", "rc=3: watchdog"])
-    back = read_jsonl(str(path))
-    assert back[-1]["name"] == "tpu_init_error"
-    assert back[-1]["fields"]["attempts"] == 2
+def test_bench_refuses_a_non_tpu_backend(tmp_path):
+    """Asked for the chip (no BENCH_FORCE_CPU) on a host where jax
+    selects the CPU: exit non-zero, say no TPU was found, print no
+    metric — never a CPU number under a device metric's name."""
+    env = {k: v for k, v in os.environ.items() if k != "BENCH_FORCE_CPU"}
+    env.update(JAX_PLATFORMS="cpu",
+               APEX_TPU_METRICS=str(tmp_path / "m.jsonl"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"))
+    proc = subprocess.run(
+        [sys.executable, "bench.py"], capture_output=True, text=True,
+        timeout=300, env=env,
+        cwd=os.path.dirname(os.path.abspath(bench.__file__)))
+    assert proc.returncode != 0
+    assert "no TPU found" in proc.stderr
+    assert "{" not in proc.stdout
+    assert not (tmp_path / "m.jsonl").exists()
 
 
 @pytest.mark.slow
 def test_bench_cpu_mode_emits_metrics_jsonl(tmp_path):
-    """End-to-end: a BENCH_FORCE_CPU worker run writes a metrics JSONL
+    """End-to-end: a BENCH_FORCE_CPU run writes a metrics JSONL
     whose records include step time, recompile count, and the
     kernel-dispatch choice (the ISSUE acceptance criterion), and the
     report CLI summarizes it."""
-    import os
-
     path = tmp_path / "bench_metrics.jsonl"
     env = {**os.environ, "BENCH_FORCE_CPU": "1",
            "APEX_TPU_METRICS": str(path), "JAX_PLATFORMS": "cpu"}
     proc = subprocess.run(
-        [sys.executable, "bench.py", "--worker"],
+        [sys.executable, "bench.py"],
         capture_output=True, text=True, timeout=1200, env=env,
         cwd=os.path.dirname(os.path.abspath(bench.__file__)))
     assert proc.returncode == 0, proc.stderr[-2000:]

@@ -99,8 +99,20 @@ def test_guard_first_compile_is_free(listener):
         obs_fresh_probe(jnp.ones((3,)))  # first-ever compile: free
 
 
+def test_unnamed_compile_event_blinds_the_guard_loudly():
+    """A jax that stops naming its compile events must not read as "zero
+    retraces": every per-function read raises once one arrived unnamed."""
+    lst = recompile_mod.RecompileListener(registry=MetricRegistry())
+    lst._on_duration(recompile_mod._EV_COMPILE, 0.1, "jit(named)")
+    assert lst.compiles("named") == 1
+    lst._on_duration(recompile_mod._EV_COMPILE, 0.1, None)
+    assert lst.backend_compiles() == 2
+    for read in (lst.compiles, lst.retraces, lst.snapshot):
+        with pytest.raises(RuntimeError, match="without a fun_name"):
+            read()
+
+
 def test_install_is_idempotent_and_uninstall_restores():
-    prev_flag = jax.config.jax_log_compiles
     reg = MetricRegistry()
     l1 = install_recompile_listener(reg)
     l2 = install_recompile_listener()
@@ -108,7 +120,6 @@ def test_install_is_idempotent_and_uninstall_restores():
     assert recompile_mod.current() is l1
     uninstall_recompile_listener()
     assert recompile_mod.current() is None
-    assert jax.config.jax_log_compiles == prev_flag
     uninstall_recompile_listener()  # second uninstall is a no-op
 
 

@@ -100,3 +100,6 @@ def test_peak_flops_table():
     assert peak_flops("TPU v4") == 275e12
     assert peak_flops("cpu") is None
     assert peak_flops("") is None
+    # a TPU nobody entered a peak for is an error, not "no MFU"
+    with pytest.raises(ValueError, match="TPU v9"):
+        peak_flops("TPU v9")

@@ -7,10 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from apex_tpu.contrib.optimizers import distributed_fused_lamb
 from apex_tpu.optimizers import fused_lamb

@@ -6,8 +6,22 @@ so tests and the race itself can still reach both paths.
 """
 
 import jax
+import pytest
 
 from apex_tpu.ops import pallas_config
+
+
+def test_device_tables_raise_on_unlisted_tpu_kind():
+    """A planning figure is only returned for a kind the table lists
+    (or off-TPU); a TPU nobody measured raises."""
+    assert pallas_config.device_vmem_bytes("TPU v5 lite") == 16 << 20
+    assert pallas_config.device_hbm_bytes("TPU v5 lite") == 16 << 30
+    assert pallas_config.device_vmem_bytes("cpu") == 16 << 20
+    assert pallas_config.device_hbm_bytes("cpu") == 16 << 30
+    for fn in (pallas_config.device_vmem_bytes,
+               pallas_config.device_hbm_bytes):
+        with pytest.raises(ValueError, match="TPU v9"):
+            fn("TPU v9")
 
 
 def test_force_overrides_table():

@@ -200,12 +200,15 @@ def test_flash_attention_bwd_interpret(causal, h_kv):
                                    err_msg=f"d{name}")
 
 
-def test_flash_attention_bwd_interpret_multiblock():
+@pytest.mark.parametrize("s,causal", [(96, True), (80, True), (80, False)])
+def test_flash_attention_bwd_interpret_multiblock(s, causal):
     """Small blocks: dq k-sweep and dk/dv q-sweep accumulate across a real
-    grid; GQA rep accumulation across shared query heads."""
+    grid; GQA rep accumulation across shared query heads. s=80 does not
+    divide into 32-row blocks, so q/k/v ride padded to 96 and the padded
+    keys must be masked out of dq/dk/dv."""
     from apex_tpu.ops.flash_attention import _flash_bwd_pallas
 
-    bh, bh_kv, s, d = 4, 2, 96, 16
+    bh, bh_kv, d = 4, 2, 16
     ks = [jax.random.normal(jax.random.PRNGKey(i), (bh, s, d)) for i in
           range(2)]
     q, do = ks
@@ -213,10 +216,10 @@ def test_flash_attention_bwd_interpret_multiblock():
     v = jax.random.normal(jax.random.PRNGKey(3), (bh_kv, s, d))
 
     o, vjp = jax.vjp(
-        lambda q, k, v: _reference_attention(q, k, v, True, 0.25), q, k, v)
+        lambda q, k, v: _reference_attention(q, k, v, causal, 0.25), q, k, v)
     ref = vjp(do)
-    _, lse = _flash_fwd_pallas(q, k, v, True, 0.25, 32, 32, interpret=True)
-    out = _flash_bwd_pallas(q, k, v, o, lse, do, True, 0.25, 32, 32,
+    _, lse = _flash_fwd_pallas(q, k, v, causal, 0.25, 32, 32, interpret=True)
+    out = _flash_bwd_pallas(q, k, v, o, lse, do, causal, 0.25, 32, 32,
                             interpret=True)
     for name, got, want in zip("q k v".split(), out, ref):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -93,7 +93,7 @@ class TestFlashLowering:
         lowers_for_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
 
     def test_small_heads_and_blocks(self):
-        # d=64, sq below the default block -> _pick_block shrink path
+        # d=64, sq below the default block -> one full-extent block
         q = jnp.ones((4, 192, 2, 64), jnp.bfloat16)
         lowers_for_tpu(
             functools.partial(flash_attention, causal=True), q, q, q)

@@ -5,7 +5,6 @@ to the single-psum ``sync_gradients``, the plan must follow grad-ready
 (reverse) order, and the shared multi-device subprocess harness must
 run real collectives in a fresh interpreter."""
 
-import apex_tpu  # noqa: F401 — installs the jax 0.4.37 shims
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -6,7 +6,6 @@ through the atomic checkpoint path."""
 
 import functools
 
-import apex_tpu  # noqa: F401 — installs the jax 0.4.37 shims
 import jax
 import jax.numpy as jnp
 import numpy as np

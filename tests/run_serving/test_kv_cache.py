@@ -62,8 +62,8 @@ def test_page_hbm_bytes_formula():
 def test_derive_page_budget_math_with_overrides():
     cfg = _cfg()
     page_bytes = kvc.page_hbm_bytes(cfg, page_size=8)
-    priors = {"priors": {"serving_decode_step": {"ratio": 2.0}},
-              "default_ratio": 1.5}
+    priors = {"backend": "cpu", "default_ratio": 1.5,
+              "priors": {"serving_decode_step": {"ratio": 2.0}}}
     b = kvc.derive_page_budget(cfg, 8, hbm_bytes=page_bytes * 100,
                                watermark_bytes=page_bytes * 10,
                                priors=priors, safety=0.5)
@@ -75,12 +75,19 @@ def test_derive_page_budget_math_with_overrides():
     # no serving-specific prior -> the document default prices the page
     b2 = kvc.derive_page_budget(cfg, 8, hbm_bytes=page_bytes * 100,
                                 watermark_bytes=0,
-                                priors={"priors": {},
+                                priors={"backend": "cpu", "priors": {},
                                         "default_ratio": 1.5},
                                 safety=1.0)
     assert b2.ratio == 1.5
     assert b2.pages == int(page_bytes * 100
                            // int(np.ceil(page_bytes * 1.5)))
+    # a ratio measured on ANOTHER backend is not applied: one below 1
+    # would add pages that do not fit on this one
+    b3 = kvc.derive_page_budget(cfg, 8, hbm_bytes=page_bytes * 100,
+                                watermark_bytes=0,
+                                priors=dict(priors, backend="tpu"),
+                                safety=1.0)
+    assert b3.ratio == 1.0 and b3.pages == 100
 
 
 def test_derive_page_budget_watermark_floor_and_safety_validation():

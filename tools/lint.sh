@@ -73,7 +73,7 @@ if [[ "${1:-}" == "--changed-only" ]]; then
           git diff --name-only --cached 2>/dev/null;
           git diff --name-only 2>/dev/null; } \
         | sort -u \
-        | grep -E '^(apex_tpu|examples|tools)/.*\.py$|^bench\.py$' || true)"
+        | grep -E '^(apex_tpu|examples|tools)/.*\.py$|^(bench|chip_smoke)\.py$' || true)"
     ast_paths=()
     while IFS= read -r f; do
         [[ -n "$f" && -e "$f" ]] && ast_paths+=("$f")
@@ -95,7 +95,7 @@ fi
 rc=0
 python -m apex_tpu.analysis \
     --baseline tests/run_analysis/baseline.json \
-    apex_tpu examples tools bench.py "$@" || rc=$?
+    apex_tpu examples tools bench.py chip_smoke.py "$@" || rc=$?
 
 # Goodput regression gate (ISSUE 17 satellite): compare a bench metrics
 # dump against the pinned BENCH_BASELINE.jsonl. By default the baseline

@@ -11,9 +11,8 @@ price on. This one-shot refreshes it from, in order of preference:
   2. the newest ``BENCH_*_live.json`` / ``BENCH_BASELINE.jsonl`` in
      the repo root that carries calibration events;
   3. ``--live``              a fresh ``calibrate_targets()`` run on
-     the current backend (what tools/relay_hunter.py invokes on a
-     clean live TPU window, replacing CPU ratios with on-silicon
-     ones).
+     the current backend (run on a TPU it replaces CPU ratios with
+     on-silicon ones).
 
 Output is deterministic (sorted keys, fixed rounding, no clocks), so
 an unchanged capture regenerates a byte-identical file and the diff in
@@ -123,10 +122,9 @@ def build_document(rows: dict, backend: str, source: str) -> dict:
             "memory.calibrate). Consumed by estimate_hbm_and_comms("
             "priors=...) and apex_tpu.analysis.planner pruning; "
             "validated loudly by memory_checks.load_hbm_priors. "
-            "Regenerate with: python tools/refresh_priors.py (run "
-            "opportunistically by tools/relay_hunter.py on clean live "
-            "TPU windows, which replaces these CPU-backend ratios "
-            "with on-silicon ones)."),
+            "Regenerate with: python tools/refresh_priors.py (--live "
+            "on a TPU replaces CPU-backend ratios with on-silicon "
+            "ones)."),
         "schema_version": 1,
         "backend": backend,
         "source": source,
