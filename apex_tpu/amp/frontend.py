@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.amp._amp_state import _amp_state, maybe_print, warn_or_err
+from apex_tpu.observability import scope
 
 _NORM_KEY_HINTS = ("batchnorm", "bn", "layernorm", "rmsnorm", "norm", "scale_bias")
 
@@ -214,7 +215,8 @@ class Policy:
                     return leaf.astype(jnp.float32)
             return leaf.astype(self.param_dtype)
 
-        leaves = [cast_one(path, leaf) for path, leaf in flat]
+        with scope("amp/cast_model"):
+            leaves = [cast_one(path, leaf) for path, leaf in flat]
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
 

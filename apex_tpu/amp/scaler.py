@@ -21,6 +21,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.observability import scope
 from apex_tpu.ops.vma import to_varying
 
 
@@ -601,11 +602,12 @@ def scaled_update(tx, scaler: LossScaler, grads, opt_state, params,
 
     Returns ``(updates, new_opt_state, new_scaler_state, overflow)``.
     """
-    unscaled, overflow = scaler.unscale(grads, scaler_state)
-    if overflow_reduce_axes:
-        ovf = to_varying(overflow.astype(jnp.float32),
-                         overflow_reduce_axes)
-        overflow = jax.lax.psum(ovf, tuple(overflow_reduce_axes)) > 0
+    with scope("amp/unscale"):
+        unscaled, overflow = scaler.unscale(grads, scaler_state)
+        if overflow_reduce_axes:
+            ovf = to_varying(overflow.astype(jnp.float32),
+                             overflow_reduce_axes)
+            overflow = jax.lax.psum(ovf, tuple(overflow_reduce_axes)) > 0
 
     def do_update(_):
         return tx.update(unscaled, opt_state, params)
@@ -628,6 +630,8 @@ def scaled_update(tx, scaler: LossScaler, grads, opt_state, params,
         kept = jax.tree_util.tree_map(_match_vma, opt_state, out_shapes[1])
         return zeros, kept
 
-    updates, new_opt_state = jax.lax.cond(overflow, skip, do_update, None)
-    new_scaler_state = scaler.update(scaler_state, overflow)
+    with scope("amp/update"):
+        updates, new_opt_state = jax.lax.cond(overflow, skip, do_update,
+                                              None)
+        new_scaler_state = scaler.update(scaler_state, overflow)
     return updates, new_opt_state, new_scaler_state, overflow

@@ -23,6 +23,7 @@ from apex_tpu.models._common import (
     packed_qkv_attention,
 )
 
+from apex_tpu.observability import scope
 from apex_tpu.transformer.functional.fused_softmax import (
     scaled_upper_triang_masked_softmax,
 )
@@ -130,9 +131,14 @@ def _mlp(x, lp, tp_axis):
 
 
 def decoder_layer(x, lp, cfg: GPT2Config, tp_axis: Optional[str] = "tp"):
-    x = x + _attention(_ln(x, lp["ln1_w"], lp["ln1_b"], cfg.ln_eps), lp, cfg,
-                       tp_axis)
-    x = x + _mlp(_ln(x, lp["ln2_w"], lp["ln2_b"], cfg.ln_eps), lp, tp_axis)
+    # each scope holds its LayerNorm and its residual add: the device
+    # trace's ops carry these names (forward, recompute and backward)
+    with scope("gpt2/attn"):
+        x = x + _attention(_ln(x, lp["ln1_w"], lp["ln1_b"], cfg.ln_eps), lp,
+                           cfg, tp_axis)
+    with scope("gpt2/mlp"):
+        x = x + _mlp(_ln(x, lp["ln2_w"], lp["ln2_b"], cfg.ln_eps), lp,
+                     tp_axis)
     return x
 
 
@@ -140,8 +146,10 @@ def hidden_states(params, tokens, cfg: GPT2Config,
                   tp_axis: Optional[str] = "tp", remat: bool = True):
     """Shared trunk: embeddings + layers + final LN (pre-head)."""
     b, s = tokens.shape
-    x = vocab_parallel_embedding(tokens, params["embed"], axis_name=tp_axis)
-    x = (x + params["pos_embed"][None, :s]).astype(cfg.dtype)
+    with scope("gpt2/embed"):
+        x = vocab_parallel_embedding(tokens, params["embed"],
+                                     axis_name=tp_axis)
+        x = (x + params["pos_embed"][None, :s]).astype(cfg.dtype)
 
     def body(h, lp):
         return decoder_layer(h, lp, cfg, tp_axis), None
@@ -149,7 +157,8 @@ def hidden_states(params, tokens, cfg: GPT2Config,
     if remat:
         body = jax.checkpoint(body)
     x, _ = jax.lax.scan(body, x, params["layers"])
-    return _ln(x, params["lnf_w"], params["lnf_b"], cfg.ln_eps)
+    with scope("gpt2/final_ln"):
+        return _ln(x, params["lnf_w"], params["lnf_b"], cfg.ln_eps)
 
 
 def forward(params, tokens, cfg: GPT2Config, tp_axis: Optional[str] = "tp",
@@ -157,7 +166,9 @@ def forward(params, tokens, cfg: GPT2Config, tp_axis: Optional[str] = "tp",
     """tokens [b, s] → vocab-sharded logits [b, s, v_local] (tied head)."""
     x = hidden_states(params, tokens, cfg, tp_axis, remat)
     # tied embedding head → vocab-sharded logits (embed rows are the shard)
-    return jnp.matmul(x, params["embed"].T.astype(x.dtype)).astype(jnp.float32)
+    with scope("gpt2/head_ce"):
+        return jnp.matmul(
+            x, params["embed"].T.astype(x.dtype)).astype(jnp.float32)
 
 
 def loss_fn(params, batch, cfg: GPT2Config, tp_axis: Optional[str] = "tp",
@@ -171,12 +182,14 @@ def loss_fn(params, batch, cfg: GPT2Config, tp_axis: Optional[str] = "tp",
         )
 
         x = hidden_states(params, tokens, cfg, tp_axis, remat)
-        losses = chunked_lm_cross_entropy(
-            x.reshape(-1, x.shape[-1]), params["embed"].T,
-            targets.reshape(-1), vocab_chunks,
-            tp_axis=tp_axis if _axis_bound(tp_axis) else None)
-        return jnp.mean(losses)
+        with scope("gpt2/head_ce"):
+            losses = chunked_lm_cross_entropy(
+                x.reshape(-1, x.shape[-1]), params["embed"].T,
+                targets.reshape(-1), vocab_chunks,
+                tp_axis=tp_axis if _axis_bound(tp_axis) else None)
+            return jnp.mean(losses)
     logits = forward(params, tokens, cfg, tp_axis, remat)
-    return jnp.mean(
-        vocab_parallel_cross_entropy(logits, targets, axis_name=tp_axis)
-    )
+    with scope("gpt2/head_ce"):
+        return jnp.mean(
+            vocab_parallel_cross_entropy(logits, targets, axis_name=tp_axis)
+        )

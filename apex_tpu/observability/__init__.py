@@ -82,6 +82,7 @@ from apex_tpu.observability.profiling import (  # noqa: F401
     SpanTracer,
     StepPhases,
     get_tracer,
+    host_span,
     set_tracer,
     span,
 )
@@ -134,7 +135,7 @@ __all__ = [
     "RecompileListener", "RetraceBudgetExceeded", "retrace_guard",
     "install_recompile_listener", "uninstall_recompile_listener",
     "scope", "annotate",
-    "span", "SpanTracer", "get_tracer", "set_tracer",
+    "span", "host_span", "SpanTracer", "get_tracer", "set_tracer",
     "StepPhases", "FlightRecorder",
     "StepReporter", "STEP_RECORD_FIELDS", "peak_flops",
     "transformer_step_flops",

@@ -5,8 +5,11 @@ The profiling tier the reference ships as ``apex.pyprof``, rebuilt on
 PR 2's registry/scope plumbing:
 
 - :mod:`~apex_tpu.observability.profiling.spans` — always-on
-  ring-buffer span tracer; ``span()`` supersedes the bare ``scope()``
-  on every hot path and exports Chrome/Perfetto trace-event JSON;
+  ring-buffer span tracer for host code (``span()``, ``host_span()``;
+  code under ``jit`` keeps the bare ``scope()``), exported as
+  Chrome/Perfetto trace-event JSON;
+- :mod:`~apex_tpu.observability.profiling.hlo_scopes` — which phase
+  and scope of the train step each compiled instruction belongs to;
 - :mod:`~apex_tpu.observability.profiling.xplane` — device-side
   per-phase attribution from a ``jax.profiler`` capture (the library
   form of ``tools/trace_report.py``);
@@ -32,6 +35,7 @@ from apex_tpu.observability.profiling.spans import (  # noqa: F401
     SpanTracer,
     get_tracer,
     decode_span_payload,
+    host_span,
     load_spans,
     set_tracer,
     span,
@@ -55,7 +59,7 @@ from apex_tpu.observability.profiling.xplane import (  # noqa: F401
 )
 
 __all__ = [
-    "Span", "SpanTracer", "span", "get_tracer", "set_tracer",
+    "Span", "SpanTracer", "span", "host_span", "get_tracer", "set_tracer",
     "to_trace_events", "write_chrome_trace", "load_spans",
     "decode_span_payload", "spans_from_dicts",
     "StepPhases", "classify_span", "compute_breakdown",

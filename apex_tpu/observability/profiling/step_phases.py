@@ -4,12 +4,19 @@ A step-time number says *that* a step was slow; this module says
 *where it went*. Two signal sources, correlated per training step:
 
 - **host spans** from the always-on ring tracer
-  (:mod:`~apex_tpu.observability.profiling.spans`): every hot-path
-  ``span()`` — pipeline phases, TP/SP collectives, DDP buckets,
-  fused-adam dispatch — classified into ``data`` / ``comms`` /
-  ``compute``, with the unattributed remainder reported as ``host``
-  (Python, dispatch, everything nobody instrumented). Fractions are
-  of the step span's wall time and sum to ~1.0 by construction.
+  (:mod:`~apex_tpu.observability.profiling.spans`): the spans that
+  HOST code opened inside the step (a data loader, an eager optimizer
+  update, a caller's own ``span("fwd")``), classified into ``data`` /
+  ``comms`` / ``compute``, with the unattributed remainder reported as
+  ``host`` (Python, dispatch, everything nobody instrumented).
+  Fractions are of the step span's wall time and sum to ~1.0 by
+  construction. The library's in-jit sites (``tp/*``, ``sp/*``,
+  ``ddp/*``, ``pp/*``, ``fused_adam/flat/*``) are bare ``scope()`` and
+  are NOT in the ring: under ``jit`` their Python runs once, at trace
+  time, so a ring entry would be the trace's duration and never a
+  step's. A compiled step's compute/comms split is a device question:
+  it comes from the capture below, by the names those scopes leave in
+  the HLO.
 - **device categories** from an xplane capture
   (:mod:`~apex_tpu.observability.profiling.xplane`), when one exists:
   the real silicon-side compute/comms split plus the compute↔comms

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import contextlib
 
-__all__ = ["scope", "annotate"]
+__all__ = ["scope", "host_annotation", "annotate"]
 
 _jax = None
 
@@ -41,6 +41,12 @@ def scope(name: str):
     jax = _get_jax()
     with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
         yield
+
+
+def host_annotation(name: str):
+    """The host half of :func:`scope` alone, for code that builds no
+    HLO."""
+    return _get_jax().profiler.TraceAnnotation(name)
 
 
 def annotate(name: str):

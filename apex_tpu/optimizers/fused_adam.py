@@ -78,7 +78,7 @@ def fused_adam(
             lr=lr_t, b1=b1, b2=b2, eps=eps, weight_decay=weight_decay,
             adam_w_mode=adam_w_mode, step=step, bias_correction=bias_correction,
         )
-        from apex_tpu.observability import get_registry, span
+        from apex_tpu.observability import get_registry, scope, span
 
         if flat:
             from apex_tpu.ops import pallas_config
@@ -102,7 +102,7 @@ def fused_adam(
             path = "pallas" if kernel_on else "xla"
             get_registry().counter("optimizer/fused_adam/dispatch",
                                    path=f"flat_{path}").inc()
-            with span(f"fused_adam/flat/{path}"):
+            with scope(f"fused_adam/flat/{path}"):
                 # Group by *param* dtype; grads may arrive in a different
                 # dtype (e.g. fp32 grads over bf16 params) and are packed
                 # fp32 anyway.
@@ -139,6 +139,10 @@ def fused_adam(
         else:
             get_registry().counter("optimizer/fused_adam/dispatch",
                                    path="tree").inc()
+            # the one in-jit site still in the span ring (the others are
+            # bare scopes: under jit the ring would time the trace): an
+            # eager update is real host time, and
+            # tests/run_observability::test_hot_paths_record_spans reads it
             with span("fused_adam/tree"):
                 g_leaves, treedef = jax.tree_util.tree_flatten(grads)
                 p_leaves = jax.tree_util.tree_leaves(params)

@@ -49,7 +49,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.observability import span
+from apex_tpu.observability import scope
 from apex_tpu.observability.fleet import probe as fleet_probe
 from apex_tpu.ops.flat import flatten_tree, unflatten_tree
 
@@ -85,7 +85,7 @@ def sync_gradients(grads, axis_name: str = "data", gradient_average: bool = True
             g = g * jnp.asarray(gradient_predivide_factor / n, g.dtype)
         return g
 
-    with span("ddp/allreduce"):
+    with scope("ddp/allreduce"):
         return jax.tree_util.tree_map(reduce_leaf, grads)
 
 
@@ -101,11 +101,11 @@ def sync_gradients_flat(grads, axis_name: str = "data", gradient_average: bool =
     the same fp16-overflow headroom.
     """
     pre = gradient_predivide_factor
-    with span("ddp/allreduce_flat"):
+    with scope("ddp/allreduce_flat"):
         bufs, meta = flatten_tree(grads)
         reduced = {}
         for k, buf in bufs.items():
-            with span(f"ddp/bucket/{k}"):
+            with scope(f"ddp/bucket/{k}"):
                 if pre != 1.0:
                     buf = buf / pre
                 buf = fleet_probe.collective_enter(
@@ -158,7 +158,7 @@ def sync_gradients_bucketed(grads, axis_name: str = "data",
         n_buckets = max(bucket_ids) + 1 if bucket_ids else 0
         for b in range(n_buckets):
             members = [i for i, bid in zip(idxs, bucket_ids) if bid == b]
-            with span(f"ddp/bucket{b}/{dt}"):
+            with scope(f"ddp/bucket{b}/{dt}"):
                 flat = jnp.concatenate([leaves[i].ravel() for i in members])
                 if pre != 1.0:
                     flat = flat / pre
@@ -196,7 +196,8 @@ def sync_autodiff_gradients(grads, axis_name: str = "data"):
             return jax.lax.pmean(g, axis_name)
         n = jax.lax.axis_size(axis_name)
         return (g / jnp.asarray(n, g.dtype)).astype(g.dtype)
-    return jax.tree_util.tree_map(one, grads)
+    with scope("ddp/sync"):
+        return jax.tree_util.tree_map(one, grads)
 
 
 class Reducer:

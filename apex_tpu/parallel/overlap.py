@@ -49,7 +49,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.observability import span
+from apex_tpu.observability import scope
 from apex_tpu.observability.fleet import probe as fleet_probe
 
 
@@ -205,7 +205,7 @@ def sync_gradients_overlapped(grads, axis_name: str = "data",
     token = None
     for k, bucket in enumerate(plan.buckets):
         site = f"ddp/overlap/bucket{k}/{bucket.dtype}"
-        with span(site):
+        with scope(site):
             flat = _pack(leaves, bucket)
             if pre != 1.0:
                 flat = flat / pre
@@ -253,7 +253,7 @@ def overlapped_value_and_grad(
             return leaves, None
 
         def bwd(_, cts):
-            with span(f"ddp/overlap/bwd_bucket{tag}/{bucket.dtype}"):
+            with scope(f"ddp/overlap/bwd_bucket{tag}/{bucket.dtype}"):
                 # pack the accumulated bucket cotangents and reduce them
                 # right here in the backward
                 local = _pack(list(cts), _rebase(bucket))

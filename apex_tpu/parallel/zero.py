@@ -44,7 +44,7 @@ from typing import Callable, NamedTuple, Optional, Union
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.observability import span
+from apex_tpu.observability import scope
 from apex_tpu.observability.fleet import probe as fleet_probe
 from apex_tpu.optimizers import _math
 from apex_tpu.parallel.overlap import (
@@ -183,7 +183,7 @@ class Zero1FusedAdam:
         for k, bucket in enumerate(plan.buckets):
             shard_len = bucket.padded // n
             site = f"ddp/zero1/bucket{k}/{bucket.dtype}"
-            with span(site):
+            with scope(site):
                 # grads travel fp32 (the fused_adam flat packing),
                 # params in their own storage dtype
                 gflat = _pack(g_leaves, bucket, cast=jnp.float32)

@@ -31,6 +31,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from apex_tpu.observability import host_span
 from apex_tpu.resilience.loop import Preempted
 from apex_tpu.resilience.preemption import EXIT_PREEMPTED
 from apex_tpu.serving.kv_cache import derive_page_budget
@@ -63,6 +64,8 @@ class ServerMetrics:
             from apex_tpu.observability import get_registry
             registry = get_registry()
         self.registry = registry
+        self._occupancy = registry.gauge("serving/batch_occupancy")
+        self._page_utilization = registry.gauge("serving/page_utilization")
 
     def submitted(self) -> None:
         self.registry.counter("serving/requests_submitted").inc()
@@ -86,9 +89,8 @@ class ServerMetrics:
             n_outstanding)
 
     def step(self, occupancy: float, page_utilization: float) -> None:
-        self.registry.gauge("serving/batch_occupancy").set(occupancy)
-        self.registry.gauge("serving/page_utilization").set(
-            page_utilization)
+        self._occupancy.set(occupancy)
+        self._page_utilization.set(page_utilization)
 
     def publish_summary(self, summary: dict) -> None:
         """Mirror a loadgen report's scalars as ``serving/*`` gauges —
@@ -185,21 +187,25 @@ class ServingEngine:
 
     def step(self) -> List[Request]:
         """One engine iteration: poll preemption, admit, decode, evict.
-        Returns the requests finished this iteration."""
-        self._poll_preemption()
-        admitted, finished = self.scheduler.try_admit()
-        for _ in admitted:
-            self.metrics.admitted()
-        occ = self.scheduler.occupancy()
-        if self.scheduler.num_active():
-            self._occ_sum += occ
-            self._occ_steps += 1
-        self.metrics.step(occ, self.scheduler.cache.utilization())
-        finished = finished + self.scheduler.step_decode()
-        for req in finished:
-            self._finish(req)
-        self.iteration += 1
-        return finished
+        Returns the requests finished this iteration. In the span ring
+        it is one ``serving/step`` whose children say what it did: a
+        ``serving/admit`` per admission, a ``serving/decode`` if it
+        decoded."""
+        with host_span("serving/step"):
+            self._poll_preemption()
+            admitted, finished = self.scheduler.try_admit()
+            for _ in admitted:
+                self.metrics.admitted()
+            occ = self.scheduler.occupancy()
+            if self.scheduler.num_active():
+                self._occ_sum += occ
+                self._occ_steps += 1
+            self.metrics.step(occ, self.scheduler.cache.utilization())
+            finished = finished + self.scheduler.step_decode()
+            for req in finished:
+                self._finish(req)
+            self.iteration += 1
+            return finished
 
     def run(self, max_iterations: int = 100_000,
             retrace_guard: bool = True) -> Dict[int, dict]:

@@ -38,6 +38,7 @@ import numpy as np
 
 from apex_tpu.models import generate as _gen
 from apex_tpu.models import llama as _llama
+from apex_tpu.observability import get_tracer, host_span
 from apex_tpu.serving.kv_cache import PagedKVCache
 from apex_tpu.transformer.functional.rope import apply_rotary_qk
 
@@ -57,17 +58,26 @@ WEIGHT_MODES = ("native", "bf16", "fp8")
 @dataclasses.dataclass
 class Request:
     """One serving request and its lifecycle timestamps (monotonic
-    seconds; ``arrival_s`` is the loadgen trace offset)."""
+    seconds; ``arrival_s`` is the loadgen trace offset). ``admit_s`` is
+    taken when admission starts, before anything is dispatched: queue
+    wait is ``admit_s - submit_s``, prefill ``first_token_s - admit_s``.
+    """
 
     rid: int
     prompt: np.ndarray
     max_new_tokens: int
     arrival_s: float = 0.0
     submit_s: Optional[float] = None
+    admit_s: Optional[float] = None
     first_token_s: Optional[float] = None
     finish_s: Optional[float] = None
     state: str = "queued"                 # queued -> active -> done
     tokens: List[int] = dataclasses.field(default_factory=list)
+
+
+def _ns(seconds: float) -> int:
+    """``time.monotonic()`` seconds on the span ring's nanosecond clock."""
+    return int(seconds * 1e9)
 
 
 def pages_per_request(prompt_len: int, max_new_tokens: int,
@@ -367,67 +377,95 @@ class ContinuousBatchScheduler:
     def _admit(self, req: Request) -> bool:
         """Prefill + slot placement; returns False when the request
         finished at its first token (no slot taken)."""
+        req.admit_s = time.monotonic()
         p = len(req.prompt)
         s_pad = self._bucket(p)
-        pages = self.cache.alloc.alloc(self.pages_needed(req), req.rid)
-        prompt = np.zeros((1, s_pad), np.int32)
-        prompt[0, :p] = req.prompt
-        first, ks, vs = self._prefill_for(s_pad)(
-            self.params, self._scales, jnp.asarray(prompt),
-            np.int32(p))
-        self.prefill_count += 1
-        self.cache.write_prompt(pages[:s_pad // self.page_size], ks, vs)
-        t0 = int(np.asarray(first)[0])
-        req.tokens = [t0]
-        req.first_token_s = time.monotonic()
-        if self._is_finished(req, t0):
-            self._retire(req)
-            return False
-        slot = self.slots.index(None)
-        self.slots[slot] = req
-        req.state = "active"
-        self._tokens[slot] = t0
-        self._pos[slot] = p
-        row = np.full(self.max_pages_per_req, self.cache.trash_page,
-                      np.int32)
-        row[:len(pages)] = pages
-        self._tables[slot] = row
-        self._active[slot] = True
-        return True
+        # rows: the decoding rows that get no token while this runs
+        with host_span("serving/admit", rid=req.rid, prompt_tokens=p,
+                       bucket=s_pad, rows=self.num_active()):
+            if req.submit_s is not None:
+                get_tracer().record("serving/queue_wait",
+                                    _ns(req.submit_s), _ns(req.admit_s),
+                                    rid=req.rid)
+            with host_span("serving/prefill_dispatch", rid=req.rid):
+                pages = self.cache.alloc.alloc(self.pages_needed(req),
+                                               req.rid)
+                prompt = np.zeros((1, s_pad), np.int32)
+                prompt[0, :p] = req.prompt
+                first, ks, vs = self._prefill_for(s_pad)(
+                    self.params, self._scales, jnp.asarray(prompt),
+                    np.int32(p))
+            self.prefill_count += 1
+            n_prompt = s_pad // self.page_size
+            with host_span("serving/write_prompt", rid=req.rid,
+                           pages=n_prompt):
+                self.cache.write_prompt(pages[:n_prompt], ks, vs)
+            with host_span("serving/first_token_fetch", rid=req.rid):
+                t0 = int(np.asarray(first)[0])
+            req.tokens = [t0]
+            req.first_token_s = time.monotonic()
+            if self._is_finished(req, t0):
+                self._retire(req)
+                return False
+            slot = self.slots.index(None)
+            self.slots[slot] = req
+            req.state = "active"
+            self._tokens[slot] = t0
+            self._pos[slot] = p
+            row = np.full(self.max_pages_per_req, self.cache.trash_page,
+                          np.int32)
+            row[:len(pages)] = pages
+            self._tables[slot] = row
+            self._active[slot] = True
+            return True
 
     # ---------------------------------------------------------- decode
+
+    def pages_live(self) -> int:
+        """Pages the next decode step reads that hold a position some
+        active row attends to: each row's ``ceil((pos + 1) / page_size)``,
+        from the host mirrors."""
+        return int(np.sum(self._pos[self._active] // self.page_size + 1))
 
     def step_decode(self) -> List[Request]:
         """One packed decode step; returns requests finished by it."""
         if not self._active.any():
             return []
-        nxt, self.cache.k_pages, self.cache.v_pages = self._decode(
-            self.params, self._scales,
-            self.cache.k_pages, self.cache.v_pages,
-            jnp.asarray(self._tokens), jnp.asarray(self._tables),
-            jnp.asarray(self._pos), jnp.asarray(self._active))
-        self.decode_steps += 1
-        if self._decode_compiles0 is None:
-            self._decode_compiles0 = self._recompiles.compiles(
-                "_decode_step")
-            if self._decode_compiles0 < 1:
-                raise RuntimeError(
-                    "the recompile listener did not see _decode_step "
-                    "compile: the zero-retrace guard is blind")
-        nxt = np.asarray(nxt)
-        finished = []
-        for slot, req in enumerate(self.slots):
-            if req is None or not self._active[slot]:
-                continue
-            t = int(nxt[slot])
-            req.tokens.append(t)
-            self._tokens[slot] = t
-            self._pos[slot] += 1
-            if self._is_finished(req, t):
-                self._free_slot(slot)
-                self._retire(req)
-                finished.append(req)
-        return finished
+        # pages_gathered: what ``kp[tables]`` touches in every layer,
+        # whatever the rows hold
+        with host_span("serving/decode", rows=self.num_active(),
+                       pages_live=self.pages_live(),
+                       pages_gathered=self._tables.size):
+            with host_span("serving/decode_upload"):
+                nxt, self.cache.k_pages, self.cache.v_pages = self._decode(
+                    self.params, self._scales,
+                    self.cache.k_pages, self.cache.v_pages,
+                    jnp.asarray(self._tokens), jnp.asarray(self._tables),
+                    jnp.asarray(self._pos), jnp.asarray(self._active))
+            self.decode_steps += 1
+            if self._decode_compiles0 is None:
+                self._decode_compiles0 = self._recompiles.compiles(
+                    "_decode_step")
+                if self._decode_compiles0 < 1:
+                    raise RuntimeError(
+                        "the recompile listener did not see _decode_step "
+                        "compile: the zero-retrace guard is blind")
+            with host_span("serving/decode_fetch"):
+                nxt = np.asarray(nxt)
+            finished = []
+            with host_span("serving/decode_bookkeep"):
+                for slot, req in enumerate(self.slots):
+                    if req is None or not self._active[slot]:
+                        continue
+                    t = int(nxt[slot])
+                    req.tokens.append(t)
+                    self._tokens[slot] = t
+                    self._pos[slot] += 1
+                    if self._is_finished(req, t):
+                        self._free_slot(slot)
+                        self._retire(req)
+                        finished.append(req)
+            return finished
 
     def _is_finished(self, req: Request, token: int) -> bool:
         return (len(req.tokens) >= req.max_new_tokens
@@ -436,6 +474,10 @@ class ContinuousBatchScheduler:
     def _retire(self, req: Request) -> None:
         req.state = "done"
         req.finish_s = time.monotonic()
+        if req.submit_s is not None:
+            get_tracer().record("serving/request", _ns(req.submit_s),
+                                _ns(req.finish_s), rid=req.rid,
+                                tokens=len(req.tokens))
         self.cache.alloc.free_owner(req.rid)
 
     def _free_slot(self, slot: int) -> None:
@@ -482,6 +524,7 @@ class ContinuousBatchScheduler:
                       max_new_tokens=rec["max_new_tokens"],
                       arrival_s=rec.get("arrival_s", 0.0),
                       submit_s=time.monotonic())
+        req.admit_s = req.submit_s
         slot = self.slots.index(None)
         pages = self.cache.alloc.alloc(rec["npages"], req.rid)
         self.cache.restore_pages(pages, k, v)

@@ -32,7 +32,7 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.observability import span
+from apex_tpu.observability import scope
 from apex_tpu.transformer import parallel_state
 from apex_tpu.transformer.pipeline_parallel import p2p
 
@@ -89,7 +89,7 @@ def forward_backward_no_pipelining(
     zero_grads = jax.tree_util.tree_map(
         lambda sd: jnp.zeros(sd.shape, sd.dtype), grad_shapes
     )
-    with span("pp/grad_accum"):
+    with scope("pp/grad_accum"):
         (loss_sum, grad_sum), _ = jax.lax.scan(body, (0.0, zero_grads),
                                                microbatches)
     scale = 1.0 / m_count if grad_scale is None else grad_scale / m_count
@@ -139,7 +139,7 @@ def pipelined_forward(
         mb_idx = jnp.clip(t, 0, m_count - 1)
         feed = jax.lax.dynamic_index_in_dim(inputs, mb_idx, 0, keepdims=False)
         x = jnp.where(rank == 0, feed, incoming)
-        with span("pp/stage_compute"):
+        with scope("pp/stage_compute"):
             y = body_fn(stage_params, x)
         out_idx = jnp.clip(t - (n_stage - 1), 0, m_count - 1)
         write = (t >= n_stage - 1)  # uniform across ranks
@@ -148,7 +148,7 @@ def pipelined_forward(
         outputs = jax.lax.dynamic_update_index_in_dim(
             outputs, jnp.where(write, y, prev), out_idx, 0
         )
-        with span("pp/send_recv"):
+        with scope("pp/send_recv"):
             incoming = p2p.send_forward_recv_forward(y, axis)
         return (incoming, outputs), None
 
@@ -158,7 +158,7 @@ def pipelined_forward(
     # carries become device-varying inside the loop; start them that way
     init = (_to_varying(jnp.zeros_like(one), axis),
             _to_varying(jnp.zeros_like(inputs), axis))
-    with span("pp/forward"):
+    with scope("pp/forward"):
         (_, outputs), _ = jax.lax.scan(step, init, jnp.arange(steps))
     return outputs
 
@@ -194,12 +194,12 @@ def forward_backward_pipelining_without_interleaving(
 
     def total_loss(stage_params):
         outs = pipelined_forward(stage_fn, stage_params, inputs, axis, remat)
-        with span("pp/loss"):
+        with scope("pp/loss"):
             return _last_stage_mean_loss(loss_fn, outs, targets, axis)
 
     if forward_only:
         return total_loss(stage_params), None
-    with span("pp/forward_backward"):
+    with scope("pp/forward_backward"):
         return jax.value_and_grad(total_loss)(stage_params)
 
 
@@ -311,21 +311,21 @@ def pipelined_forward_interleaved(
         feed = jax.lax.dynamic_index_in_dim(inputs_v, m, 0, keepdims=False)
         # virtual stage 0 = (device 0, chunk 0) reads external input
         x = jnp.where((rank == 0) & (c == 0), feed, incoming)
-        with span("pp/stage_compute"):
+        with scope("pp/stage_compute"):
             y = body_fn(params_c, x)
         # virtual stage V·P−1 = (device P−1, chunk V−1) emits the output
         is_out = (rank == p - 1) & (c == v - 1) & valid
         prev = jax.lax.dynamic_index_in_dim(outputs, m, 0, keepdims=False)
         outputs = jax.lax.dynamic_update_index_in_dim(
             outputs, jnp.where(is_out, y, prev), m, 0)
-        with span("pp/send_recv"):
+        with scope("pp/send_recv"):
             incoming = p2p._shift_cyclic(y, +1, axis)
         return (incoming, outputs), None
 
     one = jax.lax.dynamic_index_in_dim(inputs, 0, 0, keepdims=False)
     init = (_to_varying(jnp.zeros_like(one), axis),
             _to_varying(jnp.zeros_like(inputs), axis))
-    with span("pp/forward_interleaved"):
+    with scope("pp/forward_interleaved"):
         (_, outputs), _ = jax.lax.scan(step, init, jnp.arange(steps))
     return outputs
 
@@ -350,12 +350,12 @@ def _forward_backward_pipelining_with_interleaving(
     def total_loss(chunks):
         outs = pipelined_forward_interleaved(stage_fn, chunks, inputs, axis,
                                              remat, strict=strict)
-        with span("pp/loss"):
+        with scope("pp/loss"):
             return _last_stage_mean_loss(loss_fn, outs, targets, axis)
 
     if forward_only:
         return total_loss(stage_params_chunks), None
-    with span("pp/forward_backward"):
+    with scope("pp/forward_backward"):
         return jax.value_and_grad(total_loss)(stage_params_chunks)
 
 

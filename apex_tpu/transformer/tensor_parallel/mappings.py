@@ -27,7 +27,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.observability import span
+from apex_tpu.observability import scope
 from apex_tpu.ops import vma as _vma
 from apex_tpu.transformer import parallel_state
 
@@ -90,7 +90,7 @@ def copy_to_tensor_model_parallel_region(x, axis_name: Optional[str] = None):
     axis = _axis(axis_name)
     if not _axis_bound(axis):
         return x
-    with span("tp/copy"):
+    with scope("tp/copy"):
         return _to_varying(x, axis)
 
 
@@ -99,7 +99,7 @@ def reduce_from_tensor_model_parallel_region(x, axis_name: Optional[str] = None)
     axis = _axis(axis_name)
     if not _axis_bound(axis):
         return x
-    with span("tp/allreduce"):
+    with scope("tp/allreduce"):
         return jax.lax.psum(x, axis)
 
 
@@ -111,7 +111,7 @@ def scatter_to_tensor_model_parallel_region(x, axis_name: Optional[str] = None):
     n = jax.lax.axis_size(axis)
     rank = jax.lax.axis_index(axis)
     chunk = x.shape[-1] // n
-    with span("tp/scatter"):
+    with scope("tp/scatter"):
         x = _to_varying(x, axis)
         return jax.lax.dynamic_slice_in_dim(x, rank * chunk, chunk,
                                             axis=x.ndim - 1)
@@ -122,7 +122,7 @@ def gather_from_tensor_model_parallel_region(x, axis_name: Optional[str] = None)
     axis = _axis(axis_name)
     if not _axis_bound(axis):
         return x
-    with span("tp/all_gather"):
+    with scope("tp/all_gather"):
         return jax.lax.all_gather(x, axis, axis=x.ndim - 1, tiled=True)
 
 
@@ -135,7 +135,7 @@ def reduce_scatter_to_tensor_model_parallel_region(x, axis_name: Optional[str] =
     axis = _axis(axis_name)
     if not _axis_bound(axis):
         return x
-    with span("tp/reduce_scatter"):
+    with scope("tp/reduce_scatter"):
         return jax.lax.psum_scatter(x, axis,
                                     scatter_dimension=x.ndim - 1,
                                     tiled=True)
@@ -156,7 +156,7 @@ def scatter_to_sequence_parallel_region(x, axis_name: Optional[str] = None,
     n = jax.lax.axis_size(axis)
     rank = jax.lax.axis_index(axis)
     chunk = x.shape[seq_dim] // n
-    with span("sp/scatter"):
+    with scope("sp/scatter"):
         x = _to_varying(x, axis)
         return jax.lax.dynamic_slice_in_dim(x, rank * chunk, chunk,
                                             axis=seq_dim)
@@ -167,7 +167,7 @@ def gather_from_sequence_parallel_region(x, axis_name: Optional[str] = None,
     axis = _axis(axis_name)
     if not _axis_bound(axis):
         return x
-    with span("sp/all_gather"):
+    with scope("sp/all_gather"):
         return jax.lax.all_gather(x, axis, axis=seq_dim, tiled=True)
 
 
@@ -177,6 +177,6 @@ def reduce_scatter_to_sequence_parallel_region(x, axis_name: Optional[str] = Non
     axis = _axis(axis_name)
     if not _axis_bound(axis):
         return x
-    with span("sp/reduce_scatter"):
+    with scope("sp/reduce_scatter"):
         return jax.lax.psum_scatter(x, axis, scatter_dimension=seq_dim,
                                     tiled=True)
