@@ -167,6 +167,27 @@ def _causal_softmax():
             x, name="causal_softmax")
 
 
+@target("causal_softmax_bwd")
+def _causal_softmax_bwd():
+    """Forward and backward kernels (``apex_softmax_bwd``) at once: the
+    pallas-block check sees the backward's three row blocks."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.ops import pallas_config
+    from apex_tpu.transformer.functional.fused_softmax import (
+        scaled_upper_triang_masked_softmax,
+    )
+
+    def loss(x):
+        y = scaled_upper_triang_masked_softmax(x, None, 1.0)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    x = jnp.zeros((8, 256, 1024), jnp.bfloat16)
+    with pallas_config.force("on"):
+        return analyze_fn(jax.grad(loss), x, name="causal_softmax_bwd")
+
+
 @target("tp_collectives")
 def _tp_collectives():
     """Tensor-parallel allreduce wiring against the live parallel_state

@@ -12,8 +12,18 @@ XLA can't express:
 - softmax statistics are computed in fp32 in VMEM regardless of the bf16
   storage dtype (same accumulator policy as the CUDA kernels).
 
-Backward is left to autodiff: softmax's vjp is a row reduction XLA fuses.
-Non-TPU backends (the CPU test mesh) use the identical-math jnp fallback.
+The backward has a kernel of its own, ``apex_softmax_bwd``, behind both
+``custom_vjp`` rules: dx = scale * y * (g - sum(g * y)) over blocks of whole
+rows, reading the saved probabilities and the cotangent in the dtype autodiff
+hands over (bf16 under amp O2) and writing dx once, float32 arithmetic in
+VMEM. It needs no mask (y is 0 wherever the forward masked). Left to XLA, as
+it was until PR 27, the row sum was lowered on the v5e to a ``reduce-window``
+2,047 wide behind a layout copy of a float32 cotangent: ``fusion.323`` 0.867 s
+and ``copy.170`` 0.550 s of the 5.44 s busy in GPT-2 345M's traced steps, 26%
+of the step (PERF_LEDGER.jsonl, PR 26 ``breakdown``; PERF.md section 6). Rows
+longer than ``_WHOLE_ROW_MAX_SK`` keys (no benchmark cell runs them) and
+non-TPU backends (the CPU test mesh) keep the identical-math jnp backward, as
+non-TPU backends keep the jnp forward.
 """
 
 from __future__ import annotations
@@ -80,9 +90,11 @@ def _largest_divisor(s: int, target: int) -> int:
     return b
 
 
-def _pick_block_rows(sq: int, sk: int) -> int:
+def _pick_block_rows(sq: int, sk: int, budget: Optional[int] = None) -> int:
     # largest divisor of sq whose fp32 row block fits the VMEM budget
-    return _largest_divisor(sq, max(8, _VMEM_ROW_BUDGET // (4 * sk)))
+    # (the forward's unless the caller has one of its own)
+    budget = _VMEM_ROW_BUDGET if budget is None else budget
+    return _largest_divisor(sq, max(8, budget // (4 * sk)))
 
 
 def _pallas_ok(sq: int, sk: int) -> bool:
@@ -278,11 +290,55 @@ def _pallas_masked(x, mask, scale):
     return out.reshape(lead + (sq, sk))
 
 
+# ---------------------------------------------------------------- Pallas bwd
+# One kernel for both custom_vjp rules. The backward needs no mask: a
+# masked position has y == 0 exactly (causal) or y ~ exp(-10000) == 0, so
+# dx = scale * y * (g - sum(g * y)) is 0 there by the formula. One grid
+# step holds a block of whole rows of y, g and dx; the row sum is a lane
+# reduction in VMEM, all arithmetic float32 as in the forward.
+
+# fp32 row-block budget of the backward: y, g and dx blocks double-buffered
+# in their storage dtype plus the float32 temporaries of one block must fit
+# the ~16 MiB VMEM, so half the forward's.
+_VMEM_BWD_ROW_BUDGET = _VMEM_ROW_BUDGET // 2
+
+
+def _bwd_kernel(scale, y_ref, g_ref, dx_ref):
+    y = y_ref[:].astype(jnp.float32)  # [1, block_rows, sk]
+    g = g_ref[:].astype(jnp.float32)
+    inner = jnp.sum(g * y, axis=-1, keepdims=True)
+    dx_ref[:] = (scale * y * (g - inner)).astype(dx_ref.dtype)
+
+
+def _pallas_softmax_bwd(scale, y, g):
+    """``y``/``g`` of any rank >= 2 with rows last; ``g`` in the dtype
+    autodiff hands over, ``dx`` in ``y.dtype``."""
+    sq, sk = y.shape[-2:]
+    y3 = y.reshape((-1, sq, sk))
+    g3 = g.reshape((-1, sq, sk))
+    rows = _pick_block_rows(sq, sk, _VMEM_BWD_ROW_BUDGET)
+    blk = (1, rows, sk)
+    idx = lambda i, j: (i, j, 0)
+    dx = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale),
+        out_shape=pallas_config.out_struct(y3.shape, y.dtype, y3, g3),
+        grid=(y3.shape[0], sq // rows),
+        in_specs=[pl.BlockSpec(blk, idx), pl.BlockSpec(blk, idx)],
+        out_specs=pl.BlockSpec(blk, idx),
+        name="apex_softmax_bwd",
+        interpret=pallas_config.interpret(),
+    )(y3, g3)
+    return dx.reshape(y.shape)
+
+
 # -------------------------------------------------------------- custom vjp
-# Pallas kernels are forward-only; the backward is the standard softmax vjp
-# dx = scale · y · (g − Σ g·y), a row reduction XLA fuses. Saving only ``y``
-# (not the masked pre-softmax logits) matches the CUDA kernels' backward
-# (ref csrc/megatron/scaled_masked_softmax.h bwd reads softmax output).
+# The backward is the standard softmax vjp dx = scale * y * (g - sum(g * y)).
+# Saving only ``y`` (not the masked pre-softmax logits) matches the CUDA
+# kernels' backward (ref csrc/megatron/scaled_masked_softmax.h bwd reads
+# softmax output). Both rules dispatch it by what they can observe, as the
+# forward does: ``apex_softmax_bwd`` wherever the forward took its whole-row
+# kernel, ``_softmax_bwd_math`` for rows longer than ``_WHOLE_ROW_MAX_SK``
+# and off the TPU (why not XLA on the TPU: the module docstring).
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -306,8 +362,15 @@ def _softmax_bwd_math(scale, y, g):
     return (scale * y32 * (g32 - inner)).astype(y.dtype)
 
 
+def _softmax_bwd(scale, y, g):
+    sq, sk = y.shape[-2:]
+    if sk <= _WHOLE_ROW_MAX_SK and _pallas_ok(sq, sk):
+        return _pallas_softmax_bwd(scale, y, g)
+    return _softmax_bwd_math(scale, y, g)
+
+
 def _causal_softmax_bwd(scale, y, g):
-    return (_softmax_bwd_math(scale, y, g),)
+    return (_softmax_bwd(scale, y, g),)
 
 
 _causal_softmax.defvjp(_causal_softmax_fwd, _causal_softmax_bwd)
@@ -327,7 +390,7 @@ def _masked_softmax_fwd(x, mask, scale):
 
 
 def _masked_softmax_bwd(scale, y, g):
-    return (_softmax_bwd_math(scale, y, g), None)
+    return (_softmax_bwd(scale, y, g), None)
 
 
 _masked_softmax.defvjp(_masked_softmax_fwd, _masked_softmax_bwd)

@@ -254,6 +254,28 @@ def _kernel_checks(shapes):
             lambda x: scaled_masked_softmax(x, mask, 0.5), x),
             what="masked softmax")
 
+    def softmax_bwd():
+        # one backward kernel behind both custom_vjp rules: the causal
+        # one square, the masked one rectangular
+        bh, sm = shapes["causal_softmax"]
+        x = jax.random.normal(jax.random.PRNGKey(15), (bh, sm, sm),
+                              jnp.bfloat16)
+        causal_sm = jax.grad(_sq_loss(
+            lambda x: scaled_upper_triang_masked_softmax(x, None, 0.125)))
+        with pallas_config.force(_kernel_mode()):
+            _expect_mosaic(jax.jit(causal_sm).lower(x).as_text(),
+                           "apex_softmax_bwd")
+        _close(*_pallas_vs_jnp(causal_sm, x), atol=2e-3,
+               what="causal softmax dx")
+        bh, sm = shapes["masked_softmax"]
+        x = jax.random.normal(jax.random.PRNGKey(16),
+                              (4, bh // 4, sm // 2, sm), jnp.bfloat16)
+        mask = jax.random.uniform(jax.random.PRNGKey(17),
+                                  (4, 1, sm // 2, sm)) > 0.8
+        _close(*_pallas_vs_jnp(jax.grad(_sq_loss(
+            lambda x: scaled_masked_softmax(x, mask, 0.5))), x), atol=2e-3,
+            what="masked softmax dx")
+
     def flat_adam():
         # not the default since the XLA chain won the cost study, but it
         # must still execute when forced on: scalar (1, 4) block + slab
@@ -302,6 +324,7 @@ def _kernel_checks(shapes):
             ("layer_norm_odd_rows", ln_odd_rows),
             ("causal_softmax", causal_softmax),
             ("masked_softmax", masked_softmax),
+            ("softmax_bwd", softmax_bwd),
             ("flat_adam_kernel", flat_adam),
             ("fp8_cast_kernel", fp8_cast)]
 
@@ -414,7 +437,7 @@ def phase_train(cfg=None, batch=8, steps=8) -> dict:
     compiled = step.lower(*state, data).compile()
     say(f"  train step compiled in {time.perf_counter() - t0:.1f}s")
     _expect_mosaic(compiled.as_text(), "apex_ln_fwd", "apex_ln_bwd",
-                   "apex_causal_softmax")
+                   "apex_causal_softmax", "apex_softmax_bwd")
     state, losses = _run_steps(compiled, state, data, steps)
     # ln(vocab) plus half the init's logit variance (~1)
     ln_v = float(np.log(cfg.vocab_size))

@@ -322,6 +322,141 @@ def test_softmax_interpret_grads():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
+# ------------------------------------------------- fused softmax backward
+
+
+def _probs_and_cotangent(kind, dtype):
+    """Saved probabilities as the forward would hand them to the backward
+    rule, and a cotangent of the same shape."""
+    shape = {"causal_square": (3, 32, 32), "causal_rect": (2, 16, 64),
+             "masked": (2, 2, 16, 48)}[kind]
+    x = jax.random.normal(jax.random.PRNGKey(0), shape, dtype)
+    if kind == "masked":
+        mask = jax.random.bernoulli(jax.random.PRNGKey(1), 0.3,
+                                    (2, 1, 16, 48))
+        y = scaled_masked_softmax(x, mask, 0.7)
+    else:
+        y = scaled_upper_triang_masked_softmax(x, None, 0.7)
+    g = jax.random.normal(jax.random.PRNGKey(2), shape, dtype)
+    return y, g
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("kind", ["causal_square", "causal_rect", "masked"])
+def test_softmax_bwd_kernel_matches_math(kind, dtype):
+    """One kernel for both rules: no mask needed, y is 0 where masked."""
+    from apex_tpu.transformer.functional import fused_softmax as fs
+
+    y, g = _probs_and_cotangent(kind, dtype)
+    ref = fs._softmax_bwd_math(0.7, y, g)
+    with pallas_config.force("interpret"):
+        out = fs._pallas_softmax_bwd(0.7, y, g)
+    assert out.dtype == y.dtype and out.shape == y.shape
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=1e-6 if dtype == jnp.float32 else 1e-3)
+
+
+def test_softmax_bwd_kernel_takes_the_cotangent_as_handed_over():
+    """bf16 probabilities with a float32 cotangent: dx comes back in the
+    probabilities' dtype, computed from the unrounded cotangent."""
+    from apex_tpu.transformer.functional import fused_softmax as fs
+
+    y, g = _probs_and_cotangent("causal_square", jnp.bfloat16)
+    g = g.astype(jnp.float32) * 1.001
+    ref = fs._softmax_bwd_math(1.3, y, g)
+    with pallas_config.force("interpret"):
+        out = fs._pallas_softmax_bwd(1.3, y, g)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(ref, np.float32))
+
+
+def _calls_softmax_bwd_kernel(fn, *args):
+    return "apex_softmax_bwd" in str(jax.make_jaxpr(fn)(*args))
+
+
+@pytest.mark.parametrize("kind", ["causal", "masked"])
+def test_softmax_grad_through_kernel_matches_autodiff(kind):
+    """jax.grad through the public ops in interpret mode (Pallas forward
+    and backward) against autodiff of the plain jnp softmax."""
+    if kind == "causal":
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 40),
+                              jnp.float32)
+        mask = jnp.arange(40)[None, :] > jnp.arange(24)[:, None] + 16
+        fused = lambda x: scaled_upper_triang_masked_softmax(x, None, 0.9)
+    else:
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 2, 16, 48),
+                              jnp.float32)
+        mask = jax.random.bernoulli(jax.random.PRNGKey(1), 0.3,
+                                    (2, 1, 16, 48))
+        fused = lambda x: scaled_masked_softmax(x, mask, 0.9)
+    w = jax.random.normal(jax.random.PRNGKey(3), x.shape, jnp.float32)
+
+    def plain(x):
+        return jax.nn.softmax(jnp.where(mask, -10000.0, x * 0.9), axis=-1)
+
+    ref = jax.grad(lambda x: jnp.sum(plain(x) * w))(x)
+    loss = lambda x: jnp.sum(fused(x) * w)
+    with pallas_config.force("interpret"):
+        assert _calls_softmax_bwd_kernel(jax.grad(loss), x)
+        out = jax.grad(loss)(x)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+
+
+def test_softmax_bwd_long_rows_keep_the_jnp_math(monkeypatch):
+    """sk beyond the whole-row limit: blocked forward kernels, and the
+    backward stays _softmax_bwd_math (no kernel, same gradient)."""
+    from apex_tpu.transformer.functional import fused_softmax as fs
+
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 96, 96), jnp.float32)
+    loss = lambda x: jnp.sum(
+        scaled_upper_triang_masked_softmax(x, None, 0.9) ** 2)
+    ref = jax.grad(loss)(x)
+    with pallas_config.force("interpret"):
+        assert _calls_softmax_bwd_kernel(jax.grad(loss), x)
+        monkeypatch.setattr(fs, "_WHOLE_ROW_MAX_SK", 64)
+        monkeypatch.setattr(fs, "_BLOCKED_BK", 32)
+        assert not _calls_softmax_bwd_kernel(jax.grad(loss), x)
+        out = jax.grad(loss)(x)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_softmax_bwd_off_tpu_keeps_the_jnp_math():
+    """'auto' on the CPU backend: neither direction takes a kernel."""
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 32, 32), jnp.float32)
+    loss = lambda x: jnp.sum(
+        scaled_upper_triang_masked_softmax(x, None, 0.9) ** 2)
+    assert "pallas_call" not in str(jax.make_jaxpr(jax.grad(loss))(x))
+
+
+@pytest.mark.multidevice(n=4)
+def test_softmax_bwd_kernel_under_shard_map_check_vma():
+    """The four-chip cell's shape of use: forward and backward kernels
+    inside shard_map with check_vma on, rows split over dp. check_vma types
+    the kernel's output while tracing (out_struct carries the inputs' vma),
+    so the gradient is traced and lowered for TPU on the CPU mesh; the
+    interpreter cannot run kernel bodies there (it binds a kernel's own
+    constants against varying operands), the values are the tests' above."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
+    x = jnp.ones((8, 128, 256), jnp.bfloat16)
+
+    def grad(x, w):
+        return jax.grad(lambda x: jnp.sum(
+            scaled_upper_triang_masked_softmax(x, None, 0.8) * w))(x)
+
+    sharded = jax.jit(jax.shard_map(
+        grad, mesh=mesh, in_specs=(P("dp"), P("dp")), out_specs=P("dp"),
+        check_vma=True))
+    with pallas_config.force("on"):
+        traced = sharded.trace(x, x)
+        assert "apex_softmax_bwd" in str(traced.jaxpr)
+        assert traced.out_info.shape == x.shape
+        traced.lower(lowering_platforms=("tpu",))
+
+
 # -------------------------------------------------- k-blocked long softmax
 
 

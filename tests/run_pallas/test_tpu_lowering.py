@@ -26,9 +26,13 @@ from apex_tpu.transformer.functional.fused_softmax import (
 )
 
 
-def lowers_for_tpu(fn, *args):
+def lowers_for_tpu(fn, *args, kernel=None):
+    """Lower ``fn`` for TPU; with ``kernel``, the traced program must call
+    the Pallas kernel of that name."""
     with pallas_config.force("on"):
-        jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+        traced = jax.jit(fn).trace(*args)
+        assert kernel is None or kernel in str(traced.jaxpr)
+        traced.lower(lowering_platforms=("tpu",))
 
 
 B, S, H, D = 2, 512, 4, 128
@@ -229,12 +233,25 @@ class TestSoftmaxLowering:
             y = scaled_upper_triang_masked_softmax(x, None, 1.0)
             return jnp.sum(y.astype(jnp.float32))
 
-        lowers_for_tpu(jax.grad(loss), x)
+        lowers_for_tpu(jax.grad(loss), x, kernel="apex_softmax_bwd")
 
     def test_masked(self):
         x = jnp.ones((2, 4, 256, 256), jnp.bfloat16)
         mask = jnp.zeros((2, 1, 256, 256), bool)
         lowers_for_tpu(lambda x: scaled_masked_softmax(x, mask, 0.5), x)
+
+    @pytest.mark.parametrize("cotangent", [jnp.bfloat16, jnp.float32])
+    def test_masked_bwd(self, cotangent):
+        """apex_softmax_bwd through the masked rule, rectangular, with the
+        cotangent in the dtype its consumer hands over."""
+        x = jnp.ones((2, 4, 256, 384), jnp.bfloat16)
+        mask = jnp.zeros((2, 1, 256, 384), bool)
+
+        def loss(x):
+            y = scaled_masked_softmax(x, mask, 0.5)
+            return jnp.sum(y.astype(cotangent) ** 2)
+
+        lowers_for_tpu(jax.grad(loss), x, kernel="apex_softmax_bwd")
 
     def test_blocked_long_sk(self, monkeypatch):
         # force the two-pass k-blocked kernels

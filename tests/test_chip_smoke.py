@@ -34,7 +34,7 @@ def interpret():
 
 
 def test_phase_kernels_tiny(interpret):
-    assert chip_smoke.phase_kernels(TINY_KERNELS) == {"checks": 12}
+    assert chip_smoke.phase_kernels(TINY_KERNELS) == {"checks": 13}
 
 
 def test_phase_train_then_mesh_tiny():
