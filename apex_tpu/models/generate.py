@@ -6,8 +6,9 @@ weights, and the natural smoke test of real weights is sampling. The
 design is decode-native rather than a re-run of the training forward:
 
 - static shapes throughout: the cache is ``[L, b, max_len, nkv, d]``
-  and a position mask (``idx <= pos``) replaces dynamic slicing, so the
-  whole generation loop is ONE ``lax.scan`` under jit;
+  (``L = cfg.cache_layers``: a looped llama stack keeps K and V of every
+  pass, pass-major) and a position mask (``idx <= pos``) replaces dynamic
+  slicing, so the whole generation loop is ONE ``lax.scan`` under jit;
 - prefill is a single full-sequence pass (flash attention) that also
   emits every layer's rotated k / v — the prompt costs one step, not
   one step per token;
@@ -145,11 +146,13 @@ def _decode_layer(x, lp, cfg, k_cache, v_cache, pos):
     v_cache = jax.lax.dynamic_update_slice_in_dim(
         v_cache, v.astype(v_cache.dtype), pos, axis=1)
     o = _decode_attention(q, k_cache, v_cache, pos).astype(x.dtype)
-    x = x + jnp.matmul(o, lp["wo"].astype(x.dtype))
+    x = x + _llama.post_norm(jnp.matmul(o, lp["wo"].astype(x.dtype)), lp,
+                             "attn_post_norm", cfg)
     hm = _llama._rmsnorm(x, lp["mlp_norm"], cfg.rms_eps)
-    if cfg.moe:
-        return x + _moe_decode_ffn(hm, lp, cfg), k_cache, v_cache
-    return x + _dense_ffn(hm, lp, x.dtype), k_cache, v_cache
+    y = (_moe_decode_ffn(hm, lp, cfg) if cfg.moe
+         else _dense_ffn(hm, lp, x.dtype))
+    return (x + _llama.post_norm(y, lp, "mlp_post_norm", cfg), k_cache,
+            v_cache)
 
 
 def _prefill_layer(x, lp, cfg, positions):
@@ -160,11 +163,13 @@ def _prefill_layer(x, lp, cfg, positions):
     q, k, v = _layer_qkv(h, lp, cfg, positions)
     o = flash_attention(q, k, v, causal=True, scale=cfg.head_dim ** -0.5)
     b, s = x.shape[:2]
-    x = x + jnp.matmul(o.reshape(b, s, -1), lp["wo"].astype(x.dtype))
+    x = x + _llama.post_norm(
+        jnp.matmul(o.reshape(b, s, -1), lp["wo"].astype(x.dtype)), lp,
+        "attn_post_norm", cfg)
     hm = _llama._rmsnorm(x, lp["mlp_norm"], cfg.rms_eps)
-    if cfg.moe:
-        return x + _moe_prefill_ffn(hm, lp, cfg), k, v
-    return x + _dense_ffn(hm, lp, x.dtype), k, v
+    y = (_moe_prefill_ffn(hm, lp, cfg) if cfg.moe
+         else _dense_ffn(hm, lp, x.dtype))
+    return x + _llama.post_norm(y, lp, "mlp_post_norm", cfg), k, v
 
 
 def _logits(params, x, cfg):
@@ -179,25 +184,19 @@ def _sample(logits, temperature, key):
     return jnp.argmax(logits, axis=-1)
 
 
-def _autoregress(embed_step, decode_layer_fn, logits_fn, layers,
+def _autoregress(embed_step, decode_stack, logits_fn,
                  k_cache, v_cache, logits0, prompt_tokens,
                  max_new_tokens, temperature, key):
     """The shared decode loop: max_new-1 scan steps, each consuming the
     previous token and emitting the next (the final token needs no
-    decode pass)."""
+    decode pass). ``decode_stack(x, (k, v), pos) -> (x, (k, v))`` is the
+    model's whole depth for one token."""
     key, key0 = jax.random.split(key)
     first = _sample(logits0, temperature, key0)[:, None]
 
     def step(carry, key_t):
         token, kc, vc, pos = carry
-        x = embed_step(token, pos)
-
-        def body(h, layer):
-            lp, k1, v1 = layer
-            h, k1, v1 = decode_layer_fn(h, lp, k1, v1, pos)
-            return h, (k1, v1)
-
-        x, (kc, vc) = jax.lax.scan(body, x, (layers, kc, vc))
+        x, (kc, vc) = decode_stack(embed_step(token, pos), (kc, vc), pos)
         nxt = _sample(logits_fn(x)[:, 0], temperature, key_t)
         return (nxt[:, None], kc, vc, pos + 1), nxt
 
@@ -233,21 +232,30 @@ def generate(params, prompt_tokens, cfg, max_new_tokens: int,
     positions = jnp.broadcast_to(jnp.arange(p), (b, p))
     x = _llama.embed(params, prompt_tokens, cfg, tp_axis=None)
 
-    def pre_body(h, lp):
+    def pre_body(h, lp, _):
         h, k, v = _prefill_layer(h, lp, cfg, positions)
         return h, (k, v)
 
-    x, (ks, vs) = jax.lax.scan(pre_body, x, params["layers"])
+    x, (ks, vs) = _llama.scan_passes(x, params, cfg, pre_body,
+                                     params["layers"])
     pad = [(0, 0), (0, 0), (0, max_new_tokens), (0, 0), (0, 0)]
-    k_cache = jnp.pad(ks.astype(cfg.dtype), pad)  # [L, b, max_len, ...]
+    k_cache = jnp.pad(ks.astype(cfg.dtype), pad)  # [T*L, b, max_len, ...]
     v_cache = jnp.pad(vs.astype(cfg.dtype), pad)
     logits0 = _logits(params, x[:, -1:], cfg)[:, 0]
 
+    def decode_stack(x, caches, pos):
+        def layer(h, lp, cache):
+            h, k1, v1 = _decode_layer(h, lp, cfg, *cache, pos)
+            return h, (k1, v1)
+
+        return _llama.scan_passes(x, params, cfg, layer, params["layers"],
+                                  caches)
+
     return _autoregress(
         lambda token, pos: _llama.embed(params, token, cfg, tp_axis=None),
-        lambda h, lp, kc, vc, pos: _decode_layer(h, lp, cfg, kc, vc, pos),
+        decode_stack,
         lambda x: _logits(params, x, cfg),
-        params["layers"], k_cache, v_cache, logits0, prompt_tokens,
+        k_cache, v_cache, logits0, prompt_tokens,
         max_new_tokens, temperature, key)
 
 
@@ -342,9 +350,15 @@ def gpt2_generate(params, prompt_tokens, cfg, max_new_tokens: int,
     v_cache = jnp.pad(vs.astype(cfg.dtype), pad)
     logits0 = logits_fn(x[:, -1:])[:, 0]
 
+    def decode_stack(x, caches, pos):
+        def body(h, layer):
+            lp, k1, v1 = layer
+            h, k1, v1 = _gpt2_decode_layer(h, lp, cfg, k1, v1, pos)
+            return h, (k1, v1)
+
+        return jax.lax.scan(body, x, (params["layers"],) + tuple(caches))
+
     return _autoregress(
-        lambda token, pos: embed(token, pos),
-        lambda h, lp, kc, vc, pos: _gpt2_decode_layer(h, lp, cfg, kc, vc,
-                                                      pos),
-        logits_fn, params["layers"], k_cache, v_cache, logits0,
+        lambda token, pos: embed(token, pos), decode_stack,
+        logits_fn, k_cache, v_cache, logits0,
         prompt_tokens, max_new_tokens, temperature, key)

@@ -25,6 +25,15 @@ under tp-only shard_map, and as one pipeline stage:
         over the 'ep' axis, the router replicates. The load-balancing aux
         loss is returned by :func:`loss_fn`; the pipeline ``stage_fn``
         path drops it (documented — activations are the only pp payload).
+
+Looped stacks (Ouro): ``num_passes > 1`` applies the SAME [L, ...] stack
+that many times (:func:`scan_passes`), the final norm between passes, and
+``sandwich_norm`` norms each sub-layer's output as well as its input.
+Pass ``t`` of layer ``l`` has keys and values of its own, so anything that
+caches them holds ``cfg.cache_layers = num_passes * num_layers`` layers,
+pass-major. The exit gate's weights are in the tree; the served token is
+the last pass's (the published ``early_exit_threshold`` of 1), and
+:func:`exit_distribution` gives the gate's reading of every pass.
 """
 
 from __future__ import annotations
@@ -77,10 +86,21 @@ class LlamaConfig:
     num_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
+    # looped stack: the [L, ...] layers run this many times over the same
+    # weights, the final norm between passes (Ouro's ``total_ut_steps``)
+    num_passes: int = 1
+    # RMSNorm on each sub-layer's output too, before the residual add
+    sandwich_norm: bool = False
 
     @property
     def moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers of K and V behind ``num_layers`` layers of weights: pass
+        ``t`` of layer ``l`` is cache layer ``t * num_layers + l``."""
+        return self.num_passes * self.num_layers
 
     @property
     def head_dim(self) -> int:
@@ -136,6 +156,9 @@ def init_params(key, cfg: LlamaConfig):
         "wo": norm(ks[4], L, nq * d, h),
         "mlp_norm": jnp.ones((L, h), dt),
     }
+    if cfg.sandwich_norm:
+        layers["attn_post_norm"] = jnp.ones((L, h), dt)
+        layers["mlp_post_norm"] = jnp.ones((L, h), dt)
     if cfg.moe:
         E = cfg.num_experts
         layers.update({
@@ -158,11 +181,22 @@ def init_params(key, cfg: LlamaConfig):
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = norm(ks[8], h, cfg.vocab_size, fan_in=h)
+    if cfg.num_passes > 1:
+        # Linear(h, 1): one exit logit a position after every pass
+        params["exit_gate"] = {
+            "w": norm(jax.random.fold_in(key, 10), h, 1, fan_in=h),
+            "b": jnp.zeros((1,), dt)}
     return params
 
 
 def _rmsnorm(x, w, eps):
     return fused_rms_norm_affine(x, w, (x.shape[-1],), eps=eps)
+
+
+def post_norm(y, lp, name, cfg: LlamaConfig):
+    """A sub-layer's output on its way to the residual add: normed by
+    ``lp[name]`` under ``sandwich_norm``, untouched otherwise."""
+    return _rmsnorm(y, lp[name], cfg.rms_eps) if cfg.sandwich_norm else y
 
 
 def _attention(x, lp, cfg: LlamaConfig, positions, tp_axis, cp_axis,
@@ -274,15 +308,16 @@ def decoder_layer(x, lp, cfg: LlamaConfig, positions,
         return h
 
     h = to_full(_rmsnorm(x, lp["attn_norm"], cfg.rms_eps))
-    x = x + _attention(h, lp, cfg, positions, tp_axis, cp_axis,
-                       sequence_parallel)
+    x = x + post_norm(_attention(h, lp, cfg, positions, tp_axis, cp_axis,
+                                 sequence_parallel),
+                      lp, "attn_post_norm", cfg)
     h = to_full(_rmsnorm(x, lp["mlp_norm"], cfg.rms_eps))
     if cfg.moe:
         y, aux = _moe_mlp(h, lp, cfg, ep_axis, tp_axis, sequence_parallel)
     else:
         y, aux = _mlp(h, lp, tp_axis, sequence_parallel), jnp.zeros(
             (), jnp.float32)
-    return x + y, aux
+    return x + post_norm(y, lp, "mlp_post_norm", cfg), aux
 
 
 def _positions(b, s_local, cp_axis):
@@ -293,18 +328,9 @@ def _positions(b, s_local, cp_axis):
     return jnp.broadcast_to(pos[None, :], (b, s_local))
 
 
-def run_layers(x, stacked, cfg: LlamaConfig, positions,
-               tp_axis="tp", cp_axis="cp", sequence_parallel=False,
-               remat=True, ep_axis: Optional[str] = "ep"):
-    """Scan a stacked [L, ...] layer pytree over the residual stream.
-    Returns ``(x, aux)`` — aux sums the per-layer MoE balance losses.
-
-    ``remat``: False = save all activations; True = full per-layer
-    recompute; ``"dots"`` = recompute only elementwise/norm chains while
-    keeping matmul outputs resident
-    (``jax.checkpoint_policies.dots_with_no_batch_dims_saveable``) — the
-    usual best memory/MFU trade on TPU, where the recompute that hurts is
-    the MXU work, not the VPU chains."""
+def _layer_body(cfg: LlamaConfig, positions, tp_axis, cp_axis,
+                sequence_parallel, remat, ep_axis):
+    """``(h, lp) -> (h, aux)`` for one layer, under the remat policy."""
 
     def body(h, lp):
         # aux rides the scan's stacked outputs, not the carry — a fresh
@@ -312,6 +338,14 @@ def run_layers(x, stacked, cfg: LlamaConfig, positions,
         return decoder_layer(h, lp, cfg, positions, tp_axis, cp_axis,
                              sequence_parallel, ep_axis)
 
+    if remat:
+        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+                  if remat == "dots" else None)
+        body = jax.checkpoint(body, policy=policy)
+    return body
+
+
+def _ep_varying(x, cfg: LlamaConfig, ep_axis):
     if cfg.moe and _axis_bound(ep_axis):
         # the MoE all_to_all makes the stream ep-varying; the carry must
         # start that way or the scan's vma check trips
@@ -320,12 +354,63 @@ def run_layers(x, stacked, cfg: LlamaConfig, positions,
         )
 
         x = _to_varying(x, ep_axis)
-    if remat:
-        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                  if remat == "dots" else None)
-        body = jax.checkpoint(body, policy=policy)
-    x, auxs = jax.lax.scan(body, x, stacked)
+    return x
+
+
+def run_layers(x, stacked, cfg: LlamaConfig, positions,
+               tp_axis="tp", cp_axis="cp", sequence_parallel=False,
+               remat=True, ep_axis: Optional[str] = "ep"):
+    """Scan a stacked [L, ...] layer pytree over the residual stream, ONCE
+    (a looped model's passes are :func:`scan_passes`'s).
+    Returns ``(x, aux)`` — aux sums the per-layer MoE balance losses.
+
+    ``remat``: False = save all activations; True = full per-layer
+    recompute; ``"dots"`` = recompute only elementwise/norm chains while
+    keeping matmul outputs resident
+    (``jax.checkpoint_policies.dots_with_no_batch_dims_saveable``) — the
+    usual best memory/MFU trade on TPU, where the recompute that hurts is
+    the MXU work, not the VPU chains."""
+    body = _layer_body(cfg, positions, tp_axis, cp_axis, sequence_parallel,
+                       remat, ep_axis)
+    x, auxs = jax.lax.scan(body, _ep_varying(x, cfg, ep_axis), stacked)
     return x, jnp.sum(auxs)
+
+
+def scan_passes(x, params, cfg: LlamaConfig, layer_fn, shared,
+                per_layer=None):
+    """Scan the residual stream through ``cfg.num_passes`` passes over the
+    ``[L, ...]`` stack: ONE ``lax.scan`` of ``cfg.cache_layers`` steps.
+
+    ``layer_fn(h, shared_l, per_layer_i) -> (h, out_i)`` applies one layer.
+    ``shared`` is what every pass uses alike (a pytree with leading axis
+    ``L``: the weights, their scales); ``per_layer`` is what each pass of
+    each layer owns (leading axis ``cfg.cache_layers``, pass-major: K and
+    V). Step ``i`` is pass ``i // L`` of layer ``i % L``; every pass after
+    the first starts from the final norm of the one before, and the last
+    pass's output is left for the head's own norm. Returns ``(x, outs)``,
+    ``outs`` stacked over the steps. With one pass this is the plain scan
+    over ``(shared, per_layer)``: a model that is not looped compiles to
+    what it did before.
+    """
+    L, T = cfg.num_layers, cfg.num_passes
+    if T == 1:
+        return jax.lax.scan(lambda h, xs: layer_fn(h, *xs), x,
+                            (shared, per_layer))
+
+    def body(h, xs):
+        i, mine = xs
+        layer = i % L
+        with jax.named_scope("llama/pass"):
+            # one select a step on [.., h]: a cond would cost the loop more
+            h = jnp.where((layer == 0) & (i > 0),
+                          _rmsnorm(h, params["final_norm"], cfg.rms_eps), h)
+            shared_l = jax.tree_util.tree_map(
+                lambda w: jax.lax.dynamic_index_in_dim(w, layer,
+                                                       keepdims=False),
+                shared)
+            return layer_fn(h, shared_l, mine)
+
+    return jax.lax.scan(body, x, (jnp.arange(T * L), per_layer))
 
 
 def embed(params, tokens, cfg: LlamaConfig, tp_axis="tp",
@@ -372,8 +457,46 @@ def hidden_states(params, tokens, cfg: LlamaConfig,
     b, s = tokens.shape
     positions = _positions(b, s, cp_axis)
     x = embed(params, tokens, cfg, tp_axis, sequence_parallel)
-    return run_layers(x, params["layers"], cfg, positions, tp_axis,
-                      cp_axis, sequence_parallel, remat, ep_axis)
+    body = _layer_body(cfg, positions, tp_axis, cp_axis, sequence_parallel,
+                       remat, ep_axis)
+    x, auxs = scan_passes(_ep_varying(x, cfg, ep_axis), params, cfg,
+                          lambda h, lp, _: body(h, lp), params["layers"])
+    return x, jnp.sum(auxs)
+
+
+def exit_distribution(params, tokens, cfg: LlamaConfig,
+                      tp_axis: Optional[str] = "tp",
+                      cp_axis: Optional[str] = "cp", remat: bool = False):
+    """A looped stack's exit gate read after every pass of the plain
+    forward: ``(lam, p)``, both ``[T, b, s]`` float32. ``lam[t]`` is
+    ``sigmoid(w . norm(h_t) + b)`` on pass ``t``'s normed output; ``p[t]``
+    is the probability of leaving after pass ``t``: ``lam[t]`` times the
+    share that has not left yet, and all of that share at the last pass,
+    so ``p`` sums to 1. The rule "first pass whose cumulative ``p`` reaches
+    ``early_exit_threshold``" picks the last pass at the published
+    threshold of 1, which is what every other path computes. The passes
+    are written out here, one ``run_layers`` each: a second wording of
+    what :func:`scan_passes` rolls into one loop."""
+    if "exit_gate" not in params:
+        raise ValueError("exit_distribution needs a looped model "
+                         "(num_passes > 1): the tree has no exit_gate")
+    b, s = tokens.shape
+    positions = _positions(b, s, cp_axis)
+    gate = params["exit_gate"]
+    x = embed(params, tokens, cfg, tp_axis)
+    lams = []
+    for _ in range(cfg.num_passes):
+        x, _aux = run_layers(x, params["layers"], cfg, positions, tp_axis,
+                             cp_axis, remat=remat, ep_axis=None)
+        x = _rmsnorm(x, params["final_norm"], cfg.rms_eps)
+        logit = jnp.matmul(x.astype(jnp.float32),
+                           gate["w"].astype(jnp.float32))[..., 0]
+        lams.append(jax.nn.sigmoid(logit + gate["b"].astype(jnp.float32)))
+    lam = jnp.stack(lams)
+    stay = jnp.cumprod(1.0 - lam, axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]], axis=0)
+    p = jnp.concatenate([(lam * before)[:-1], before[-1:]], axis=0)
+    return lam, p
 
 
 def forward_with_aux(params, tokens, cfg: LlamaConfig,
@@ -443,6 +566,8 @@ def param_specs(cfg: LlamaConfig, tp_axis: str = "tp",
         "wq": P(None, None, t), "wk": P(None, None, t),
         "wv": P(None, None, t), "wo": P(None, t, None),
     }
+    if cfg.sandwich_norm:
+        layer_specs.update(attn_post_norm=P(), mlp_post_norm=P())
     if cfg.moe:
         # experts shard over ep_axis (orthogonal to tp); router replicates
         e = ep_axis
@@ -464,6 +589,8 @@ def param_specs(cfg: LlamaConfig, tp_axis: str = "tp",
     }
     if not cfg.tie_embeddings:
         specs["lm_head"] = P(None, t)
+    if cfg.num_passes > 1:
+        specs["exit_gate"] = {"w": P(), "b": P()}
     return specs
 
 

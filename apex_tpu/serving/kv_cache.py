@@ -2,9 +2,13 @@
 
 The cache is two donated device buffers ``[L, P + 1, page_size, nkv, d]``
 (k and v) plus a host-side free-list allocator with per-request page
-accounting. Requests own page lists; the scheduler maps them into a
-static ``[B, max_pages]`` block table consumed by the jit decode step,
-so the device side never sees a dynamic shape.
+accounting. ``L`` is ``cfg.cache_layers``, the layers of K and V, which a
+looped stack has ``num_passes`` times as many of as layers of weights
+(pass-major: pass ``t`` of layer ``l`` is cache layer ``t * num_layers +
+l``); a page, the budget and every transfer are sized by it. Requests
+own page lists; the scheduler maps them into a static ``[B, max_pages]``
+block table consumed by the jit decode step, so the device side never
+sees a dynamic shape.
 
 Page ``P`` (the last one) is the *trash page*: inactive batch slots
 scatter their (masked, never-read) k/v writes there, which keeps the
@@ -26,6 +30,7 @@ not padded).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Dict, List, Optional
 
@@ -43,10 +48,10 @@ __all__ = [
 
 
 def page_hbm_bytes(cfg, page_size: int, dtype=None) -> int:
-    """Modeled HBM bytes of ONE page: k + v across all layers."""
+    """Modeled HBM bytes of ONE page: k + v across all cache layers."""
     dtype = cfg.dtype if dtype is None else dtype
     itemsize = jnp.dtype(dtype).itemsize
-    return (2 * cfg.num_layers * page_size * cfg.num_kv_heads
+    return (2 * cfg.cache_layers * page_size * cfg.num_kv_heads
             * cfg.head_dim * itemsize)
 
 
@@ -164,10 +169,25 @@ class PageAllocator:
         return sorted(p for pages in self._owned.values() for p in pages)
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _serving_write_pages(buf, idx, payload):
+    """``buf[:, idx] = payload`` on the donated buffer, so in place: the
+    un-jitted ``.at[:, idx].set`` allocated a second whole buffer for as
+    long as the write ran, a third copy beside the decode step's two once
+    the cache is most of the chip (a looped stack's is). ``payload`` is
+    ``[L, n * page_size, nkv, d]`` (prefill's) or ``[L, n, page_size, nkv,
+    d]`` (a dump's). One compile per number of pages, named apart from the
+    decode step for the recompile listener."""
+    pages = payload.astype(buf.dtype).reshape(
+        buf.shape[0], idx.shape[0], *buf.shape[2:])
+    return buf.at[:, idx].set(pages)
+
+
 class PagedKVCache:
     """The device-side paged cache + its allocator.
 
-    Buffers are ``[L, P + 1, page_size, nkv, d]`` in ``cfg.dtype``; the
+    Buffers are ``[L, P + 1, page_size, nkv, d]`` in ``cfg.dtype``, ``L``
+    being :attr:`layers` (``cfg.cache_layers``); the
     extra page at index ``P`` (:attr:`trash_page`) absorbs inactive-slot
     scatter writes. The scheduler donates both buffers into the decode
     jit each step and stores the outputs back here.
@@ -179,7 +199,8 @@ class PagedKVCache:
         self.page_size = int(page_size)
         self.dtype = cfg.dtype if dtype is None else dtype
         self.alloc = PageAllocator(self.num_pages)
-        shape = (cfg.num_layers, self.num_pages + 1, self.page_size,
+        self.layers = int(cfg.cache_layers)
+        shape = (self.layers, self.num_pages + 1, self.page_size,
                  cfg.num_kv_heads, cfg.head_dim)
         self.k_pages = jnp.zeros(shape, self.dtype)
         self.v_pages = jnp.zeros(shape, self.dtype)
@@ -200,19 +221,18 @@ class PagedKVCache:
     def write_prompt(self, pages: List[int], ks, vs) -> None:
         """Store prefill k/v ``[L, S, nkv, d]`` (S = len(pages) × page
         size) into ``pages`` in order."""
-        L = self.cfg.num_layers
+        L = self.layers
         n = len(pages)
         s = ks.shape[1]
         if s != n * self.page_size:
             raise ValueError(f"prefill length {s} != {n} pages × "
                              f"{self.page_size}")
+        if ks.shape[0] != L or vs.shape != ks.shape:
+            raise ValueError(f"prefill k/v {ks.shape} / {vs.shape} do not "
+                             f"hold this cache's {L} layers")
         idx = jnp.asarray(pages, jnp.int32)
-        kt = ks.astype(self.dtype).reshape(L, n, self.page_size,
-                                           *ks.shape[2:])
-        vt = vs.astype(self.dtype).reshape(L, n, self.page_size,
-                                           *vs.shape[2:])
-        self.k_pages = self.k_pages.at[:, idx].set(kt)
-        self.v_pages = self.v_pages.at[:, idx].set(vt)
+        self.k_pages = _serving_write_pages(self.k_pages, idx, ks)
+        self.v_pages = _serving_write_pages(self.v_pages, idx, vs)
 
     def gather_pages(self, pages: List[int]):
         """Fetch ``pages`` to host as ``(k, v)`` numpy arrays
@@ -225,11 +245,14 @@ class PagedKVCache:
         """Scatter a dumped payload back (resume path). Restoring by
         scatter — not re-prefilling — is what keeps resumed decodes
         bit-identical to the uninterrupted run."""
+        want = (self.layers, len(pages)) + self.k_pages.shape[2:]
+        if k.shape != want or v.shape != want:
+            raise ValueError(
+                f"dumped pages {k.shape} / {v.shape} do not fit this cache "
+                f"({want}: cache layers, pages, page size, kv heads, d)")
         idx = jnp.asarray(pages, jnp.int32)
-        self.k_pages = self.k_pages.at[:, idx].set(
-            jnp.asarray(k, self.dtype))
-        self.v_pages = self.v_pages.at[:, idx].set(
-            jnp.asarray(v, self.dtype))
+        self.k_pages = _serving_write_pages(self.k_pages, idx, k)
+        self.v_pages = _serving_write_pages(self.v_pages, idx, v)
 
     # ------------------------------------------------------------ defrag
 

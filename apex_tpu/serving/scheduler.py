@@ -18,6 +18,11 @@ what makes a request's token stream bit-identical regardless of what
 else shares the batch — the property the preempt/resume chaos test
 pins down.
 
+A looped stack (``cfg.num_passes > 1``) makes both shapes' layer scan
+``cfg.cache_layers`` steps long (``llama.scan_passes``): step ``i`` uses
+the weights of layer ``i % L`` and its own slice of the ``[T*L, ...]``
+page buffers. The programs, the cache and the dump all count cache layers.
+
 Admission is FCFS: a request enters when a slot is free AND its whole
 page worst case (padded prompt + max_new_tokens) can be allocated, so
 an admitted request can never deadlock on pages mid-decode. Eviction
@@ -147,6 +152,7 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     mode = _normalize_weight_mode(weight_mode)
     mm = _make_mm(mode)
     d = cfg.head_dim
+    post = _llama.post_norm
 
     def _layer(x, lp, sc, kp, vp, tables, pos, page_idx, off):
         b = x.shape[0]
@@ -164,11 +170,13 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
         vg = vp[tables].reshape(b, -1, cfg.num_kv_heads, d)
         o = _gen._decode_attention(q, kg, vg,
                                    pos[:, None, None]).astype(x.dtype)
-        x = x + mm(o, lp["wo"], sc.get("wo"))
+        x = x + post(mm(o, lp["wo"], sc.get("wo")), lp, "attn_post_norm",
+                     cfg)
         hm = _llama._rmsnorm(x, lp["mlp_norm"], cfg.rms_eps)
         g = mm(hm, lp["wg"], sc.get("wg"))
         u = mm(hm, lp["wu"], sc.get("wu"))
-        return x + mm(jax.nn.silu(g) * u, lp["wd"], sc.get("wd")), kp, vp
+        y = mm(jax.nn.silu(g) * u, lp["wd"], sc.get("wd"))
+        return x + post(y, lp, "mlp_post_norm", cfg), kp, vp
 
     def _decode_step(params, scales, k_pages, v_pages, tokens, tables,
                      pos, active):
@@ -179,14 +187,14 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
         page_idx = jnp.where(active, page_idx, trash)
         off = pos % page_size
 
-        def body(h, layer):
-            lp, sc, kp, vp = layer
-            h, kp, vp = _layer(h, lp, sc, kp, vp, tables, pos,
+        def body(h, shared, pages):
+            h, kp, vp = _layer(h, *shared, *pages, tables, pos,
                                page_idx, off)
             return h, (kp, vp)
 
-        x, (k_pages, v_pages) = jax.lax.scan(
-            body, x, (params["layers"], scales, k_pages, v_pages))
+        x, (k_pages, v_pages) = _llama.scan_passes(
+            x, params, cfg, body, (params["layers"], scales),
+            (k_pages, v_pages))
         logits = _gen._logits(params, x, cfg)[:, 0]
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return jnp.where(active, nxt, tokens), k_pages, v_pages
@@ -197,7 +205,8 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
 def build_prefill(cfg, bucket_len: int, weight_mode: str = "native"):
     """Jit'd full-sequence prefill for ONE prompt padded to
     ``bucket_len``: ``(params, scales, prompt [1, S], true_len) ->
-    (first_token [1], ks [L, S, nkv, d], vs [L, S, nkv, d])``.
+    (first_token [1], ks [L, S, nkv, d], vs [L, S, nkv, d])``, ``L``
+    being ``cfg.cache_layers``.
 
     Causal flash attention means the pad suffix never contaminates
     real positions; the pad k/v land in the request's pages but decode
@@ -210,6 +219,7 @@ def build_prefill(cfg, bucket_len: int, weight_mode: str = "native"):
     mode = _normalize_weight_mode(weight_mode)
     mm = _make_mm(mode)
     d = cfg.head_dim
+    post = _llama.post_norm
 
     def prefill(params, scales, prompt, true_len):
         from apex_tpu.ops.flash_attention import flash_attention
@@ -218,8 +228,8 @@ def build_prefill(cfg, bucket_len: int, weight_mode: str = "native"):
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
         x = _llama.embed(params, prompt, cfg, tp_axis=None)
 
-        def body(h, layer):
-            lp, sc = layer
+        def body(h, shared, _):
+            lp, sc = shared
             hh = _llama._rmsnorm(h, lp["attn_norm"], cfg.rms_eps)
             q = mm(hh, lp["wq"], sc.get("wq")).reshape(
                 b, s, cfg.num_heads, d)
@@ -230,15 +240,16 @@ def build_prefill(cfg, bucket_len: int, weight_mode: str = "native"):
             q, k = apply_rotary_qk(q, k, positions=positions,
                                    base=cfg.rope_theta)
             o = flash_attention(q, k, v, causal=True, scale=d ** -0.5)
-            h = h + mm(o.reshape(b, s, -1), lp["wo"], sc.get("wo"))
+            h = h + post(mm(o.reshape(b, s, -1), lp["wo"], sc.get("wo")),
+                         lp, "attn_post_norm", cfg)
             hm = _llama._rmsnorm(h, lp["mlp_norm"], cfg.rms_eps)
             g = mm(hm, lp["wg"], sc.get("wg"))
             u = mm(hm, lp["wu"], sc.get("wu"))
-            h = h + mm(jax.nn.silu(g) * u, lp["wd"], sc.get("wd"))
-            return h, (k, v)
+            y = mm(jax.nn.silu(g) * u, lp["wd"], sc.get("wd"))
+            return h + post(y, lp, "mlp_post_norm", cfg), (k, v)
 
-        x, (ks, vs) = jax.lax.scan(body, x,
-                                   (params["layers"], scales))
+        x, (ks, vs) = _llama.scan_passes(
+            x, params, cfg, body, (params["layers"], scales))
         x_last = jax.lax.dynamic_slice_in_dim(x, true_len - 1, 1,
                                               axis=1)
         logits = _gen._logits(params, x_last, cfg)[:, 0]
@@ -284,6 +295,11 @@ class ContinuousBatchScheduler:
                 f"worst-case request ({self.max_pages_per_req} pages "
                 f"for prompt {max_prompt_len} + {max_new_cap} new)")
         self.cache = PagedKVCache(cfg, num_pages, page_size)
+        # what the admit and decode records say of the model's depth: the
+        # layers a token goes through (the stack's, times its passes) and
+        # the layers of K and V a page holds
+        self._depth = {"layer_passes": cfg.num_passes * cfg.num_layers,
+                       "cache_layers": self.cache.layers}
         self.queue: "collections.deque[Request]" = collections.deque()
         self.slots: List[Optional[Request]] = [None] * self.max_batch
         trash = self.cache.trash_page
@@ -382,7 +398,7 @@ class ContinuousBatchScheduler:
         s_pad = self._bucket(p)
         # rows: the decoding rows that get no token while this runs
         with host_span("serving/admit", rid=req.rid, prompt_tokens=p,
-                       bucket=s_pad, rows=self.num_active()):
+                       bucket=s_pad, rows=self.num_active(), **self._depth):
             if req.submit_s is not None:
                 get_tracer().record("serving/queue_wait",
                                     _ns(req.submit_s), _ns(req.admit_s),
@@ -398,7 +414,7 @@ class ContinuousBatchScheduler:
             self.prefill_count += 1
             n_prompt = s_pad // self.page_size
             with host_span("serving/write_prompt", rid=req.rid,
-                           pages=n_prompt):
+                           pages=n_prompt, cache_layers=self.cache.layers):
                 self.cache.write_prompt(pages[:n_prompt], ks, vs)
             with host_span("serving/first_token_fetch", rid=req.rid):
                 t0 = int(np.asarray(first)[0])
@@ -431,11 +447,11 @@ class ContinuousBatchScheduler:
         """One packed decode step; returns requests finished by it."""
         if not self._active.any():
             return []
-        # pages_gathered: what ``kp[tables]`` touches in every layer,
-        # whatever the rows hold
+        # pages_gathered: what ``kp[tables]`` touches in every cache
+        # layer, whatever the rows hold
         with host_span("serving/decode", rows=self.num_active(),
                        pages_live=self.pages_live(),
-                       pages_gathered=self._tables.size):
+                       pages_gathered=self._tables.size, **self._depth):
             with host_span("serving/decode_upload"):
                 nxt, self.cache.k_pages, self.cache.v_pages = self._decode(
                     self.params, self._scales,

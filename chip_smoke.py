@@ -16,6 +16,10 @@ supports (weights random, from a seed), and checks what comes out:
 - *serve*    ``ServingEngine`` on ``llama.flagship_0p9b()`` with the live
              page budget, eight requests of 64/520/1024-token prompts,
              checked against ``models.generate.generate()``;
+- *looped*   ``ServingEngine`` on a two-layer, four-pass llama stack at
+             Ouro-2.6B's widths (eight cache layers behind two layers of
+             weights), four requests, checked on logits against the plain
+             reference ``perfbench/references/ouro_looped.py``;
 - *mesh*     (4+ chips) the same GPT-2 step on a dp=2 x tp=2 mesh, and a
              dp=4 DDP step through ``sync_autodiff_gradients``.
 
@@ -564,6 +568,93 @@ def phase_serve(cfg=None, mix=SERVE_MIX, requests=8, max_batch=8,
     return {"exact": exact, "near_ties": ties}
 
 
+# ------------------------------------------------------------------- looped
+
+# Ouro-2.6B's widths (perfbench/configs/ouro_2p6b.json) at two layers: the
+# stack runs four times over the same weights, every sub-layer's output is
+# normed, and the cache holds 4 x 2 layers
+LOOPED = {"hidden_size": 2048, "intermediate_size": 5632,
+          "num_attention_heads": 16, "num_key_value_heads": 16,
+          "head_dim": 128, "num_hidden_layers": 2, "vocab_size": 49152,
+          "rms_norm_eps": 1e-6, "rope_theta": 1000000.0,
+          "total_ut_steps": 4, "tie_word_embeddings": False,
+          "torch_dtype": "bfloat16"}
+LOOPED_MIX = ((40, 24), (100, 16), (64, 24), (128, 8))
+# The widest gap by which a served token's float32 reference logit may lie
+# below the reference's best: bf16 through 8 layer passes reads 0.02-0.06 at
+# these widths (the 16-layer Mistral cells of PERF.md read the same), and a
+# model with a pass or the output norms left out reads over 1.
+LOOPED_GAP = 0.2
+
+
+def phase_serve_looped(cfg_dict=None, mix=LOOPED_MIX, max_batch=4,
+                       page_size=64, gap_limit=LOOPED_GAP) -> dict:
+    """A stack run several times over shared weights, through the engine,
+    against the plain reference's full forward: logits, not tokens."""
+    from perfbench.references import ouro_looped as ref
+    from perfbench.references.common import seed_words
+
+    cfg_dict = dict(cfg_dict or LOOPED)
+    cfg = llama.LlamaConfig(
+        vocab_size=cfg_dict["vocab_size"], hidden_size=cfg_dict["hidden_size"],
+        intermediate_size=cfg_dict["intermediate_size"],
+        num_layers=cfg_dict["num_hidden_layers"],
+        num_heads=cfg_dict["num_attention_heads"],
+        num_kv_heads=cfg_dict["num_key_value_heads"], max_seq_len=4096,
+        rope_theta=cfg_dict["rope_theta"], rms_eps=cfg_dict["rms_norm_eps"],
+        dtype=jnp.dtype(cfg_dict["torch_dtype"]),
+        num_passes=cfg_dict["total_ut_steps"], sandwich_norm=True)
+    params = jax.jit(lambda lo, hi: ref.init(lo, hi, cfg_dict))(
+        *seed_words(2 ** 31 + 29))
+    max_prompt = max(p for p, _ in mix)
+    max_new = max(n for _, n in mix)
+    engine = ServingEngine(
+        params, cfg, page_size=page_size, max_batch=max_batch, num_pages=None,
+        max_prompt_len=max_prompt, max_new_cap=max_new)
+    cache = engine.scheduler.cache
+    if cache.layers != cfg.num_passes * cfg.num_layers:
+        raise AssertionError(f"the cache has {cache.layers} layers, not "
+                             f"{cfg.num_passes} x {cfg.num_layers}")
+    say(f"  {cfg.num_layers} layers x {cfg.num_passes} passes: k_pages "
+        f"{tuple(cache.k_pages.shape)} {cache.k_pages.dtype}; page budget "
+        f"{engine.page_budget}")
+    rng = np.random.default_rng(0)
+    prompts = {}
+    for p_len, new in mix:
+        prompt = rng.integers(0, cfg.vocab_size, p_len, dtype=np.int32)
+        prompts[engine.submit(prompt, new)] = (prompt, new)
+    results = engine.run()      # raises if the decode step retraced
+
+    length = -(-(max_prompt + max_new) // 64) * 64
+
+    @jax.jit
+    def gaps(params, tokens, rows, served):
+        logits = ref.row_logits(params, tokens, rows, cfg_dict)
+        return ref.served_gaps(logits, served)
+
+    widest = 0.0
+    for rid, (prompt, new) in sorted(prompts.items()):
+        served = np.asarray(results[rid]["tokens"], np.int32)
+        if len(served) != new:
+            raise AssertionError(
+                f"request {rid}: {len(served)} tokens, asked for {new}")
+        seq = np.concatenate([prompt, served[:-1]])
+        rows = np.minimum(len(prompt) - 1 + np.arange(max_new), len(seq) - 1)
+        tokens = np.zeros(max_new, np.int32)
+        tokens[:new] = served
+        gap = float(np.asarray(gaps(
+            params, np.pad(seq, (0, length - len(seq))), rows,
+            tokens))[:new].max())
+        say(f"  request {rid} (prompt {len(prompt)}, {new} tokens): widest "
+            f"reference logit gap {gap:.4f} (limit {gap_limit})")
+        widest = max(widest, gap)
+    if not widest <= gap_limit:
+        raise AssertionError(
+            f"served tokens lie {widest:.4f} below the reference's best "
+            f"logit, over the limit {gap_limit}")
+    return {"widest_gap": widest}
+
+
 # --------------------------------------------------------------------- mesh
 
 
@@ -732,6 +823,7 @@ def main() -> int:
     run("kernels", phase_kernels)
     train = run("train", phase_train)
     run("serve", phase_serve)
+    run("looped", phase_serve_looped)
     if device["count"] >= 4:
         run("mesh", phase_mesh, train["first_loss"])
     else:

@@ -111,7 +111,9 @@ def test_every_request_has_its_spans_under_one_rid(served):
         assert admit.args["bucket"] == math.ceil(p / PAGE) * PAGE
         assert 0 <= admit.args["rows"] < 3
         assert mine["serving/write_prompt"].args == {
-            "pages": math.ceil(p / PAGE)}
+            "pages": math.ceil(p / PAGE), "cache_layers": 2}
+        assert (admit.args["layer_passes"], admit.args["cache_layers"]) \
+            == (2, 2)
     # the request that finished at its first token never decoded
     assert sum(s.args["rows"] for s in by_name(spans, "serving/decode")) \
         == sum(len(r.tokens) - 1 for r in engine.completed)

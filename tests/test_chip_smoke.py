@@ -59,6 +59,24 @@ def test_phase_serve_tiny(interpret):
     assert out["exact"] + out["near_ties"] == 5
 
 
+def test_phase_serve_looped_tiny(interpret, monkeypatch):
+    """Two layers run four times, float32: the engine's tokens are the plain
+    reference's to round-off; with a pass left out of the program they are
+    not."""
+    tiny = dict(chip_smoke.LOOPED, hidden_size=64, intermediate_size=128,
+                num_attention_heads=4, num_key_value_heads=4, head_dim=16,
+                vocab_size=256, torch_dtype="float32")
+    kw = dict(mix=((8, 8), (20, 6), (32, 4)), max_batch=2, page_size=8)
+    out = chip_smoke.phase_serve_looped(tiny, gap_limit=1e-3, **kw)
+    assert 0 <= out["widest_gap"] <= 1e-3
+    # the reference runs four passes, the program is told three
+    real = llama.LlamaConfig
+    monkeypatch.setattr(llama, "LlamaConfig", lambda **k: real(
+        **{**k, "num_passes": k["num_passes"] - 1}))
+    with pytest.raises(AssertionError, match="over the limit"):
+        chip_smoke.phase_serve_looped(tiny, gap_limit=1e-3, **kw)
+
+
 def test_phases_refuse_the_jnp_path():
     """Outside interpret mode on the CPU the kernels take their jnp path;
     the HLO check must catch that, not pass it."""
