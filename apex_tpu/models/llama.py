@@ -381,25 +381,32 @@ def scan_passes(x, params, cfg: LlamaConfig, layer_fn, shared,
     """Scan the residual stream through ``cfg.num_passes`` passes over the
     ``[L, ...]`` stack: ONE ``lax.scan`` of ``cfg.cache_layers`` steps.
 
-    ``layer_fn(h, shared_l, per_layer_i) -> (h, out_i)`` applies one layer.
-    ``shared`` is what every pass uses alike (a pytree with leading axis
-    ``L``: the weights, their scales); ``per_layer`` is what each pass of
-    each layer owns (leading axis ``cfg.cache_layers``, pass-major: K and
-    V). Step ``i`` is pass ``i // L`` of layer ``i % L``; every pass after
-    the first starts from the final norm of the one before, and the last
-    pass's output is left for the head's own norm. Returns ``(x, outs)``,
-    ``outs`` stacked over the steps. With one pass this is the plain scan
-    over ``(shared, per_layer)``: a model that is not looped compiles to
-    what it did before.
+    ``layer_fn(carry, shared_l, per_layer_i) -> (carry, out_i)`` applies one
+    layer. ``x`` is the carry: the residual stream, or a tuple whose first
+    member is the stream and whose others are state that every step may
+    read and update where it lies (the serving decode step's page buffers:
+    a scan's carry is updated in place, its ``xs`` and ``ys`` are separate
+    arrays). ``shared`` is what every pass uses alike (a pytree with leading
+    axis ``L``: the weights, their scales); ``per_layer`` is what each pass
+    of each layer owns (leading axis ``cfg.cache_layers``, pass-major): K
+    and V where a step consumes or produces them whole (``generate``'s
+    contiguous cache), or just the steps' own indices into carried state.
+    Step ``i`` is pass ``i // L`` of layer ``i % L``; every pass after the
+    first starts from the final norm of the one before, applied to the
+    stream alone, and the last pass's output is left for the head's own
+    norm. Returns ``(carry, outs)``, ``outs`` stacked over the steps. With
+    one pass this is the plain scan over ``(shared, per_layer)``: a model
+    that is not looped compiles to what it did before.
     """
     L, T = cfg.num_layers, cfg.num_passes
     if T == 1:
         return jax.lax.scan(lambda h, xs: layer_fn(h, *xs), x,
                             (shared, per_layer))
 
-    def body(h, xs):
+    def body(carry, xs):
         i, mine = xs
         layer = i % L
+        h, *state = carry if isinstance(carry, tuple) else (carry,)
         with jax.named_scope("llama/pass"):
             # one select a step on [.., h]: a cond would cost the loop more
             h = jnp.where((layer == 0) & (i > 0),
@@ -408,7 +415,7 @@ def scan_passes(x, params, cfg: LlamaConfig, layer_fn, shared,
                 lambda w: jax.lax.dynamic_index_in_dim(w, layer,
                                                        keepdims=False),
                 shared)
-            return layer_fn(h, shared_l, mine)
+            return layer_fn((h, *state) if state else h, shared_l, mine)
 
     return jax.lax.scan(body, x, (jnp.arange(T * L), per_layer))
 

@@ -173,8 +173,8 @@ class PageAllocator:
 def _serving_write_pages(buf, idx, payload):
     """``buf[:, idx] = payload`` on the donated buffer, so in place: the
     un-jitted ``.at[:, idx].set`` allocated a second whole buffer for as
-    long as the write ran, a third copy beside the decode step's two once
-    the cache is most of the chip (a looped stack's is). ``payload`` is
+    long as the write ran, which does not fit once the cache is most of
+    the chip (a looped stack's is). ``payload`` is
     ``[L, n * page_size, nkv, d]`` (prefill's) or ``[L, n, page_size, nkv,
     d]`` (a dump's). One compile per number of pages, named apart from the
     decode step for the recompile listener."""

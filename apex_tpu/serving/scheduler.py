@@ -10,7 +10,10 @@ The serving loop has exactly two compiled shapes:
   packed ``[max_batch]`` slot arrays and the donated page buffers. The
   batch composition (which requests occupy which slots, who is active)
   is data — block tables, positions and an active mask — never shape,
-  so steady-state decode retraces exactly zero times.
+  so steady-state decode retraces exactly zero times. The page buffers
+  ride the layer scan's carry and are written in place: the program
+  holds the cache once and moves only the rows it writes and the pages
+  the tables name.
 
 Every decode op is per-slot independent (row-wise gemms, per-row
 attention over the row's own block table, per-row argmax), which is
@@ -20,8 +23,10 @@ pins down.
 
 A looped stack (``cfg.num_passes > 1``) makes both shapes' layer scan
 ``cfg.cache_layers`` steps long (``llama.scan_passes``): step ``i`` uses
-the weights of layer ``i % L`` and its own slice of the ``[T*L, ...]``
-page buffers. The programs, the cache and the dump all count cache layers.
+the weights of layer ``i % L`` and cache layer ``i`` of the ``[T*L, ...]``
+page buffers (prefill returns that layer's K and V, decode reads and
+writes it where it lies). The programs, the cache and the dump all count
+cache layers.
 
 Admission is FCFS: a request enters when a slot is free AND its whole
 page worst case (padded prompt + max_new_tokens) can be allocated, so
@@ -96,7 +101,8 @@ def pages_per_request(prompt_len: int, max_new_tokens: int,
 
 def fp8_weight_scales(params) -> Dict[str, jax.Array]:
     """Static per-layer weight scales (E4M3 amax scaling) for every
-    dense layer kernel, stacked ``[L]`` to ride the decode scan's xs.
+    dense layer kernel, stacked ``[L]`` to go beside the weights as the
+    layer scan's ``shared`` (``llama.scan_passes``).
     Serving weights are frozen, so one amax pass at engine build
     replaces the training path's delayed-scaling ring."""
     out = {}
@@ -144,6 +150,14 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     their k/v to the trash page and pass their token through, so the
     step is total over any batch composition with zero control flow.
     Greedy (argmax) by design — the bit-reproducibility contract.
+
+    Arguments 2 and 3 are the whole cache and come back updated. The
+    layer scan carries them beside the residual stream and scans over
+    the cache layers' indices: step ``i`` scatters the batch's new rows
+    into cache layer ``i`` and gathers the tables' pages from it, in the
+    carried buffer itself. Donated, the outputs are the inputs' memory;
+    nothing K- or V-shaped is the scan's ``xs`` or ``ys``, which would
+    be a second copy of the cache written whole every step.
     """
     if cfg.moe:
         raise NotImplementedError(
@@ -181,20 +195,28 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     def _decode_step(params, scales, k_pages, v_pages, tokens, tables,
                      pos, active):
         x = _llama.embed(params, tokens[:, None], cfg, tp_axis=None)
-        trash = k_pages.shape[1] - 1
+        shape = k_pages.shape           # [CL, P + 1, page, nkv, d]
+        stride, trash = shape[1], shape[1] - 1
         page_idx = jnp.take_along_axis(
             tables, (pos // page_size)[:, None], axis=1)[:, 0]
         page_idx = jnp.where(active, page_idx, trash)
         off = pos % page_size
 
-        def body(h, shared, pages):
-            h, kp, vp = _layer(h, *shared, *pages, tables, pos,
-                               page_idx, off)
-            return h, (kp, vp)
+        # the buffers ride the carry as [CL * (P + 1), page, nkv, d] (a
+        # bitcast): step i touches rows i * (P + 1) + page of them, so no
+        # layer's slab is ever cut out or put back as a value
+        flat = (shape[0] * stride,) + shape[2:]
 
-        x, (k_pages, v_pages) = _llama.scan_passes(
-            x, params, cfg, body, (params["layers"], scales),
-            (k_pages, v_pages))
+        def body(carry, shared, i):
+            h, kp, vp = carry
+            at = i * stride
+            return _layer(h, *shared, kp, vp, at + tables, pos,
+                          at + page_idx, off), None
+
+        (x, k_pages, v_pages), _ = _llama.scan_passes(
+            (x, k_pages.reshape(flat), v_pages.reshape(flat)), params, cfg,
+            body, (params["layers"], scales), jnp.arange(shape[0]))
+        k_pages, v_pages = k_pages.reshape(shape), v_pages.reshape(shape)
         logits = _gen._logits(params, x, cfg)[:, 0]
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return jnp.where(active, nxt, tokens), k_pages, v_pages

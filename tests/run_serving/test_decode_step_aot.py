@@ -1,0 +1,164 @@
+"""The shape of the compiled decode step (ISSUE 30), and that only it changed.
+
+The step is compiled for a v5e that is described and not attached, from
+shapes, at the engine keys of the two backlog cells: the page buffers ride
+the layer scan's carry and are updated in place, so the program holds them
+once. A scan that took them as `xs` and gave them back as `ys` held a second
+copy of both (2.5 and 3.84 GiB of temporaries) and copied them whole every
+step. The topology is described inside a fixture, never at import. Two other
+files load the TPU's compiler (`tests/perfbench/test_perfbench_aot.py`,
+`tests/run_pallas/test_softmax_bwd_aot.py`): without the driver's
+ALLOW_MULTIPLE_LIBTPU_LOAD the files that land on later workers skip.
+
+The rest pins what shares `llama.scan_passes` with the step and must not have
+moved: prefill of one bucket against `models/generate`, and the gradient of
+`llama.loss_fn` against the float32 references' own, plain and looped.
+"""
+
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from apex_tpu.models import generate as gen
+from apex_tpu.models import llama
+from apex_tpu.serving import scheduler as sched
+from perfbench import harness
+from perfbench.references import llama_dense, ouro_looped
+from perfbench.references.common import seed_words
+from perfbench.runners import serve, serve_looped
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no libtpu here, or another process has it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def read(kind, name):
+    return harness.load_json(harness.ROOT, "perfbench", kind, name + ".json")
+
+
+@pytest.mark.parametrize("config,mix,runner", [
+    ("mistral7b_v03_d16", "longgen_backlog", serve),
+    ("ouro_2p6b", "reasoning_backlog", serve_looped)], ids=["plain", "looped"])
+def test_the_compiled_step_holds_the_page_buffers_once(one_chip, config, mix,
+                                                       runner):
+    cfg = runner.model_config(read("configs", config))
+    eng = read("traffic", mix)["engine"]
+    page, rows = eng["page_size"], eng["max_batch"]
+    table = sched.pages_per_request(eng["max_prompt_len"], eng["max_new_cap"],
+                                    page)
+    shape = (cfg.cache_layers, eng["num_pages"] + 1, page, cfg.num_kv_heads,
+             cfg.head_dim)
+
+    def struct(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: struct(a.shape, a.dtype),
+        jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0), cfg)))
+    pages = struct(shape, cfg.dtype)
+    step = jax.jit(sched.build_decode_step(cfg, page), donate_argnums=(2, 3))
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = step.lower(
+            params, {}, pages, pages, struct((rows,), jnp.int32),
+            struct((rows, table), jnp.int32), struct((rows,), jnp.int32),
+            struct((rows,), jnp.bool_)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    both = 2 * int(np.prod(shape)) * jnp.dtype(cfg.dtype).itemsize
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == both          # arguments 2 and 3
+    assert memory.temp_size_in_bytes < both / 4
+    text = compiled.as_text()
+    assert "jit__decode_step" in text
+    # as the cache has them, and as the scan carries them (layers x pages)
+    shapes = {",".join(map(str, s)) for s in (
+        shape, (shape[0] * shape[1],) + shape[2:])}
+    copies = re.findall(r"= bf16\[([\d,]+)\]\S* copy\(", text)
+    assert copies and not shapes & set(copies)
+
+
+TINY = {"hidden_size": 64, "intermediate_size": 128,
+        "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 16,
+        "num_hidden_layers": 2, "vocab_size": 256, "rms_norm_eps": 1e-6,
+        "rope_theta": 1000000, "tie_word_embeddings": False,
+        "torch_dtype": "float32"}
+PLAIN = llama.LlamaConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
+    num_heads=4, num_kv_heads=4, max_seq_len=256, rope_theta=1e6,
+    rms_eps=1e-6, dtype=jnp.float32)
+STACKS = {
+    "plain": (PLAIN, llama_dense, TINY),
+    "looped": (dataclasses.replace(PLAIN, num_passes=4, sandwich_norm=True),
+               ouro_looped, {**TINY, "total_ut_steps": 4})}
+TIGHT = 2e-4          # float32 round-off through 8 layer passes, values O(1)
+
+
+@pytest.fixture(scope="module", params=sorted(STACKS))
+def stack(request):
+    cfg, ref, tiny = STACKS[request.param]
+    return cfg, ref, tiny, ref.init(*seed_words(2 ** 31 + 30), tiny)
+
+
+def test_prefill_of_one_bucket_is_generates(stack):
+    """The first token and K and V of every cache layer, from a prompt of 11
+    padded to the bucket of 16, are those of `generate`'s own prefill."""
+    cfg, ref, tiny, params = stack
+    prompt = jax.random.randint(jax.random.PRNGKey(4), (1, 11), 0, 256)
+    padded = jnp.zeros((1, 16), jnp.int32).at[:, :11].set(prompt)
+    first, ks, vs = sched.build_prefill(cfg, 16)(params, {}, padded,
+                                                 np.int32(11))
+    assert ks.shape == vs.shape == (cfg.cache_layers, 16, 4, 16)
+    logits = ref.row_logits(params, prompt[0], jnp.asarray([10]), tiny)
+    assert float(ref.served_gaps(logits, first)[0]) < TIGHT
+    assert int(first[0]) == int(gen.generate(params, prompt, cfg, 1)[0, -1])
+    positions = jnp.arange(11)[None]
+
+    def layer(h, lp, _):
+        h, k, v = gen._prefill_layer(h, lp, cfg, positions)
+        return h, (k, v)
+
+    _, (want_k, want_v) = llama.scan_passes(
+        llama.embed(params, prompt, cfg, tp_axis=None), params, cfg, layer,
+        params["layers"])
+    np.testing.assert_allclose(ks[:, :11], want_k[:, 0], atol=TIGHT)
+    np.testing.assert_allclose(vs[:, :11], want_v[:, 0], atol=TIGHT)
+    # the passes of one layer do not share their K and V
+    if cfg.num_passes > 1:
+        assert float(jnp.max(jnp.abs(ks[0] - ks[cfg.num_layers]))) > 0.01
+
+
+def test_the_gradient_of_the_loss_is_the_references(stack):
+    cfg, ref, tiny, params = stack
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 24), 0, 256)
+    batch = (tokens[:, :-1], tokens[:, 1:])
+
+    def ref_loss(p):
+        return jnp.mean(jnp.stack([
+            -jax.nn.log_softmax(ref.row_logits(
+                p, batch[0][r], jnp.arange(23), tiny))[jnp.arange(23),
+                                                        batch[1][r]]
+            for r in range(2)]))
+
+    want, want_grads = jax.value_and_grad(ref_loss)(params)
+    got, got_grads = jax.value_and_grad(llama.loss_fn)(
+        params, batch, cfg, tp_axis=None, cp_axis=None)
+    assert float(got) == pytest.approx(float(want), abs=1e-4)
+    flat = jax.tree_util.tree_leaves_with_path(want_grads)
+    assert len(flat) == len(jax.tree_util.tree_leaves(got_grads))
+    for (path, w), g in zip(flat, jax.tree_util.tree_leaves(got_grads)):
+        scale = max(float(jnp.max(jnp.abs(w))), 1e-3)
+        assert float(jnp.max(jnp.abs(g - w))) < TIGHT * scale, path
+    assert float(jnp.max(jnp.abs(got_grads["layers"]["wd"]))) > 0

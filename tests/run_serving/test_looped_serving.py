@@ -88,24 +88,11 @@ def test_served_tokens_lie_within_the_limit_of_the_reference(params):
 
 def reuse_first_pass_cache(x, params, cfg, layer_fn, shared, per_layer=None):
     """`llama.scan_passes` with the fault planted: every pass of layer l
-    reads and writes cache layer l, the first pass's (prefill untouched)."""
-    if per_layer is None:
-        return TRUE_SCAN_PASSES(x, params, cfg, layer_fn, shared)
-    L, T = cfg.num_layers, cfg.num_passes
-
-    def body(carry, i):
-        h, cache = carry
-        layer = i % L
-        h = jnp.where((layer == 0) & (i > 0), llama._rmsnorm(
-            h, params["final_norm"], cfg.rms_eps), h)
-        pick = lambda tree: jax.tree_util.tree_map(lambda a: a[layer], tree)
-        h, out = layer_fn(h, pick(shared), pick(cache))
-        cache = jax.tree_util.tree_map(lambda a, o: a.at[layer].set(o),
-                                       cache, out)
-        return (h, cache), None
-
-    (x, cache), _ = jax.lax.scan(body, (x, per_layer), jnp.arange(T * L))
-    return x, cache
+    reads and writes cache layer l, the first pass's (prefill untouched).
+    The decode step's `per_layer` is each step's cache layer."""
+    if per_layer is not None:
+        per_layer = per_layer % cfg.num_layers
+    return TRUE_SCAN_PASSES(x, params, cfg, layer_fn, shared, per_layer)
 
 
 TRUE_SCAN_PASSES = llama.scan_passes
