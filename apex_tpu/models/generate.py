@@ -21,33 +21,16 @@ backend; sharded serving is out of scope (single-host batch decode).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from apex_tpu.models import llama as _llama
-from apex_tpu.transformer.functional.rope import apply_rotary_qk
+from apex_tpu.ops.flash_attention import flash_attention
 
 __all__ = ["greedy_generate", "generate", "gpt2_generate"]
-
-
-def _split_heads(x, n, d):
-    b, s, _ = x.shape
-    return x.reshape(b, s, n, d)
-
-
-def _layer_qkv(x, lp, cfg, positions):
-    """Projections + rope for one (unstacked) layer on [b, s, h]."""
-    d = cfg.head_dim
-    q = _split_heads(jnp.matmul(x, lp["wq"].astype(x.dtype)),
-                     cfg.num_heads, d)
-    k = _split_heads(jnp.matmul(x, lp["wk"].astype(x.dtype)),
-                     cfg.num_kv_heads, d)
-    v = _split_heads(jnp.matmul(x, lp["wv"].astype(x.dtype)),
-                     cfg.num_kv_heads, d)
-    q, k = apply_rotary_qk(q, k, positions=positions, base=cfg.rope_theta)
-    return q, k, v
 
 
 def _decode_attention(q, k_cache, v_cache, pos):
@@ -129,47 +112,32 @@ def _moe_prefill_ffn(hm, lp, cfg):
     return out.reshape(b, s, h)
 
 
-def _dense_ffn(hm, lp, dtype):
-    g = jnp.matmul(hm, lp["wg"].astype(dtype))
-    u = jnp.matmul(hm, lp["wu"].astype(dtype))
-    return jnp.matmul(jax.nn.silu(g) * u, lp["wd"].astype(dtype))
+def _routed(ffn, cfg):
+    """``llama.block``'s ``ffn`` here: the routed form ``ffn`` for an MoE
+    config, the block's own dense SwiGLU otherwise."""
+    return functools.partial(ffn, cfg=cfg) if cfg.moe else None
 
 
-def _decode_layer(x, lp, cfg, k_cache, v_cache, pos):
-    """One decode step through one layer; returns (x, new_k, new_v)."""
-    h = _llama._rmsnorm(x, lp["attn_norm"], cfg.rms_eps)
-    q, k, v = _layer_qkv(h, lp, cfg,
-                         positions=jnp.full((x.shape[0], 1), pos,
-                                            jnp.int32))
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        k_cache, k.astype(k_cache.dtype), pos, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        v_cache, v.astype(v_cache.dtype), pos, axis=1)
-    o = _decode_attention(q, k_cache, v_cache, pos).astype(x.dtype)
-    x = x + _llama.post_norm(jnp.matmul(o, lp["wo"].astype(x.dtype)), lp,
-                             "attn_post_norm", cfg)
-    hm = _llama._rmsnorm(x, lp["mlp_norm"], cfg.rms_eps)
-    y = (_moe_decode_ffn(hm, lp, cfg) if cfg.moe
-         else _dense_ffn(hm, lp, x.dtype))
-    return (x + _llama.post_norm(y, lp, "mlp_post_norm", cfg), k_cache,
-            v_cache)
+def _flash_keeping_kv(q, k, v):
+    """The prefills' ``attend`` (``llama.block``): causal flash attention
+    over the whole prompt, the rotated K and V kept for the cache."""
+    o = flash_attention(q, k, v, causal=True, scale=q.shape[-1] ** -0.5)
+    return o, (k, v)
 
 
-def _prefill_layer(x, lp, cfg, positions):
-    """Full-sequence layer pass that also returns rotated k / v."""
-    from apex_tpu.ops.flash_attention import flash_attention
+def _attend_cache_at(pos, k_cache, v_cache):
+    """The decode loop's ``attend``: one position's K and V written into
+    the contiguous ``[b, max_len, nkv, d]`` cache at ``pos``, the query
+    attending to everything up to it; the updated cache is kept."""
 
-    h = _llama._rmsnorm(x, lp["attn_norm"], cfg.rms_eps)
-    q, k, v = _layer_qkv(h, lp, cfg, positions)
-    o = flash_attention(q, k, v, causal=True, scale=cfg.head_dim ** -0.5)
-    b, s = x.shape[:2]
-    x = x + _llama.post_norm(
-        jnp.matmul(o.reshape(b, s, -1), lp["wo"].astype(x.dtype)), lp,
-        "attn_post_norm", cfg)
-    hm = _llama._rmsnorm(x, lp["mlp_norm"], cfg.rms_eps)
-    y = (_moe_prefill_ffn(hm, lp, cfg) if cfg.moe
-         else _dense_ffn(hm, lp, x.dtype))
-    return x + _llama.post_norm(y, lp, "mlp_post_norm", cfg), k, v
+    def attend(q, k, v):
+        kc = jax.lax.dynamic_update_slice_in_dim(
+            k_cache, k.astype(k_cache.dtype), pos, axis=1)
+        vc = jax.lax.dynamic_update_slice_in_dim(
+            v_cache, v.astype(v_cache.dtype), pos, axis=1)
+        return _decode_attention(q, kc, vc, pos).astype(q.dtype), (kc, vc)
+
+    return attend
 
 
 def _logits(params, x, cfg):
@@ -233,8 +201,8 @@ def generate(params, prompt_tokens, cfg, max_new_tokens: int,
     x = _llama.embed(params, prompt_tokens, cfg, tp_axis=None)
 
     def pre_body(h, lp, _):
-        h, k, v = _prefill_layer(h, lp, cfg, positions)
-        return h, (k, v)
+        return _llama.block(h, lp, cfg, positions, _flash_keeping_kv,
+                            ffn=_routed(_moe_prefill_ffn, cfg))
 
     x, (ks, vs) = _llama.scan_passes(x, params, cfg, pre_body,
                                      params["layers"])
@@ -245,8 +213,9 @@ def generate(params, prompt_tokens, cfg, max_new_tokens: int,
 
     def decode_stack(x, caches, pos):
         def layer(h, lp, cache):
-            h, k1, v1 = _decode_layer(h, lp, cfg, *cache, pos)
-            return h, (k1, v1)
+            return _llama.block(h, lp, cfg, jnp.full((b, 1), pos, jnp.int32),
+                                _attend_cache_at(pos, *cache),
+                                ffn=_routed(_moe_decode_ffn, cfg))
 
         return _llama.scan_passes(x, params, cfg, layer, params["layers"],
                                   caches)

@@ -26,6 +26,11 @@ under tp-only shard_map, and as one pipeline stage:
         loss is returned by :func:`loss_fn`; the pipeline ``stage_fn``
         path drops it (documented — activations are the only pp payload).
 
+The layer itself is written once, in :func:`block`. Training
+(:func:`decoder_layer`), ``models/generate.py`` and the two serving programs
+(``serving/scheduler.py``) call it and pass in what differs between them:
+how a weight multiplies, where K and V live, and which FFN runs.
+
 Looped stacks (Ouro): ``num_passes > 1`` applies the SAME [L, ...] stack
 that many times (:func:`scan_passes`), the final norm between passes, and
 ``sandwich_norm`` norms each sub-layer's output as well as its input.
@@ -112,9 +117,9 @@ def llama3_8b(**over) -> LlamaConfig:
 
 
 def flagship_0p9b(**over) -> LlamaConfig:
-    """The single-chip benchmark config (bench.py's Llama MFU model and
-    tools/tpu_profile.py's traced model — one definition so the profile
-    always explains the bench number)."""
+    """A 0.9B llama that fits one chip with room for a cache: the model
+    ``chip_smoke.py``'s serve check drives through ``ServingEngine`` against
+    ``generate`` (``bench.py`` and ``tools/tpu_profile.py`` use it too)."""
     kw = dict(vocab_size=32000, hidden_size=2048, intermediate_size=5632,
               num_layers=8, num_heads=16, num_kv_heads=8, max_seq_len=2048,
               dtype=jnp.bfloat16)
@@ -199,58 +204,45 @@ def post_norm(y, lp, name, cfg: LlamaConfig):
     return _rmsnorm(y, lp[name], cfg.rms_eps) if cfg.sandwich_norm else y
 
 
-def _attention(x, lp, cfg: LlamaConfig, positions, tp_axis, cp_axis,
-               sequence_parallel):
-    """GQA attention on [b, s_local, h]; q/k/v heads tp-sharded, sequence
-    cp-sharded (ring attention when 'cp' is bound)."""
-    b = x.shape[0]
+def products(x, lp, *names):
+    """``x`` times each named weight of the layer, in ``x``'s dtype: the
+    plain form of :func:`block`'s product hook."""
+    return (jnp.matmul(x, lp[n].astype(x.dtype)) for n in names)
+
+
+def block(x, lp, cfg: LlamaConfig, positions, attend, mm=products, ffn=None):
+    """The Llama decoder block on one layer's (unstacked) weights ``lp``:
+    the one place the sub-layer sequence is written. Training, ``generate``
+    and both serving programs call it and pass in what differs:
+
+    - ``attend(q, k, v) -> (o, kept)``: attention on the rotated heads
+      (``q`` ``[b, s, nq, d]``, ``k`` and ``v`` ``[b, s, nkv, d]``) and where
+      K and V live: nowhere (training), returned whole (the prefills), put
+      into a cache that is then attended to (the decode steps). ``kept`` is
+      handed back beside the stream, untouched.
+    - ``mm(x, lp, *names)``: ``x`` times each named weight, an iterable of
+      one product a name. The names of one call share their input, so a
+      hook that has to gather it (sequence parallelism) does so once a
+      half-block.
+    - ``ffn(h, lp)``: the feed-forward on the normed stream; without one,
+      the dense SwiGLU through ``mm``.
+
+    Returns ``(x, kept)``."""
     d = cfg.head_dim
-    tp = jax.lax.axis_size(tp_axis) if _axis_bound(tp_axis) else 1
-    if cfg.num_heads % tp or cfg.num_kv_heads % tp:
-        raise ValueError(
-            f"tp={tp} must divide num_heads={cfg.num_heads} and "
-            f"num_kv_heads={cfg.num_kv_heads}")
-    nq, nkv = cfg.num_heads // tp, cfg.num_kv_heads // tp
-
-    # x arrives sequence-FULL (decoder_layer gathers once in sp mode), so
-    # the qkv projections never re-gather.
-    q = column_parallel_linear(x, lp["wq"], gather_output=False,
-                               axis_name=tp_axis)
-    k = column_parallel_linear(x, lp["wk"], gather_output=False,
-                               axis_name=tp_axis)
-    v = column_parallel_linear(x, lp["wv"], gather_output=False,
-                               axis_name=tp_axis)
-    s_full = q.shape[1]
-    q = q.reshape(b, s_full, nq, d)
-    k = k.reshape(b, s_full, nkv, d)
-    v = v.reshape(b, s_full, nkv, d)
-
+    h = _rmsnorm(x, lp["attn_norm"], cfg.rms_eps)
+    q, k, v = (y.reshape(*y.shape[:2], -1, d)
+               for y in mm(h, lp, "wq", "wk", "wv"))
     q, k = apply_rotary_qk(q, k, positions=positions, base=cfg.rope_theta)
-
-    if _axis_bound(cp_axis):
-        # ring_attention is GQA-aware: k/v circulate at nkv heads
-        o = ring_attention(q, k, v, axis_name=cp_axis, causal=True)
+    o, kept = attend(q, k, v)
+    (y,) = mm(o.reshape(*o.shape[:2], -1), lp, "wo")
+    x = x + post_norm(y, lp, "attn_post_norm", cfg)
+    h = _rmsnorm(x, lp["mlp_norm"], cfg.rms_eps)
+    if ffn is None:
+        g, u = mm(h, lp, "wg", "wu")
+        (y,) = mm(jax.nn.silu(g) * u, lp, "wd")
     else:
-        # GQA-aware flash attention: online softmax, no [s, s] matrix in
-        # HBM fwd or bwd (jnp fallback off-TPU is the same math)
-        o = flash_attention(q, k, v, causal=True, scale=d ** -0.5)
-
-    o = o.reshape(b, s_full, nq * d)
-    return row_parallel_linear(o, lp["wo"], input_is_parallel=True,
-                               sequence_parallel_enabled=sequence_parallel,
-                               axis_name=tp_axis, seq_dim=1)
-
-
-def _mlp(x, lp, tp_axis, sequence_parallel):
-    # x arrives sequence-full (see decoder_layer); no per-gemm gather.
-    g = column_parallel_linear(x, lp["wg"], gather_output=False,
-                               axis_name=tp_axis)
-    u = column_parallel_linear(x, lp["wu"], gather_output=False,
-                               axis_name=tp_axis)
-    return row_parallel_linear(jax.nn.silu(g) * u, lp["wd"],
-                               input_is_parallel=True,
-                               sequence_parallel_enabled=sequence_parallel,
-                               axis_name=tp_axis, seq_dim=1)
+        y = ffn(h, lp)
+    return x + post_norm(y, lp, "mlp_post_norm", cfg), kept
 
 
 def _moe_cfg(cfg: LlamaConfig):
@@ -293,31 +285,58 @@ def decoder_layer(x, lp, cfg: LlamaConfig, positions,
                   cp_axis: Optional[str] = "cp",
                   sequence_parallel: bool = False,
                   ep_axis: Optional[str] = "ep"):
-    """One pre-norm block on a single layer's (unstacked) params ``lp``.
-    Returns ``(x, aux)`` — aux is the MoE load-balancing loss (0 dense).
+    """:func:`block` for training, on [b, s_local, h]: q/k/v heads and the
+    FFN's width tp-sharded, the sequence cp-sharded (ring attention when
+    'cp' is bound), nothing kept. Returns ``(x, aux)`` — aux is the MoE
+    load-balancing loss (0 dense).
 
     In sp mode the residual stream (and the norms) stay sequence-sharded;
     each half-block all-gathers the normed input ONCE for its column gemms
     and reduce-scatters the row-gemm output (Megatron sequence-parallel
     comm pattern: 2 gathers + 2 scatters per layer, not one per gemm).
     """
+    tp = jax.lax.axis_size(tp_axis) if _axis_bound(tp_axis) else 1
+    if cfg.num_heads % tp or cfg.num_kv_heads % tp:
+        raise ValueError(
+            f"tp={tp} must divide num_heads={cfg.num_heads} and "
+            f"num_kv_heads={cfg.num_kv_heads}")
 
     def to_full(h):
         if sequence_parallel:
             return gather_from_sequence_parallel_region(h, tp_axis, seq_dim=1)
         return h
 
-    h = to_full(_rmsnorm(x, lp["attn_norm"], cfg.rms_eps))
-    x = x + post_norm(_attention(h, lp, cfg, positions, tp_axis, cp_axis,
-                                 sequence_parallel),
-                      lp, "attn_post_norm", cfg)
-    h = to_full(_rmsnorm(x, lp["mlp_norm"], cfg.rms_eps))
-    if cfg.moe:
-        y, aux = _moe_mlp(h, lp, cfg, ep_axis, tp_axis, sequence_parallel)
-    else:
-        y, aux = _mlp(h, lp, tp_axis, sequence_parallel), jnp.zeros(
-            (), jnp.float32)
-    return x + post_norm(y, lp, "mlp_post_norm", cfg), aux
+    def mm(h, lp, *names):
+        if names[0] in ("wo", "wd"):     # row kernels: the input is sharded
+            return [row_parallel_linear(
+                h, lp[n], input_is_parallel=True,
+                sequence_parallel_enabled=sequence_parallel,
+                axis_name=tp_axis, seq_dim=1) for n in names]
+        h = to_full(h)
+        return [column_parallel_linear(h, lp[n], gather_output=False,
+                                       axis_name=tp_axis) for n in names]
+
+    def attend(q, k, v):
+        if _axis_bound(cp_axis):
+            # ring_attention is GQA-aware: k/v circulate at nkv heads
+            return ring_attention(q, k, v, axis_name=cp_axis,
+                                  causal=True), None
+        # GQA-aware flash attention: online softmax, no [s, s] matrix in
+        # HBM fwd or bwd (jnp fallback off-TPU is the same math)
+        return flash_attention(q, k, v, causal=True,
+                               scale=cfg.head_dim ** -0.5), None
+
+    aux = jnp.zeros((), jnp.float32)
+
+    def routed(h, lp):
+        nonlocal aux
+        y, aux = _moe_mlp(to_full(h), lp, cfg, ep_axis, tp_axis,
+                          sequence_parallel)
+        return y
+
+    x, _ = block(x, lp, cfg, positions, attend, mm,
+                 routed if cfg.moe else None)
+    return x, aux
 
 
 def _positions(b, s_local, cp_axis):

@@ -50,7 +50,6 @@ from apex_tpu.models import generate as _gen
 from apex_tpu.models import llama as _llama
 from apex_tpu.observability import get_tracer, host_span
 from apex_tpu.serving.kv_cache import PagedKVCache
-from apex_tpu.transformer.functional.rope import apply_rotary_qk
 
 __all__ = [
     "ContinuousBatchScheduler",
@@ -101,8 +100,8 @@ def pages_per_request(prompt_len: int, max_new_tokens: int,
 
 def fp8_weight_scales(params) -> Dict[str, jax.Array]:
     """Static per-layer weight scales (E4M3 amax scaling) for every
-    dense layer kernel, stacked ``[L]`` to go beside the weights as the
-    layer scan's ``shared`` (``llama.scan_passes``).
+    dense layer kernel, stacked ``[L]`` to go among the weights, as
+    ``lp["scales"]``, in the layer scan's ``shared`` (``llama.scan_passes``).
     Serving weights are frozen, so one amax pass at engine build
     replaces the training path's delayed-scaling ring."""
     out = {}
@@ -113,30 +112,29 @@ def fp8_weight_scales(params) -> Dict[str, jax.Array]:
     return out
 
 
-def _make_mm(weight_mode: str):
-    """The bf16-or-fp8 routing hook: every layer gemm goes through
-    here. ``native`` is a plain matmul in the activation dtype (the
-    exact op generate.py uses, so tokens match the reference decoder);
-    ``fp8`` routes through :func:`~apex_tpu.ops.precision.matmul_fp8`
-    with the static weight scales."""
-    if weight_mode == "fp8":
-        from apex_tpu.ops.precision import matmul_fp8
-
-        def mm(x, w, scale):
-            return matmul_fp8(x, w, jnp.float32(1.0),
-                              scale).astype(x.dtype)
-    else:
-        def mm(x, w, scale):
-            del scale
-            return jnp.matmul(x, w.astype(x.dtype))
-    return mm
-
-
 def _normalize_weight_mode(weight_mode: str) -> str:
     if weight_mode not in WEIGHT_MODES:
         raise ValueError(f"weight_mode must be one of {WEIGHT_MODES}, "
                          f"got {weight_mode!r}")
     return "fp8" if weight_mode == "fp8" else "native"
+
+
+def _products(weight_mode: str):
+    """``llama.block``'s product hook for a weight mode: every layer gemm
+    goes through it. ``native`` is the block's own plain matmul in the
+    activation dtype (the exact op generate.py uses, so tokens match the
+    reference decoder); ``fp8`` routes through
+    :func:`~apex_tpu.ops.precision.matmul_fp8` with the static weight
+    scales of :func:`fp8_weight_scales` at ``lp["scales"]``."""
+    if _normalize_weight_mode(weight_mode) == "native":
+        return _llama.products
+    from apex_tpu.ops.precision import matmul_fp8
+
+    def products(x, lp, *names):
+        return (matmul_fp8(x, lp[n], jnp.float32(1.0),
+                           lp["scales"][n]).astype(x.dtype) for n in names)
+
+    return products
 
 
 def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
@@ -157,40 +155,15 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     into cache layer ``i`` and gathers the tables' pages from it, in the
     carried buffer itself. Donated, the outputs are the inputs' memory;
     nothing K- or V-shaped is the scan's ``xs`` or ``ys``, which would
-    be a second copy of the cache written whole every step.
+    be a second copy of the cache written whole every step. The layer
+    is ``llama.block``; what this step gives it is ``attend``, the
+    scatter, the gather and the attention over what was gathered.
     """
     if cfg.moe:
         raise NotImplementedError(
             "serving decode is dense-only; MoE routing needs a paged "
             "expert-gather step (llama dense configs only for now)")
-    mode = _normalize_weight_mode(weight_mode)
-    mm = _make_mm(mode)
-    d = cfg.head_dim
-    post = _llama.post_norm
-
-    def _layer(x, lp, sc, kp, vp, tables, pos, page_idx, off):
-        b = x.shape[0]
-        h = _llama._rmsnorm(x, lp["attn_norm"], cfg.rms_eps)
-        q = mm(h, lp["wq"], sc.get("wq")).reshape(b, 1, cfg.num_heads, d)
-        k = mm(h, lp["wk"], sc.get("wk")).reshape(
-            b, 1, cfg.num_kv_heads, d)
-        v = mm(h, lp["wv"], sc.get("wv")).reshape(
-            b, 1, cfg.num_kv_heads, d)
-        q, k = apply_rotary_qk(q, k, positions=pos[:, None],
-                               base=cfg.rope_theta)
-        kp = kp.at[page_idx, off].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[page_idx, off].set(v[:, 0].astype(vp.dtype))
-        kg = kp[tables].reshape(b, -1, cfg.num_kv_heads, d)
-        vg = vp[tables].reshape(b, -1, cfg.num_kv_heads, d)
-        o = _gen._decode_attention(q, kg, vg,
-                                   pos[:, None, None]).astype(x.dtype)
-        x = x + post(mm(o, lp["wo"], sc.get("wo")), lp, "attn_post_norm",
-                     cfg)
-        hm = _llama._rmsnorm(x, lp["mlp_norm"], cfg.rms_eps)
-        g = mm(hm, lp["wg"], sc.get("wg"))
-        u = mm(hm, lp["wu"], sc.get("wu"))
-        y = mm(jax.nn.silu(g) * u, lp["wd"], sc.get("wd"))
-        return x + post(y, lp, "mlp_post_norm", cfg), kp, vp
+    mm = _products(weight_mode)
 
     def _decode_step(params, scales, k_pages, v_pages, tokens, tables,
                      pos, active):
@@ -207,15 +180,26 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
         # layer's slab is ever cut out or put back as a value
         flat = (shape[0] * stride,) + shape[2:]
 
-        def body(carry, shared, i):
+        def body(carry, lp, i):
             h, kp, vp = carry
             at = i * stride
-            return _layer(h, *shared, kp, vp, at + tables, pos,
-                          at + page_idx, off), None
+            pages, rows = at + tables, at + page_idx
+
+            def attend(q, k, v):
+                kp1 = kp.at[rows, off].set(k[:, 0].astype(kp.dtype))
+                vp1 = vp.at[rows, off].set(v[:, 0].astype(vp.dtype))
+                kg = kp1[pages].reshape(k.shape[0], -1, *k.shape[2:])
+                vg = vp1[pages].reshape(v.shape[0], -1, *v.shape[2:])
+                o = _gen._decode_attention(q, kg, vg, pos[:, None, None])
+                return o.astype(q.dtype), (kp1, vp1)
+
+            h, (kp, vp) = _llama.block(h, lp, cfg, pos[:, None], attend, mm)
+            return (h, kp, vp), None
 
         (x, k_pages, v_pages), _ = _llama.scan_passes(
             (x, k_pages.reshape(flat), v_pages.reshape(flat)), params, cfg,
-            body, (params["layers"], scales), jnp.arange(shape[0]))
+            body, {**params["layers"], "scales": scales},
+            jnp.arange(shape[0]))
         k_pages, v_pages = k_pages.reshape(shape), v_pages.reshape(shape)
         logits = _gen._logits(params, x, cfg)[:, 0]
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -238,40 +222,17 @@ def build_prefill(cfg, bucket_len: int, weight_mode: str = "native"):
     """
     if cfg.moe:
         raise NotImplementedError("serving prefill is dense-only")
-    mode = _normalize_weight_mode(weight_mode)
-    mm = _make_mm(mode)
-    d = cfg.head_dim
-    post = _llama.post_norm
+    mm = _products(weight_mode)
 
     def prefill(params, scales, prompt, true_len):
-        from apex_tpu.ops.flash_attention import flash_attention
-
         b, s = prompt.shape
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
         x = _llama.embed(params, prompt, cfg, tp_axis=None)
-
-        def body(h, shared, _):
-            lp, sc = shared
-            hh = _llama._rmsnorm(h, lp["attn_norm"], cfg.rms_eps)
-            q = mm(hh, lp["wq"], sc.get("wq")).reshape(
-                b, s, cfg.num_heads, d)
-            k = mm(hh, lp["wk"], sc.get("wk")).reshape(
-                b, s, cfg.num_kv_heads, d)
-            v = mm(hh, lp["wv"], sc.get("wv")).reshape(
-                b, s, cfg.num_kv_heads, d)
-            q, k = apply_rotary_qk(q, k, positions=positions,
-                                   base=cfg.rope_theta)
-            o = flash_attention(q, k, v, causal=True, scale=d ** -0.5)
-            h = h + post(mm(o.reshape(b, s, -1), lp["wo"], sc.get("wo")),
-                         lp, "attn_post_norm", cfg)
-            hm = _llama._rmsnorm(h, lp["mlp_norm"], cfg.rms_eps)
-            g = mm(hm, lp["wg"], sc.get("wg"))
-            u = mm(hm, lp["wu"], sc.get("wu"))
-            y = mm(jax.nn.silu(g) * u, lp["wd"], sc.get("wd"))
-            return h + post(y, lp, "mlp_post_norm", cfg), (k, v)
-
         x, (ks, vs) = _llama.scan_passes(
-            x, params, cfg, body, (params["layers"], scales))
+            x, params, cfg,
+            lambda h, lp, _: _llama.block(h, lp, cfg, positions,
+                                          _gen._flash_keeping_kv, mm),
+            {**params["layers"], "scales": scales})
         x_last = jax.lax.dynamic_slice_in_dim(x, true_len - 1, 1,
                                               axis=1)
         logits = _gen._logits(params, x_last, cfg)[:, 0]
@@ -395,10 +356,8 @@ class ContinuousBatchScheduler:
                     self.pages_needed(self.queue[0])):
                 break
             req = self.queue.popleft()
-            if self._admit(req):
-                admitted.append(req)
-            else:
-                admitted.append(req)
+            admitted.append(req)
+            if not self._admit(req):
                 finished.append(req)
         return admitted, finished
 

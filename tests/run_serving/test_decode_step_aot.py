@@ -126,12 +126,10 @@ def test_prefill_of_one_bucket_is_generates(stack):
     assert int(first[0]) == int(gen.generate(params, prompt, cfg, 1)[0, -1])
     positions = jnp.arange(11)[None]
 
-    def layer(h, lp, _):
-        h, k, v = gen._prefill_layer(h, lp, cfg, positions)
-        return h, (k, v)
-
     _, (want_k, want_v) = llama.scan_passes(
-        llama.embed(params, prompt, cfg, tp_axis=None), params, cfg, layer,
+        llama.embed(params, prompt, cfg, tp_axis=None), params, cfg,
+        lambda h, lp, _: llama.block(h, lp, cfg, positions,
+                                     gen._flash_keeping_kv),
         params["layers"])
     np.testing.assert_allclose(ks[:, :11], want_k[:, 0], atol=TIGHT)
     np.testing.assert_allclose(vs[:, :11], want_v[:, 0], atol=TIGHT)
