@@ -13,13 +13,16 @@ The serving loop has exactly two compiled shapes:
   so steady-state decode retraces exactly zero times. The page buffers
   ride the layer scan's carry and are written in place: the program
   holds the cache once and moves only the rows it writes and the pages
-  the tables name.
+  that hold a position some active row attends to (a list made once a
+  step, walked in chunks by a loop whose trip count follows it).
 
 Every decode op is per-slot independent (row-wise gemms, per-row
 attention over the row's own block table, per-row argmax), which is
 what makes a request's token stream bit-identical regardless of what
 else shares the batch — the property the preempt/resume chaos test
-pins down.
+pins down. The list of live pages keeps it: a page's softmax is made
+of the page and its row's query alone, and a row's pages are combined
+in the order of the row's own columns, wherever the list held them.
 
 A looped stack (``cfg.num_passes > 1``) makes both shapes' layer scan
 ``cfg.cache_layers`` steps long (``llama.scan_passes``): step ``i`` uses
@@ -62,6 +65,11 @@ __all__ = [
 
 _E4M3_MAX = 448.0
 WEIGHT_MODES = ("native", "bf16", "fp8")
+# Entries of the step's list of live pages that one trip of the decode
+# attention's loop gathers and attends over; smaller tables are one chunk.
+# From shapes, not from a caller.
+LIST_CHUNK = 64
+_MASKED = -1e30     # finite where a softmax has -inf: a row of no page gives 0
 
 
 @dataclasses.dataclass
@@ -137,6 +145,91 @@ def _products(weight_mode: str):
     return products
 
 
+def _chunks(pages_live, table_slots: int):
+    """How the decode step walks a list of ``pages_live`` entries out of
+    tables of ``table_slots``: the entries of one chunk, and the chunks that
+    hold a live entry (an int, or traced where ``pages_live`` is). The
+    step's loop and the count of what it reads both come from here."""
+    chunk = min(LIST_CHUNK, table_slots)
+    return chunk, -(-pages_live // chunk)
+
+
+def pages_read(pages_live: int, table_slots: int) -> int:
+    """Pages a decode step gathers in every cache layer when ``pages_live``
+    of its tables' ``table_slots`` entries hold a position some active row
+    attends to: the whole chunks of its list."""
+    chunk, n_chunks = _chunks(pages_live, table_slots)
+    return n_chunks * chunk
+
+
+def _live_page_list(tables, pos, active, page_size: int, trash: int):
+    """The decode step's list of live pages, made once a step from what the
+    step is given: the tables' entries that hold a position some active row
+    attends to (row ``r``'s first ``pos[r] // page_size + 1``), row-major,
+    ahead of all others, padded to whole chunks and cut into them (``[K,
+    C]``). Per entry of the list: its row, its slot in the tables (``row *
+    width + column``; ``tables.size``, one past them, for an entry past the
+    live ones: what is scattered there is dropped), its page (``trash`` past
+    the live ones) and which of its page's positions its row attends to
+    (``[K, C, page_size]``); and the number of chunks that hold a live
+    entry, a traced scalar."""
+    w = tables.shape[1]
+    live = (jnp.arange(w) < jnp.where(active, pos // page_size + 1,
+                                      0)[:, None]).reshape(-1)
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    chunk, n_chunks = _chunks(n_live, tables.size)
+    order = jnp.pad(jnp.argsort(~live, stable=True).astype(jnp.int32),
+                    (0, -live.size % chunk))
+    valid = jnp.arange(order.size) < n_live
+    row = jnp.where(valid, order // w, 0)
+    first = jnp.where(valid, order % w, 0) * page_size
+    slot = jnp.where(valid, order, tables.size)
+    page = jnp.where(valid, tables.reshape(-1)[order], trash)
+    keys = valid[:, None] & (
+        first[:, None] + jnp.arange(page_size) <= pos[row][:, None])
+    return tuple(a.reshape(-1, chunk, *a.shape[1:])
+                 for a in (row, slot, page, keys)) + (n_chunks,)
+
+
+def _attend_live_pages(q, kp, vp, at, live_pages, table_slots: int):
+    """Decode attention of ``q [b, 1, nq, d]`` over the list's pages, read
+    at ``at + page`` out of ``kp`` and ``vp`` (``[pages, page_size, nkv,
+    d]``), in a loop over the chunks that hold a live entry. A chunk's pages
+    are gathered and each is attended over by its own row's query alone, in
+    float32; the page's softmax (its max, its sum, its weighted values) goes
+    to the page's slot in tables shaped like the step's. A row's slots are
+    then combined once, in the order of its columns. So what a row gets is
+    made of its query, its pages and its position, whatever rows are beside
+    it and wherever the list holds its pages: the same bits in any batch. A
+    row with no live page (not active) comes out as zeros."""
+    row, slot, page, keys, n_chunks = live_pages
+    b, _, nq, d = q.shape
+    nkv = kp.shape[2]
+    qg = q.astype(jnp.float32).reshape(b, nkv, nq // nkv, d)
+
+    def chunk(c, parts):
+        r, ok = row[c], keys[c]
+        kc = kp[at + page[c]].astype(jnp.float32)       # [C, page, nkv, d]
+        vc = vp[at + page[c]].astype(jnp.float32)
+        s = jnp.einsum("ckrd,ctkd->ckrt", qg[r], kc) * (d ** -0.5)
+        s = jnp.where(ok[:, None, None], s, _MASKED)
+        m = jnp.max(s, -1)
+        p = jnp.exp(s - m[..., None])   # a masked position: exp(-1e30 - m) = 0
+        new = (m, jnp.sum(p, -1), jnp.einsum("ckrt,ctkd->ckrd", p, vc))
+        return tuple(a.at[slot[c]].set(x, mode="drop")
+                     for a, x in zip(parts, new))
+
+    zeros = jnp.zeros((table_slots,) + qg.shape[1:3], jnp.float32)
+    m, l, o = (a.reshape(b, -1, *a.shape[1:]) for a in jax.lax.fori_loop(
+        0, n_chunks, chunk,
+        (jnp.full_like(zeros, _MASKED), zeros, jnp.zeros(zeros.shape + (d,)))))
+    # a slot no page was written to weighs exp(-1e30 - max) = 0 and holds 0
+    weight = jnp.exp(m - jnp.max(m, axis=1, keepdims=True))
+    o = jnp.sum(o * weight[..., None], axis=1)
+    l = jnp.sum(l * weight, axis=1)
+    return (o / jnp.maximum(l, 1e-30)[..., None]).reshape(b, 1, nq * d)
+
+
 def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     """The ONE jit-compiled decode step (jit + donation is the
     caller's: ``jax.jit(step, donate_argnums=(2, 3))``).
@@ -146,18 +239,25 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     ``[max_batch]`` slot arrays; ``tables`` is ``[max_batch,
     max_pages]`` of page indices (trash-padded). Inactive slots write
     their k/v to the trash page and pass their token through, so the
-    step is total over any batch composition with zero control flow.
+    step is total over any batch composition: one program, whose only
+    data-dependent control is the trip count of the attention's loop.
     Greedy (argmax) by design — the bit-reproducibility contract.
 
     Arguments 2 and 3 are the whole cache and come back updated. The
     layer scan carries them beside the residual stream and scans over
     the cache layers' indices: step ``i`` scatters the batch's new rows
-    into cache layer ``i`` and gathers the tables' pages from it, in the
-    carried buffer itself. Donated, the outputs are the inputs' memory;
+    into cache layer ``i`` and gathers pages from it, in the carried
+    buffer itself. Donated, the outputs are the inputs' memory;
     nothing K- or V-shaped is the scan's ``xs`` or ``ys``, which would
     be a second copy of the cache written whole every step. The layer
     is ``llama.block``; what this step gives it is ``attend``, the
     scatter, the gather and the attention over what was gathered.
+
+    What is gathered follows what is live. The step lists, once, the
+    tables' entries that hold a position some active row attends to
+    (:func:`_live_page_list`), and every layer walks that list in chunks
+    of ``LIST_CHUNK`` pages (:func:`_attend_live_pages`): its reads and
+    its arithmetic are the live pages', whatever the tables could hold.
     """
     if cfg.moe:
         raise NotImplementedError(
@@ -174,6 +274,7 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
             tables, (pos // page_size)[:, None], axis=1)[:, 0]
         page_idx = jnp.where(active, page_idx, trash)
         off = pos % page_size
+        live_pages = _live_page_list(tables, pos, active, page_size, trash)
 
         # the buffers ride the carry as [CL * (P + 1), page, nkv, d] (a
         # bitcast): step i touches rows i * (P + 1) + page of them, so no
@@ -183,14 +284,13 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
         def body(carry, lp, i):
             h, kp, vp = carry
             at = i * stride
-            pages, rows = at + tables, at + page_idx
+            rows = at + page_idx
 
             def attend(q, k, v):
                 kp1 = kp.at[rows, off].set(k[:, 0].astype(kp.dtype))
                 vp1 = vp.at[rows, off].set(v[:, 0].astype(vp.dtype))
-                kg = kp1[pages].reshape(k.shape[0], -1, *k.shape[2:])
-                vg = vp1[pages].reshape(v.shape[0], -1, *v.shape[2:])
-                o = _gen._decode_attention(q, kg, vg, pos[:, None, None])
+                o = _attend_live_pages(q, kp1, vp1, at, live_pages,
+                                       tables.size)
                 return o.astype(q.dtype), (kp1, vp1)
 
             h, (kp, vp) = _llama.block(h, lp, cfg, pos[:, None], attend, mm)
@@ -428,11 +528,12 @@ class ContinuousBatchScheduler:
         """One packed decode step; returns requests finished by it."""
         if not self._active.any():
             return []
-        # pages_gathered: what ``kp[tables]`` touches in every cache
-        # layer, whatever the rows hold
+        # pages_gathered: what the step reads in every cache layer
+        live = self.pages_live()
         with host_span("serving/decode", rows=self.num_active(),
-                       pages_live=self.pages_live(),
-                       pages_gathered=self._tables.size, **self._depth):
+                       pages_live=live,
+                       pages_gathered=pages_read(live, self._tables.size),
+                       **self._depth):
             with host_span("serving/decode_upload"):
                 nxt, self.cache.k_pages, self.cache.v_pages = self._decode(
                     self.params, self._scales,

@@ -16,7 +16,7 @@ from apex_tpu.models import llama
 from apex_tpu.resilience.faults import FaultPlan
 from apex_tpu.resilience.loop import Preempted
 from apex_tpu.resilience.preemption import EXIT_PREEMPTED
-from apex_tpu.serving import ServingEngine
+from apex_tpu.serving import ServingEngine, scheduler
 from apex_tpu.serving.engine import (
     _PAGES_FILE,
     _STATE_FILE,
@@ -53,7 +53,13 @@ def _submit_all(engine, jobs):
         engine.submit(prompt, max_new)
 
 
-def test_preempt_drain_dump_resume_bit_identical(model, tmp_path):
+@pytest.mark.parametrize("chunk", [scheduler.LIST_CHUNK, 3])
+def test_preempt_drain_dump_resume_bit_identical(model, tmp_path, chunk,
+                                                 monkeypatch):
+    """Also with the decode step's list of live pages in chunks of 3 under
+    tables of 8 slots: resumed requests land in other rows, beside other
+    requests, with their pages in other chunks, and get the same tokens."""
+    monkeypatch.setattr(scheduler, "LIST_CHUNK", chunk)
     params, cfg = model
     jobs = _jobs(cfg)
 

@@ -1,8 +1,12 @@
 """The decode step's values (ISSUE 30): the step that carries the page buffers
-through its layer scan and updates them in place gives, bit for bit, the next
-tokens and both buffers of a plain Python loop over the cache layers written
-here (scatter, gather, attention, MLP), for a plain and a looped stack, with
-native and fp8 weights, and with a slot that is not active."""
+through its layer scan and updates them in place gives the next tokens and
+both buffers of a plain Python loop over the cache layers written here
+(scatter, gather, attention, MLP), for a plain and a looped stack, with native
+and fp8 weights, and with a slot that is not active. The tokens are the same;
+the buffers are the same to float32 round-off outside the trash page, since
+the step sums a row's softmax a page at a time (ISSUE 32) and the loop here
+keeps `generate._decode_attention` over the whole table. Of its input buffers
+the step changes the rows it writes and no other bit."""
 
 import dataclasses
 
@@ -85,7 +89,10 @@ def test_the_carried_cache_equals_the_plain_loop(stack, mode):
                jnp.asarray(TABLES), jnp.asarray(POS), jnp.asarray(ACTIVE))
     for name, g, w in zip(("tokens", "k_pages", "v_pages"), got, want):
         assert g.shape == w.shape and g.dtype == w.dtype, name
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    for name, g, w in zip(("k_pages", "v_pages"), got[1:], want[1:]):
+        np.testing.assert_allclose(np.asarray(g)[:, :PAGES], np.asarray(
+            w)[:, :PAGES], atol=1e-5, rtol=0, err_msg=name)
     assert int(got[0][2]) == TOKENS[2]           # passed through
     # what changed: one position a cache layer for each active row, and the
     # trash page, where the slot that is not active wrote

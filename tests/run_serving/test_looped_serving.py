@@ -175,7 +175,12 @@ def test_the_page_write_is_in_place_under_donation():
     assert jaxpr.jaxpr.eqns[0].params["donated_invars"][0] is True
 
 
-def test_dump_then_resume_is_bit_identical(params, tmp_path):
+@pytest.mark.parametrize("chunk", [sched_mod.LIST_CHUNK, 4])
+def test_dump_then_resume_is_bit_identical(params, tmp_path, chunk,
+                                           monkeypatch):
+    """Also with the decode step's list of live pages walked in chunks of 4
+    under tables of 15 slots, as the benchmark's tables are several chunks."""
+    monkeypatch.setattr(sched_mod, "LIST_CHUNK", chunk)
     work = jobs(seed=5)
     want = serve_all(engine_for(params), work)
     d = str(tmp_path / "dump")

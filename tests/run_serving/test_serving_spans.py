@@ -13,7 +13,7 @@ import pytest
 from apex_tpu import observability as obs
 from apex_tpu.models import llama
 from apex_tpu.observability import SpanTracer, set_tracer
-from apex_tpu.serving import ServingEngine
+from apex_tpu.serving import ServingEngine, scheduler
 
 PAGE = 8
 JOBS = ((3, 4), (8, 7), (11, 4), (5, 7), (8, 4), (20, 1))
@@ -119,7 +119,13 @@ def test_every_request_has_its_spans_under_one_rid(served):
         == sum(len(r.tokens) - 1 for r in engine.completed)
 
 
-def test_decode_records_count_live_and_gathered_pages(model, tracer):
+def test_decode_records_count_live_and_gathered_pages(model, tracer,
+                                                      monkeypatch):
+    """`pages_gathered` is what the step reads in a cache layer: the whole
+    chunks of its list of live pages (ISSUE 32; chunks of 4 here, so that the
+    15 slots of these tables are more than one). One compiled step serves
+    every count of chunks."""
+    monkeypatch.setattr(scheduler, "LIST_CHUNK", 4)
     params, cfg = model
     engine = ServingEngine(params, cfg, page_size=PAGE, max_batch=3,
                            num_pages=32, max_prompt_len=24, max_new_cap=16,
@@ -129,6 +135,7 @@ def test_decode_records_count_live_and_gathered_pages(model, tracer):
     for p, max_new in JOBS:
         engine.submit(rng.integers(0, cfg.vocab_size, size=p).astype(
             np.int32), max_new)
+    chunks = set()
     while engine.pending:
         before, done = sched.decode_steps, len(engine.completed)
         mark = tracer.mark()
@@ -145,5 +152,8 @@ def test_decode_records_count_live_and_gathered_pages(model, tracer):
                     for r in engine.completed[done:] if len(r.tokens) > 1)
         assert record.args["pages_live"] == live
         assert 0 < record.args["pages_live"] <= record.args["pages_gathered"]
-        assert record.args["pages_gathered"] == 3 * sched.max_pages_per_req
+        assert record.args["pages_gathered"] == math.ceil(live / 4) * 4
+        assert record.args["pages_gathered"] < 3 * sched.max_pages_per_req
         assert 0 < record.args["rows"] <= 3
+        chunks.add(record.args["pages_gathered"] // 4)
+    assert len(chunks) > 1 and sched.decode_retraces() == 0
