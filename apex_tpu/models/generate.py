@@ -33,8 +33,10 @@ from apex_tpu.ops.flash_attention import flash_attention
 __all__ = ["greedy_generate", "generate", "gpt2_generate"]
 
 
-def _decode_attention(q, k_cache, v_cache, pos):
-    """q [b, 1, nq, d] vs cache [b, max_len, nkv, d], valid idx <= pos.
+def _decode_attention(q, k_cache, v_cache, pos, start=None):
+    """q [b, 1, nq, d] vs cache [b, max_len, nkv, d], valid idx <= pos
+    (and ``start <= idx`` where a sliding layer gives its window's start,
+    shaped like ``pos``).
 
     GQA contracts grouped: q reshapes to [b, nkv, rep, d] (query head
     n = kv * rep + r) and both einsums run against the nkv-head cache
@@ -52,7 +54,10 @@ def _decode_attention(q, k_cache, v_cache, pos):
                         k_cache.astype(jnp.float32)) * (d ** -0.5)
     scores = scores.reshape(b, nq, -1)            # [b, nq, T]
     idx = jnp.arange(k_cache.shape[1])
-    scores = jnp.where(idx[None, None, :] <= pos, scores, -jnp.inf)
+    seen = idx[None, None, :] <= pos
+    if start is not None:
+        seen = seen & (idx[None, None, :] >= start)
+    scores = jnp.where(seen, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     o = jnp.einsum("bkrt,btkd->bkrd", probs.reshape(b, nkv, rep, -1),
                    v_cache.astype(jnp.float32))
@@ -118,6 +123,17 @@ def _routed(ffn, cfg):
     return functools.partial(ffn, cfg=cfg) if cfg.moe else None
 
 
+def _block(x, lp, params, cfg, positions, attend, ffn):
+    """``llama.block`` as this module calls it: a dropless expert model's
+    layers go through ``llama.routed_block`` (its counts are a server's to
+    read), every other through ``block`` with the Mixtral form ``ffn``."""
+    if cfg.dropless:
+        return _llama.routed_block(x, lp, _llama.expert_stack(params), cfg,
+                                   positions, attend)[:2]
+    return _llama.block(x, lp, cfg, positions, attend,
+                        ffn=_routed(ffn, cfg))
+
+
 def _flash_keeping_kv(q, k, v):
     """The prefills' ``attend`` (``llama.block``): causal flash attention
     over the whole prompt, the rotated K and V kept for the cache."""
@@ -125,17 +141,28 @@ def _flash_keeping_kv(q, k, v):
     return o, (k, v)
 
 
-def _attend_cache_at(pos, k_cache, v_cache):
+def _prefill_attend(lp, cfg):
+    """The prefills' ``attend`` for this layer: :func:`_flash_keeping_kv`,
+    within the window where the layer is a sliding one."""
+    if not cfg.windowed:
+        return _flash_keeping_kv
+    return lambda q, k, v: (_llama.causal_attention(q, k, v, lp, cfg),
+                            (k, v))
+
+
+def _attend_cache_at(pos, k_cache, v_cache, start=None):
     """The decode loop's ``attend``: one position's K and V written into
     the contiguous ``[b, max_len, nkv, d]`` cache at ``pos``, the query
-    attending to everything up to it; the updated cache is kept."""
+    attending to everything up to it (from ``start`` on, where a sliding
+    layer gives one); the updated cache is kept."""
 
     def attend(q, k, v):
         kc = jax.lax.dynamic_update_slice_in_dim(
             k_cache, k.astype(k_cache.dtype), pos, axis=1)
         vc = jax.lax.dynamic_update_slice_in_dim(
             v_cache, v.astype(v_cache.dtype), pos, axis=1)
-        return _decode_attention(q, kc, vc, pos).astype(q.dtype), (kc, vc)
+        return (_decode_attention(q, kc, vc, pos, start).astype(q.dtype),
+                (kc, vc))
 
     return attend
 
@@ -191,7 +218,9 @@ def generate(params, prompt_tokens, cfg, max_new_tokens: int,
     with ``key``. The prompt must be dense (no padding); cache length is
     ``p + max_new_tokens``. MoE configs route every token through its
     top-k experts with NO capacity drop (the training path's drops are a
-    throughput artifact, not an inference semantic).
+    throughput artifact, not an inference semantic); a dropless expert
+    model runs the layer it is served by (``llama.moe_ffn``), and sliding
+    layers read their window of the cache.
     """
     b, p = prompt_tokens.shape
     key = _check_sampling_args(temperature, key)
@@ -201,11 +230,11 @@ def generate(params, prompt_tokens, cfg, max_new_tokens: int,
     x = _llama.embed(params, prompt_tokens, cfg, tp_axis=None)
 
     def pre_body(h, lp, _):
-        return _llama.block(h, lp, cfg, positions, _flash_keeping_kv,
-                            ffn=_routed(_moe_prefill_ffn, cfg))
+        return _block(h, lp, params, cfg, positions,
+                      _prefill_attend(lp, cfg), _moe_prefill_ffn)
 
     x, (ks, vs) = _llama.scan_passes(x, params, cfg, pre_body,
-                                     params["layers"])
+                                     _llama.stacks(params, cfg))
     pad = [(0, 0), (0, 0), (0, max_new_tokens), (0, 0), (0, 0)]
     k_cache = jnp.pad(ks.astype(cfg.dtype), pad)  # [T*L, b, max_len, ...]
     v_cache = jnp.pad(vs.astype(cfg.dtype), pad)
@@ -213,12 +242,14 @@ def generate(params, prompt_tokens, cfg, max_new_tokens: int,
 
     def decode_stack(x, caches, pos):
         def layer(h, lp, cache):
-            return _llama.block(h, lp, cfg, jnp.full((b, 1), pos, jnp.int32),
-                                _attend_cache_at(pos, *cache),
-                                ffn=_routed(_moe_decode_ffn, cfg))
+            return _block(h, lp, params, cfg,
+                          jnp.full((b, 1), pos, jnp.int32),
+                          _attend_cache_at(
+                              pos, *cache, _llama.window_start(lp, cfg, pos)),
+                          _moe_decode_ffn)
 
-        return _llama.scan_passes(x, params, cfg, layer, params["layers"],
-                                  caches)
+        return _llama.scan_passes(x, params, cfg, layer,
+                                  _llama.stacks(params, cfg), caches)
 
     return _autoregress(
         lambda token, pos: _llama.embed(params, token, cfg, tp_axis=None),

@@ -39,15 +39,35 @@ caches them holds ``cfg.cache_layers = num_passes * num_layers`` layers,
 pass-major. The exit gate's weights are in the tree; the served token is
 the last pass's (the published ``early_exit_threshold`` of 1), and
 :func:`exit_distribution` gives the gate's reading of every pass.
+
+Stacks of more than one layer kind (the ``afmoe`` form, Trinity). Two
+things may differ from layer to layer, and both are facts of the published
+model that ``LlamaConfig`` states. *The FFN:* the first ``num_dense_layers``
+are dense SwiGLU, the others dropless expert layers
+(``moe_capacity_factor=None``: :func:`moe_ffn`, sigmoid or softmax routing
+over all ``num_experts``, the experts of ``experts_held`` computed here, a
+shared expert beside them). Their weights differ in shape, so they are two
+stacks, ``params["dense_layers"]`` and ``params["layers"]``, each scanned
+(:func:`stacks`, :func:`scan_passes`). *The attention:* ``layer_types``
+names each layer ``sliding_attention`` (keys ``i - sliding_window < j <=
+i``) or ``full_attention``; ``rope_full_attention=False`` leaves the full
+layers without a position signal. Sliding and full layers have the same
+weights, so a layer's kind is data of its scan step (``lp["sliding"]``, a
+bool beside the weights): a window bound that is ``pos - window + 1`` or 0,
+a select between the rotated and the plain heads. ``qk_norm`` (RMSNorm on
+each query and key head), ``attn_output_gate`` (a sigmoid gate on the
+heads' output) and ``embed_scale`` are written once, in :func:`block` and
+:func:`embed`. With the defaults none of this is in any program.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from apex_tpu.models._common import fan_in_normal
 
@@ -90,16 +110,88 @@ class LlamaConfig:
     # many SwiGLU experts (top-k, capacity-dropped) over the 'ep' axis
     num_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
+    # None: token choice with no capacity, no token ever dropped (the form
+    # that is served: transformer/moe.dropless_experts)
+    moe_capacity_factor: Optional[float] = 1.25
     # looped stack: the [L, ...] layers run this many times over the same
     # weights, the final norm between passes (Ouro's ``total_ut_steps``)
     num_passes: int = 1
     # RMSNorm on each sub-layer's output too, before the residual add
     sandwich_norm: bool = False
+    # a head's width where it is not hidden_size / num_heads
+    attn_head_dim: Optional[int] = None
+    # each layer's attention, "sliding_attention" or "full_attention"; ()
+    # is full attention everywhere. A sliding layer's query i reads keys
+    # i - sliding_window < j <= i
+    layer_types: Tuple[str, ...] = ()
+    sliding_window: Optional[int] = None
+    # False: only the sliding layers rotate; a full layer has no position
+    # signal
+    rope_full_attention: bool = True
+    # RMSNorm with a gain over each query and key head, before the rotation
+    qk_norm: bool = False
+    # o = (heads * sigmoid(h @ wgate)) @ wo
+    attn_output_gate: bool = False
+    # the embedding's output is multiplied by this (muP: sqrt(hidden))
+    embed_scale: float = 1.0
+    # a dropless expert model: the first layers are dense SwiGLU of
+    # intermediate_size, the others route over num_experts experts of
+    # moe_intermediate_size beside num_shared_experts that every token uses
+    num_dense_layers: int = 0
+    moe_intermediate_size: Optional[int] = None
+    num_shared_experts: int = 0
+    router_score: str = "softmax"            # or "sigmoid"
+    route_norm: bool = True
+    route_scale: float = 1.0
+    # a [num_experts] bias that enters the selection, not the weight
+    router_bias: bool = False
+    # (first, count): the experts whose weights are here, of num_experts
+    # routed over (one chip's share of an expert-parallel deployment);
+    # None holds them all
+    experts_held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        if self.layer_types and len(self.layer_types) != self.num_layers:
+            raise ValueError(f"{len(self.layer_types)} layer_types for "
+                             f"{self.num_layers} layers")
+        if set(self.layer_types) - {"sliding_attention", "full_attention"}:
+            raise ValueError(f"unknown layer type in {self.layer_types}")
+        if self.windowed and not self.sliding_window:
+            raise ValueError("sliding_attention layers need a "
+                             "sliding_window")
+        if not self.dropless and (self.num_dense_layers
+                                  or self.num_shared_experts
+                                  or self.experts_held):
+            raise ValueError(
+                "num_dense_layers, num_shared_experts and experts_held "
+                "describe a dropless expert model (num_experts > 0, "
+                "moe_capacity_factor None)")
+        if self.dropless and self.num_passes > 1:
+            raise NotImplementedError(
+                "a looped stack of expert layers: no model has one")
 
     @property
     def moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def dropless(self) -> bool:
+        """Expert layers with no capacity: no token is ever dropped."""
+        return self.moe and self.moe_capacity_factor is None
+
+    @property
+    def windowed(self) -> bool:
+        """Whether any layer is a sliding one."""
+        return "sliding_attention" in self.layer_types
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """``(first, count)`` of the experts whose weights are here."""
+        return self.experts_held or (0, self.num_experts)
+
+    @property
+    def expert_layers(self) -> int:
+        return self.num_layers - self.num_dense_layers if self.moe else 0
 
     @property
     def cache_layers(self) -> int:
@@ -109,7 +201,7 @@ class LlamaConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.attn_head_dim or self.hidden_size // self.num_heads
 
 
 def llama3_8b(**over) -> LlamaConfig:
@@ -153,6 +245,8 @@ def init_params(key, cfg: LlamaConfig):
     def norm(k, *shape, fan_in=None):
         return fan_in_normal(k, *shape, fan_in=fan_in, dtype=dt)
 
+    if cfg.dropless:
+        return _init_dropless(key, cfg)
     layers = {
         "attn_norm": jnp.ones((L, h), dt),
         "wq": norm(ks[1], L, h, nq * d),
@@ -164,6 +258,11 @@ def init_params(key, cfg: LlamaConfig):
     if cfg.sandwich_norm:
         layers["attn_post_norm"] = jnp.ones((L, h), dt)
         layers["mlp_post_norm"] = jnp.ones((L, h), dt)
+    if cfg.qk_norm:
+        layers["q_norm"] = jnp.ones((L, d), dt)
+        layers["k_norm"] = jnp.ones((L, d), dt)
+    if cfg.attn_output_gate:
+        layers["wgate"] = norm(jax.random.fold_in(key, 11), L, h, nq * d)
     if cfg.moe:
         E = cfg.num_experts
         layers.update({
@@ -194,6 +293,71 @@ def init_params(key, cfg: LlamaConfig):
     return params
 
 
+def _init_dropless(key, cfg: LlamaConfig):
+    """:func:`init_params` of a dropless expert model: the dense lead in
+    ``dense_layers`` (absent without one), the expert layers in ``layers``,
+    each stacked on dim 0; the expert weights are those of
+    ``cfg.experts_held`` alone, the router's all ``num_experts`` wide."""
+    h, d, dt = cfg.hidden_size, cfg.head_dim, cfg.dtype
+    nq, nkv = cfg.num_heads, cfg.num_kv_heads
+    f = cfg.moe_intermediate_size or cfg.intermediate_size
+
+    def norm(k, *shape, fan_in=None):
+        return fan_in_normal(k, *shape, fan_in=fan_in, dtype=dt)
+
+    def stack(key, n, ffn):
+        ks = jax.random.split(key, 6)
+        out = {"attn_norm": jnp.ones((n, h), dt),
+               "mlp_norm": jnp.ones((n, h), dt),
+               "wq": norm(ks[0], n, h, nq * d),
+               "wk": norm(ks[1], n, h, nkv * d),
+               "wv": norm(ks[2], n, h, nkv * d),
+               "wo": norm(ks[3], n, nq * d, h)}
+        if cfg.sandwich_norm:
+            out["attn_post_norm"] = jnp.ones((n, h), dt)
+            out["mlp_post_norm"] = jnp.ones((n, h), dt)
+        if cfg.qk_norm:
+            out["q_norm"] = jnp.ones((n, d), dt)
+            out["k_norm"] = jnp.ones((n, d), dt)
+        if cfg.attn_output_gate:
+            out["wgate"] = norm(ks[4], n, h, nq * d)
+        out.update(ffn(ks[5], n))
+        return out
+
+    def dense(key, n):
+        k = jax.random.split(key, 3)
+        i = cfg.intermediate_size
+        return {"wg": norm(k[0], n, h, i), "wu": norm(k[1], n, h, i),
+                "wd": norm(k[2], n, i, h)}
+
+    def routed(key, n):
+        k = jax.random.split(key, 8)
+        held, fs = cfg.held[1], f * cfg.num_shared_experts
+        out = {"router": (jax.random.normal(k[0], (n, h, cfg.num_experts))
+                          * 0.02).astype(dt),
+               "wg": norm(k[1], n, held, h, f),
+               "wu": norm(k[2], n, held, h, f),
+               "wd": norm(k[3], n, held, f, h)}
+        if cfg.router_bias:
+            out["router_bias"] = jnp.zeros((n, cfg.num_experts),
+                                           jnp.float32)
+        if fs:
+            out.update(shared_wg=norm(k[4], n, h, fs),
+                       shared_wu=norm(k[5], n, h, fs),
+                       shared_wd=norm(k[6], n, fs, h))
+        return out
+
+    ks = jax.random.split(key, 4)
+    params = {"embed": norm(ks[0], cfg.vocab_size, h, fan_in=h),
+              "layers": stack(ks[1], cfg.expert_layers, routed),
+              "final_norm": jnp.ones((h,), dt)}
+    if cfg.num_dense_layers:
+        params["dense_layers"] = stack(ks[2], cfg.num_dense_layers, dense)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = norm(ks[3], h, cfg.vocab_size, fan_in=h)
+    return params
+
+
 def _rmsnorm(x, w, eps):
     return fused_rms_norm_affine(x, w, (x.shape[-1],), eps=eps)
 
@@ -208,6 +372,46 @@ def products(x, lp, *names):
     """``x`` times each named weight of the layer, in ``x``'s dtype: the
     plain form of :func:`block`'s product hook."""
     return (jnp.matmul(x, lp[n].astype(x.dtype)) for n in names)
+
+
+def _rotate(q, k, lp, cfg: LlamaConfig, positions):
+    """RoPE on the heads; where only the sliding layers rotate, a select on
+    the layer's kind (and no rotation at all where no layer is a sliding
+    one)."""
+    if not (cfg.rope_full_attention or cfg.windowed):
+        return q, k
+    rq, rk = apply_rotary_qk(q, k, positions=positions, base=cfg.rope_theta)
+    if cfg.rope_full_attention:
+        return rq, rk
+    return (jnp.where(lp["sliding"], rq, q), jnp.where(lp["sliding"], rk, k))
+
+
+def sliding_start(cfg: LlamaConfig, pos):
+    """The first position a sliding layer's query at ``pos`` reads."""
+    return jnp.maximum(pos - cfg.sliding_window + 1, 0)
+
+
+def window_start(lp, cfg: LlamaConfig, pos):
+    """The first position a query at ``pos`` reads in this layer:
+    :func:`sliding_start` on a sliding layer, 0 on a full one; None where
+    the model has no sliding layer."""
+    if not cfg.windowed:
+        return None
+    return jnp.where(lp["sliding"], sliding_start(cfg, pos), 0)
+
+
+def causal_attention(q, k, v, lp, cfg: LlamaConfig):
+    """Causal flash attention of a whole sequence on this layer's kind:
+    within the window on a sliding layer. A sequence no longer than the
+    window is cut by none, and every layer runs the plain call."""
+    scale = cfg.head_dim ** -0.5
+    if not cfg.windowed or q.shape[1] <= cfg.sliding_window:
+        return flash_attention(q, k, v, causal=True, scale=scale)
+    return jax.lax.cond(
+        lp["sliding"],
+        lambda: flash_attention(q, k, v, causal=True, scale=scale,
+                                window=cfg.sliding_window),
+        lambda: flash_attention(q, k, v, causal=True, scale=scale))
 
 
 def block(x, lp, cfg: LlamaConfig, positions, attend, mm=products, ffn=None):
@@ -227,14 +431,28 @@ def block(x, lp, cfg: LlamaConfig, positions, attend, mm=products, ffn=None):
     - ``ffn(h, lp)``: the feed-forward on the normed stream; without one,
       the dense SwiGLU through ``mm``.
 
+    What the config states of the attention block is here too: the norm on
+    each query and key head (``qk_norm``), the rotation, which a layer of
+    a kind that does not rotate passes by (:func:`_rotate`), and the
+    sigmoid gate on the heads' output (``attn_output_gate``). The window of
+    a sliding layer is ``attend``'s: it alone knows the keys.
+
     Returns ``(x, kept)``."""
     d = cfg.head_dim
     h = _rmsnorm(x, lp["attn_norm"], cfg.rms_eps)
-    q, k, v = (y.reshape(*y.shape[:2], -1, d)
-               for y in mm(h, lp, "wq", "wk", "wv"))
-    q, k = apply_rotary_qk(q, k, positions=positions, base=cfg.rope_theta)
+    q, k, v, *gate = (
+        y.reshape(*y.shape[:2], -1, d) for y in mm(
+            h, lp, "wq", "wk", "wv",
+            *(("wgate",) if cfg.attn_output_gate else ())))
+    if cfg.qk_norm:
+        q = _rmsnorm(q, lp["q_norm"], cfg.rms_eps)
+        k = _rmsnorm(k, lp["k_norm"], cfg.rms_eps)
+    q, k = _rotate(q, k, lp, cfg, positions)
     o, kept = attend(q, k, v)
-    (y,) = mm(o.reshape(*o.shape[:2], -1), lp, "wo")
+    o = o.reshape(*o.shape[:2], -1)
+    if gate:
+        o = o * jax.nn.sigmoid(gate[0].reshape(o.shape))
+    (y,) = mm(o, lp, "wo")
     x = x + post_norm(y, lp, "attn_post_norm", cfg)
     h = _rmsnorm(x, lp["mlp_norm"], cfg.rms_eps)
     if ffn is None:
@@ -243,6 +461,64 @@ def block(x, lp, cfg: LlamaConfig, positions, attend, mm=products, ffn=None):
     else:
         y = ffn(h, lp)
     return x + post_norm(y, lp, "mlp_post_norm", cfg), kept
+
+
+def expert_stack(params):
+    """The routed experts' weights of every expert layer end to end, ``[L *
+    held, ...]`` (a bitcast of the ``[L, held, ...]`` stacks): what
+    :func:`moe_ffn` multiplies by, whole, the layer's own experts found in
+    it at ``lp["expert_at"]``. A scan over the layers that took them as its
+    ``xs`` would cut a layer's experts out of the stack every step, 1.8 GB
+    at Trinity's widths; the grouped products read them where they lie."""
+    return {n: params["layers"][n].reshape(
+        (-1,) + params["layers"][n].shape[2:]) for n in ("wg", "wu", "wd")}
+
+
+def moe_ffn(h, lp, cfg: LlamaConfig, experts, mm=products, valid=None):
+    """The FFN of a dropless expert layer on the normed stream ``[b, s,
+    h]``: ``Shared(h) + sum_{e in top-k} w_e Expert_e(h)``, routed over all
+    ``num_experts``, computed for the experts held here
+    (``transformer/moe.route``, ``dropless_experts``), whose weights are
+    ``experts`` (:func:`expert_stack`) from group ``lp["expert_at"]`` on.
+    ``valid [b, s]`` keeps padded positions and empty rows out of the
+    routing. Returns ``(y, counts)``, ``counts`` int32 ``[2]``: the
+    assignments on held experts and the held experts touched."""
+    from apex_tpu.transformer.moe import dropless_experts, route
+
+    xt = h.reshape(-1, h.shape[-1])
+    w, idx = route(xt, lp["router"],
+                   lp["router_bias"] if cfg.router_bias else None,
+                   top_k=cfg.moe_top_k, score=cfg.router_score,
+                   route_norm=cfg.route_norm, route_scale=cfg.route_scale)
+    y, counts = dropless_experts(
+        xt, w, idx, experts, cfg.held,
+        None if valid is None else valid.reshape(-1), lp["expert_at"])
+    y = y.reshape(h.shape)
+    if cfg.num_shared_experts:
+        with jax.named_scope("moe/shared"):
+            g, u = mm(h, lp, "shared_wg", "shared_wu")
+            (shared,) = mm(jax.nn.silu(g) * u, lp, "shared_wd")
+        y = y + shared
+    return y, counts
+
+
+def routed_block(x, lp, experts, cfg: LlamaConfig, positions, attend,
+                 mm=products, valid=None):
+    """:func:`block` on a layer of a dropless expert model, whichever stack
+    it is of: the expert layer as its ``ffn`` where ``lp`` has a router
+    (``experts``: :func:`expert_stack`), the dense SwiGLU where it has
+    none. Returns ``(x, kept, counts)``, ``counts`` as :func:`moe_ffn`'s
+    (zeros from a dense layer)."""
+    counts = jnp.zeros(2, jnp.int32)
+    ffn = None
+    if "router" in lp:
+        def ffn(h, lp):
+            nonlocal counts
+            y, counts = moe_ffn(h, lp, cfg, experts, mm, valid)
+            return y
+
+    x, kept = block(x, lp, cfg, positions, attend, mm, ffn)
+    return x, kept, counts
 
 
 def _moe_cfg(cfg: LlamaConfig):
@@ -284,7 +560,7 @@ def decoder_layer(x, lp, cfg: LlamaConfig, positions,
                   tp_axis: Optional[str] = "tp",
                   cp_axis: Optional[str] = "cp",
                   sequence_parallel: bool = False,
-                  ep_axis: Optional[str] = "ep"):
+                  ep_axis: Optional[str] = "ep", experts=None):
     """:func:`block` for training, on [b, s_local, h]: q/k/v heads and the
     FFN's width tp-sharded, the sequence cp-sharded (ring attention when
     'cp' is bound), nothing kept. Returns ``(x, aux)`` — aux is the MoE
@@ -306,8 +582,15 @@ def decoder_layer(x, lp, cfg: LlamaConfig, positions,
             return gather_from_sequence_parallel_region(h, tp_axis, seq_dim=1)
         return h
 
+    if (cfg.dropless or cfg.windowed) and (
+            tp > 1 or sequence_parallel or _axis_bound(cp_axis)):
+        raise NotImplementedError(
+            "tensor, sequence and context parallelism of a model with "
+            "dropless experts or sliding-window layers are not built: its "
+            "plain forward runs, nothing trains it yet")
+
     def mm(h, lp, *names):
-        if names[0] in ("wo", "wd"):     # row kernels: the input is sharded
+        if names[0] in ("wo", "wd", "shared_wd"):  # row kernels: sharded input
             return [row_parallel_linear(
                 h, lp[n], input_is_parallel=True,
                 sequence_parallel_enabled=sequence_parallel,
@@ -323,8 +606,7 @@ def decoder_layer(x, lp, cfg: LlamaConfig, positions,
                                   causal=True), None
         # GQA-aware flash attention: online softmax, no [s, s] matrix in
         # HBM fwd or bwd (jnp fallback off-TPU is the same math)
-        return flash_attention(q, k, v, causal=True,
-                               scale=cfg.head_dim ** -0.5), None
+        return causal_attention(q, k, v, lp, cfg), None
 
     aux = jnp.zeros((), jnp.float32)
 
@@ -334,6 +616,9 @@ def decoder_layer(x, lp, cfg: LlamaConfig, positions,
                           sequence_parallel)
         return y
 
+    if cfg.dropless:
+        x, _, _ = routed_block(x, lp, experts, cfg, positions, attend, mm)
+        return x, aux
     x, _ = block(x, lp, cfg, positions, attend, mm,
                  routed if cfg.moe else None)
     return x, aux
@@ -348,14 +633,14 @@ def _positions(b, s_local, cp_axis):
 
 
 def _layer_body(cfg: LlamaConfig, positions, tp_axis, cp_axis,
-                sequence_parallel, remat, ep_axis):
+                sequence_parallel, remat, ep_axis, experts=None):
     """``(h, lp) -> (h, aux)`` for one layer, under the remat policy."""
 
     def body(h, lp):
         # aux rides the scan's stacked outputs, not the carry — a fresh
         # zero carry would need its vma hand-matched under shard_map
         return decoder_layer(h, lp, cfg, positions, tp_axis, cp_axis,
-                             sequence_parallel, ep_axis)
+                             sequence_parallel, ep_axis, experts)
 
     if remat:
         policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
@@ -365,7 +650,7 @@ def _layer_body(cfg: LlamaConfig, positions, tp_axis, cp_axis,
 
 
 def _ep_varying(x, cfg: LlamaConfig, ep_axis):
-    if cfg.moe and _axis_bound(ep_axis):
+    if cfg.moe and not cfg.dropless and _axis_bound(ep_axis):
         # the MoE all_to_all makes the stream ep-varying; the carry must
         # start that way or the scan's vma check trips
         from apex_tpu.transformer.tensor_parallel.mappings import (
@@ -389,10 +674,44 @@ def run_layers(x, stacked, cfg: LlamaConfig, positions,
     (``jax.checkpoint_policies.dots_with_no_batch_dims_saveable``) — the
     usual best memory/MFU trade on TPU, where the recompute that hurts is
     the MXU work, not the VPU chains."""
+    if cfg.dropless or cfg.windowed:
+        raise NotImplementedError(
+            "run_layers scans one stack of one layer kind; a model with a "
+            "dense lead or sliding-window layers goes through scan_passes "
+            "over stacks(params, cfg)")
     body = _layer_body(cfg, positions, tp_axis, cp_axis, sequence_parallel,
                        remat, ep_axis)
     x, auxs = jax.lax.scan(body, _ep_varying(x, cfg, ep_axis), stacked)
     return x, jnp.sum(auxs)
+
+
+def stacks(params, cfg: LlamaConfig, **riders):
+    """What :func:`scan_passes` takes as ``shared``: the model's layer
+    stacks in the order a token goes through them. A model of one stack
+    gives ``params["layers"]``; one with a dense lead gives the tuple
+    ``(params["dense_layers"], params["layers"])``. ``riders`` are put
+    among each stack's weights (the serving programs' ``scales``); where
+    the model has sliding layers every stack also carries ``sliding``, its
+    layers' kinds as a bool a layer, so that a scan step reads its kind as
+    it reads its weights. A dropless expert stack goes without its routed
+    experts' weights, which no scan may slice (:func:`expert_stack`), and
+    carries ``expert_at``, where each layer's experts begin in them."""
+    names = [n for n in ("dense_layers", "layers") if n in params]
+    out, first = [], 0
+    for name in names:
+        stack = {**params[name], **riders} if riders else params[name]
+        n = stack["attn_norm"].shape[0]
+        if cfg.dropless and "router" in stack:
+            stack = {k: v for k, v in stack.items()
+                     if k not in ("wg", "wu", "wd")}
+            stack["expert_at"] = jnp.arange(n, dtype=jnp.int32) * cfg.held[1]
+        if cfg.windowed:
+            kinds = np.asarray(cfg.layer_types[first:first + n])
+            stack = {**stack, "sliding": jnp.asarray(
+                kinds == "sliding_attention")}
+        out.append(stack)
+        first += n
+    return out[0] if len(out) == 1 else tuple(out)
 
 
 def scan_passes(x, params, cfg: LlamaConfig, layer_fn, shared,
@@ -416,8 +735,24 @@ def scan_passes(x, params, cfg: LlamaConfig, layer_fn, shared,
     norm. Returns ``(carry, outs)``, ``outs`` stacked over the steps. With
     one pass this is the plain scan over ``(shared, per_layer)``: a model
     that is not looped compiles to what it did before.
+
+    ``shared`` may be a tuple of stacks (:func:`stacks`: a dense lead, then
+    the expert layers): one scan each, in turn, over its own slice of
+    ``per_layer``; ``outs`` are the scans' laid end to end.
     """
     L, T = cfg.num_layers, cfg.num_passes
+    if isinstance(shared, tuple):
+        outs, first = [], 0
+        for stack in shared:
+            n = stack["attn_norm"].shape[0]
+            mine = jax.tree_util.tree_map(lambda a: a[first:first + n],
+                                          per_layer)
+            x, out = jax.lax.scan(lambda h, xs: layer_fn(h, *xs), x,
+                                  (stack, mine))
+            outs.append(out)
+            first += n
+        return x, jax.tree_util.tree_map(
+            lambda *a: jnp.concatenate(a), *outs)
     if T == 1:
         return jax.lax.scan(lambda h, xs: layer_fn(h, *xs), x,
                             (shared, per_layer))
@@ -443,6 +778,8 @@ def embed(params, tokens, cfg: LlamaConfig, tp_axis="tp",
           sequence_parallel=False):
     x = vocab_parallel_embedding(tokens, params["embed"], axis_name=tp_axis)
     x = x.astype(cfg.dtype)
+    if cfg.embed_scale != 1.0:
+        x = (x.astype(jnp.float32) * cfg.embed_scale).astype(cfg.dtype)
     if sequence_parallel:
         x = scatter_to_sequence_parallel_region(x, tp_axis, seq_dim=1)
     return x
@@ -484,9 +821,10 @@ def hidden_states(params, tokens, cfg: LlamaConfig,
     positions = _positions(b, s, cp_axis)
     x = embed(params, tokens, cfg, tp_axis, sequence_parallel)
     body = _layer_body(cfg, positions, tp_axis, cp_axis, sequence_parallel,
-                       remat, ep_axis)
+                       remat, ep_axis,
+                       expert_stack(params) if cfg.dropless else None)
     x, auxs = scan_passes(_ep_varying(x, cfg, ep_axis), params, cfg,
-                          lambda h, lp, _: body(h, lp), params["layers"])
+                          lambda h, lp, _: body(h, lp), stacks(params, cfg))
     return x, jnp.sum(auxs)
 
 
@@ -557,6 +895,11 @@ def loss_fn(params, batch, cfg: LlamaConfig,
     so the fp32 ``[b·s, vocab]`` logits — the largest live buffer of an
     LLM step — are never materialized (functional/chunked_ce.py). With a
     bound ``tp_axis`` the per-rank streams merge vocab-parallel."""
+    if cfg.dropless:
+        raise NotImplementedError(
+            "training a dropless expert model is not built: its balance "
+            "loss and the router bias's update are training's, and the "
+            "Pallas backward of a windowed flash call raises")
     tokens, targets = batch
     if vocab_chunks:
         from apex_tpu.transformer.functional.chunked_ce import (
@@ -594,7 +937,26 @@ def param_specs(cfg: LlamaConfig, tp_axis: str = "tp",
     }
     if cfg.sandwich_norm:
         layer_specs.update(attn_post_norm=P(), mlp_post_norm=P())
-    if cfg.moe:
+    if cfg.qk_norm:
+        layer_specs.update(q_norm=P(), k_norm=P())
+    if cfg.attn_output_gate:
+        layer_specs["wgate"] = P(None, None, t)
+    dense_ffn = {"wg": P(None, None, t), "wu": P(None, None, t),
+                 "wd": P(None, t, None)}
+    lead = None
+    if cfg.dropless:
+        # the experts held are this rank's own: nothing of them is sharded
+        # further; the shared expert shards like a dense FFN
+        if cfg.num_dense_layers:
+            lead = {**layer_specs, **dense_ffn}
+        layer_specs.update(router=P(), wg=P(), wu=P(), wd=P())
+        if cfg.router_bias:
+            layer_specs["router_bias"] = P()
+        if cfg.num_shared_experts:
+            layer_specs.update(shared_wg=P(None, None, t),
+                               shared_wu=P(None, None, t),
+                               shared_wd=P(None, t, None))
+    elif cfg.moe:
         # experts shard over ep_axis (orthogonal to tp); router replicates
         e = ep_axis
         layer_specs.update({
@@ -604,15 +966,14 @@ def param_specs(cfg: LlamaConfig, tp_axis: str = "tp",
             "wd": P(None, e, None, None),
         })
     else:
-        layer_specs.update({
-            "wg": P(None, None, t), "wu": P(None, None, t),
-            "wd": P(None, t, None),
-        })
+        layer_specs.update(dense_ffn)
     specs = {
         "embed": P(t, None),
         "layers": layer_specs,
         "final_norm": P(),
     }
+    if lead is not None:
+        specs["dense_layers"] = lead
     if not cfg.tie_embeddings:
         specs["lm_head"] = P(None, t)
     if cfg.num_passes > 1:

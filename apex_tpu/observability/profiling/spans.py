@@ -163,6 +163,13 @@ class SpanTracer:
             [name, time.monotonic_ns(), span_id, rid, args or None])
         return span_id
 
+    def annotate(self, **args) -> None:
+        """Add integer fields to the innermost span open on the calling
+        thread: counts that are known only once its work has come back."""
+        stack = self._stacks.get(threading.get_ident())
+        if stack:
+            stack[-1][4] = {**(stack[-1][4] or {}), **args}
+
     def end(self) -> None:
         """Close the innermost open span on the calling thread and
         commit it to the ring."""

@@ -9,6 +9,10 @@ Design (TPU-first, not a CUDA port):
 - causal masking is positional (iota compare) — no mask tensor ever
   materializes in HBM (the reference's kernels read a cu_seqlens array;
   fixed-shape batched input is the TPU-friendly layout).
+- a sliding ``window`` (forward only) is a second positional bound: query
+  ``i`` reads keys ``i - window < j <= i``. Key blocks wholly below a query
+  block's window are skipped as those above the diagonal are, and the edge
+  block is masked. The backward kernels do not know a window and raise.
 
 Backward (FlashAttention-2 style, TPU-blocked): the forward additionally
 writes the per-row logsumexp; the backward recomputes p-blocks from (q, k,
@@ -63,7 +67,7 @@ def _keep_mask(seed, bh, q_pos, k_pos, p_drop):
 
 
 def _fwd_kernel(causal, scale, block_q, block_k, sq, sk, varlen, p_drop,
-                q_ref, k_ref, v_ref, *refs):
+                window, q_ref, k_ref, v_ref, *refs):
     refs = list(refs)
     kvlen_ref = refs.pop(0) if varlen else None
     seed_ref = refs.pop(0) if p_drop else None
@@ -88,6 +92,9 @@ def _fwd_kernel(causal, scale, block_q, block_k, sq, sk, varlen, p_drop,
     if causal:
         # whole block above the diagonal ⇒ nothing to do
         run = (ki * block_k) <= (qi * block_q + block_q - 1)
+    if window is not None:
+        # whole block below the first query's window ⇒ nothing to do
+        run = run & ((ki * block_k + block_k - 1) > (qi * block_q - window))
     if varlen:
         # whole block past this sequence's keys ⇒ nothing to do
         run = run & ((ki * block_k) < kvlen_ref[0, 0, 0])
@@ -101,6 +108,8 @@ def _fwd_kernel(causal, scale, block_q, block_k, sq, sk, varlen, p_drop,
             preferred_element_type=jnp.float32)           # [bq, bk]
         if causal:
             s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
+        if window is not None:
+            s = jnp.where(k_pos > q_pos - window, s, _NEG_INF)
         # mask key padding (sk not multiple of block_k)
         if sk % block_k:
             s = jnp.where(k_pos < sk, s, _NEG_INF)
@@ -164,9 +173,10 @@ def _pad_rows(x, rows):
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "interpret",
-                                             "p_drop"))
+                                             "p_drop", "window"))
 def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
-                      interpret=False, kv_lens=None, p_drop=0.0, seed=None):
+                      interpret=False, kv_lens=None, p_drop=0.0, seed=None,
+                      window=None):
     """q [bh, sq, d], k/v [bh_kv, sk, d] → o [bh, sq, d].
 
     GQA: when bh_kv < bh, ``rep = bh // bh_kv`` query heads read the SAME
@@ -196,7 +206,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
     varlen = kv_lens is not None
 
     kernel = functools.partial(_fwd_kernel, causal, scale, bq, bk, sq, sk,
-                               varlen, p_drop)
+                               varlen, p_drop, window)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, bk, d), lambda b, i, j: (b // rep, j, 0)),
@@ -237,7 +247,7 @@ def _flash_fwd_pallas(q, k, v, causal, scale, block_q, block_k,
 
 
 def _reference_attention(q, k, v, causal, scale, kv_lens=None, p_drop=0.0,
-                         seed=None):
+                         seed=None, window=None):
     """jnp reference — also the VJP path (rematerialized). GQA-aware:
     q [bh, sq, d] with k/v [bh_kv, sk, d]; grouped einsum, no kv copy.
     ``kv_lens`` [bh]: varlen key bound per row (finite fill — empty
@@ -253,6 +263,9 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None, p_drop=0.0,
         qpos = jnp.arange(sq)[:, None]
         kpos = jnp.arange(sk)[None, :]
         s = jnp.where(kpos <= qpos, s, _NEG_INF)
+    if window is not None:
+        s = jnp.where(jnp.arange(sk)[None, :]
+                      > jnp.arange(sq)[:, None] - window, s, _NEG_INF)
     if kv_lens is not None:
         ok = (jnp.arange(sk)[None, None, None, :]
               < kv_lens.reshape(bh_kv, rep)[:, :, None, None])  # [g,r,1,sk]
@@ -517,32 +530,38 @@ def _blocks(kind, q, k):
                                       q.shape[2])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, causal, scale):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, scale, window=None):
     if _use_pallas():
         bq, bk = _blocks("fwd", q, k)
         return _flash_fwd_pallas(q, k, v, causal, scale, bq, bk,
-                                 pallas_config.interpret())[0]
-    return _reference_attention(q, k, v, causal, scale)
+                                 pallas_config.interpret(), window=window)[0]
+    return _reference_attention(q, k, v, causal, scale, window=window)
 
 
-def _flash_fwd(q, k, v, causal, scale):
+def _flash_fwd(q, k, v, causal, scale, window):
     if _use_pallas():
         bq, bk = _blocks("fwd", q, k)
         o, lse = _flash_fwd_pallas(q, k, v, causal, scale, bq, bk,
-                                   pallas_config.interpret())
+                                   pallas_config.interpret(), window=window)
         return o, (q, k, v, o, lse)
-    return _reference_attention(q, k, v, causal, scale), (q, k, v, None, None)
+    return (_reference_attention(q, k, v, causal, scale, window=window),
+            (q, k, v, None, None))
 
 
-def _flash_bwd(causal, scale, res, g):
+def _flash_bwd(causal, scale, window, res, g):
     q, k, v, o, lse = res
     if lse is not None:
+        if window is not None:
+            raise NotImplementedError(
+                "the flash attention backward kernels do not know a "
+                "sliding window: the windowed call is forward only")
         bq, bk = _blocks("bwd", q, k)
         return _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, bq, bk,
                                  pallas_config.interpret())
     _, vjp = jax.vjp(
-        lambda q, k, v: _reference_attention(q, k, v, causal, scale), q, k, v)
+        lambda q, k, v: _reference_attention(q, k, v, causal, scale,
+                                             window=window), q, k, v)
     return vjp(g)
 
 
@@ -653,7 +672,8 @@ def _dropout_seed(dropout_key):
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, kv_lens=None,
                     dropout_p: float = 0.0, dropout_key=None,
-                    deterministic: bool = False):
+                    deterministic: bool = False,
+                    window: Optional[int] = None):
     """Fused attention on [b, s, h, d] (heads may differ for k/v — GQA).
 
     Returns [b, sq, h, d]; fp32 softmax internally, output in q's dtype.
@@ -667,6 +687,10 @@ def flash_attention(q, k, v, causal: bool = False,
     dropout, ref apex/contrib/fmha/fmha.py:35 p_dropout) — requires
     ``dropout_key`` (jax PRNG key) unless ``deterministic`` is set, in
     which case dropout is a no-op (eval mode).
+
+    ``window`` (causal self-attention only, no ``kv_lens``, no dropout):
+    query ``i`` reads keys ``i - window < j <= i``, itself among them.
+    Forward only under the Pallas kernels.
     """
     b, sq, h, d = q.shape
     h_kv = k.shape[2]
@@ -680,6 +704,13 @@ def flash_attention(q, k, v, causal: bool = False,
             f"q_lens/kv_lens, which this kernel does not support")
     scale = scale if scale is not None else 1.0 / d ** 0.5
     p_drop = 0.0 if deterministic else float(dropout_p)
+    if window is not None:
+        if not causal or kv_lens is not None or p_drop or sq != sk:
+            raise ValueError("a sliding window goes with causal "
+                             "self-attention alone: no kv_lens, no dropout")
+        if window < 1:
+            raise ValueError(f"window must be at least 1, got {window}")
+        window = int(window)
     if p_drop and dropout_key is None:
         raise ValueError(
             "dropout_p > 0 in training needs dropout_key (jax PRNG key); "
@@ -695,7 +726,7 @@ def flash_attention(q, k, v, causal: bool = False,
             o = _flash_dropout(qt, kt, vt, _dropout_seed(dropout_key),
                                causal, float(scale), p_drop)
         else:
-            o = _flash(qt, kt, vt, causal, float(scale))
+            o = _flash(qt, kt, vt, causal, float(scale), window)
         return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     kv_lens = jnp.asarray(kv_lens, jnp.int32)
     seed = (_dropout_seed(dropout_key) if p_drop

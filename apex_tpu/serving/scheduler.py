@@ -31,6 +31,17 @@ page buffers (prefill returns that layer's K and V, decode reads and
 writes it where it lies). The programs, the cache and the dump all count
 cache layers.
 
+A stack of more than one layer kind goes through the same two shapes. A
+dropless expert model (``cfg.dropless``: a dense lead, then expert layers
+that route over all experts and compute those held here) is two scans,
+one a stack (``llama.stacks``), each step ``llama.routed_block``; both
+programs hand back, in the array the host already fetches, how many
+assignments fell on held experts and how many held experts were touched.
+Where some layers are sliding ones the decode step makes a second list of
+live pages, bounded below by each row's window, and a layer walks the list
+of its kind; the kind is data of the scan step. Pages below every sliding
+layer's window stay allocated: one table and one pool for all layers.
+
 Admission is FCFS: a request enters when a slot is free AND its whole
 page worst case (padded prompt + max_new_tokens) can be allocated, so
 an admitted request can never deadlock on pages mid-decode. Eviction
@@ -70,6 +81,9 @@ WEIGHT_MODES = ("native", "bf16", "fp8")
 # From shapes, not from a caller.
 LIST_CHUNK = 64
 _MASKED = -1e30     # finite where a softmax has -inf: a row of no page gives 0
+# What a dropless expert model's programs append to the tokens they return,
+# each summed over the expert layers: the span fields they are recorded as
+EXPERT_COUNTS = ("expert_tokens", "experts_hit")
 
 
 @dataclasses.dataclass
@@ -162,10 +176,14 @@ def pages_read(pages_live: int, table_slots: int) -> int:
     return n_chunks * chunk
 
 
-def _live_page_list(tables, pos, active, page_size: int, trash: int):
+def _live_page_list(tables, pos, active, page_size: int, trash: int,
+                    start=None):
     """The decode step's list of live pages, made once a step from what the
     step is given: the tables' entries that hold a position some active row
-    attends to (row ``r``'s first ``pos[r] // page_size + 1``), row-major,
+    attends to (row ``r``'s first ``pos[r] // page_size + 1``; with
+    ``start``, the first position each row reads in a sliding layer, those
+    from column ``start[r] // page_size`` on, and the ``keys`` of that
+    first page cut at ``start[r]``), row-major,
     ahead of all others, padded to whole chunks and cut into them (``[K,
     C]``). Per entry of the list: its row, its slot in the tables (``row *
     width + column``; ``tables.size``, one past them, for an entry past the
@@ -174,8 +192,11 @@ def _live_page_list(tables, pos, active, page_size: int, trash: int):
     (``[K, C, page_size]``); and the number of chunks that hold a live
     entry, a traced scalar."""
     w = tables.shape[1]
-    live = (jnp.arange(w) < jnp.where(active, pos // page_size + 1,
-                                      0)[:, None]).reshape(-1)
+    live = jnp.arange(w) < jnp.where(active, pos // page_size + 1,
+                                     0)[:, None]
+    if start is not None:
+        live = live & (jnp.arange(w) >= (start // page_size)[:, None])
+    live = live.reshape(-1)
     n_live = jnp.sum(live, dtype=jnp.int32)
     chunk, n_chunks = _chunks(n_live, tables.size)
     order = jnp.pad(jnp.argsort(~live, stable=True).astype(jnp.int32),
@@ -187,6 +208,9 @@ def _live_page_list(tables, pos, active, page_size: int, trash: int):
     page = jnp.where(valid, tables.reshape(-1)[order], trash)
     keys = valid[:, None] & (
         first[:, None] + jnp.arange(page_size) <= pos[row][:, None])
+    if start is not None:
+        keys = keys & (first[:, None] + jnp.arange(page_size)
+                       >= start[row][:, None])
     return tuple(a.reshape(-1, chunk, *a.shape[1:])
                  for a in (row, slot, page, keys)) + (n_chunks,)
 
@@ -230,6 +254,19 @@ def _attend_live_pages(q, kp, vp, at, live_pages, table_slots: int):
     return (o / jnp.maximum(l, 1e-30)[..., None]).reshape(b, 1, nq * d)
 
 
+def _refuse_unserved(cfg, weight_mode: str = "native") -> None:
+    """The models no serving program is built for, by name."""
+    if cfg.moe and not cfg.dropless:
+        raise NotImplementedError(
+            "serving runs dropless expert layers (moe_capacity_factor "
+            "None): the capacity-dropped GShard/Mixtral form is training's "
+            "and is not served")
+    if cfg.dropless and _normalize_weight_mode(weight_mode) == "fp8":
+        raise NotImplementedError(
+            "fp8 weights for a dropless expert model: no static scales "
+            "are made for stacked expert weights")
+
+
 def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     """The ONE jit-compiled decode step (jit + donation is the
     caller's: ``jax.jit(step, donate_argnums=(2, 3))``).
@@ -241,7 +278,10 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     their k/v to the trash page and pass their token through, so the
     step is total over any batch composition: one program, whose only
     data-dependent control is the trip count of the attention's loop.
-    Greedy (argmax) by design — the bit-reproducibility contract.
+    Greedy (argmax) by design — the bit-reproducibility contract. For a
+    dropless expert model ``next_tokens`` is two entries longer: the
+    assignments that fell on held experts and the held experts touched,
+    each summed over the expert layers (:data:`EXPERT_COUNTS`).
 
     Arguments 2 and 3 are the whole cache and come back updated. The
     layer scan carries them beside the residual stream and scans over
@@ -259,10 +299,7 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     of ``LIST_CHUNK`` pages (:func:`_attend_live_pages`): its reads and
     its arithmetic are the live pages', whatever the tables could hold.
     """
-    if cfg.moe:
-        raise NotImplementedError(
-            "serving decode is dense-only; MoE routing needs a paged "
-            "expert-gather step (llama dense configs only for now)")
+    _refuse_unserved(cfg, weight_mode)
     mm = _products(weight_mode)
 
     def _decode_step(params, scales, k_pages, v_pages, tokens, tables,
@@ -275,6 +312,14 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
         page_idx = jnp.where(active, page_idx, trash)
         off = pos % page_size
         live_pages = _live_page_list(tables, pos, active, page_size, trash)
+        experts = _llama.expert_stack(params) if cfg.dropless else None
+        if cfg.windowed:
+            # the sliding layers' list beside the full layers', made once;
+            # a layer indexes the pair by its kind
+            live_pages = jax.tree_util.tree_map(
+                lambda *a: jnp.stack(a), live_pages, _live_page_list(
+                    tables, pos, active, page_size, trash,
+                    _llama.sliding_start(cfg, pos)))
 
         # the buffers ride the carry as [CL * (P + 1), page, nkv, d] (a
         # bitcast): step i touches rows i * (P + 1) + page of them, so no
@@ -286,24 +331,37 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
             at = i * stride
             rows = at + page_idx
 
+            mine = live_pages
+            if cfg.windowed:
+                kind = lp["sliding"].astype(jnp.int32)
+                mine = jax.tree_util.tree_map(lambda a: a[kind], live_pages)
+
             def attend(q, k, v):
                 kp1 = kp.at[rows, off].set(k[:, 0].astype(kp.dtype))
                 vp1 = vp.at[rows, off].set(v[:, 0].astype(vp.dtype))
-                o = _attend_live_pages(q, kp1, vp1, at, live_pages,
-                                       tables.size)
+                o = _attend_live_pages(q, kp1, vp1, at, mine, tables.size)
                 return o.astype(q.dtype), (kp1, vp1)
 
+            if cfg.dropless:
+                h, (kp, vp), counts = _llama.routed_block(
+                    h, lp, experts, cfg, pos[:, None], attend, mm,
+                    active[:, None])
+                return (h, kp, vp), counts
             h, (kp, vp) = _llama.block(h, lp, cfg, pos[:, None], attend, mm)
             return (h, kp, vp), None
 
-        (x, k_pages, v_pages), _ = _llama.scan_passes(
+        (x, k_pages, v_pages), counts = _llama.scan_passes(
             (x, k_pages.reshape(flat), v_pages.reshape(flat)), params, cfg,
-            body, {**params["layers"], "scales": scales},
+            body, _llama.stacks(params, cfg, scales=scales),
             jnp.arange(shape[0]))
         k_pages, v_pages = k_pages.reshape(shape), v_pages.reshape(shape)
         logits = _gen._logits(params, x, cfg)[:, 0]
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return jnp.where(active, nxt, tokens), k_pages, v_pages
+        nxt = jnp.where(active, nxt, tokens)
+        if cfg.dropless:
+            # the counts ride the array the host fetches anyway
+            nxt = jnp.concatenate([nxt, jnp.sum(counts, axis=0)])
+        return nxt, k_pages, v_pages
 
     return _decode_step
 
@@ -314,29 +372,42 @@ def build_prefill(cfg, bucket_len: int, weight_mode: str = "native"):
     (first_token [1], ks [L, S, nkv, d], vs [L, S, nkv, d])``, ``L``
     being ``cfg.cache_layers``.
 
+    For a dropless expert model ``first_token`` is two entries longer,
+    as the decode step's array is (:data:`EXPERT_COUNTS`).
+
     Causal flash attention means the pad suffix never contaminates
     real positions; the pad k/v land in the request's pages but decode
     overwrites index ``p + t`` before ever unmasking it. The jit is
     named per bucket so prefill compiles never count against the
     decode step's zero-retrace guard.
     """
-    if cfg.moe:
-        raise NotImplementedError("serving prefill is dense-only")
+    _refuse_unserved(cfg, weight_mode)
     mm = _products(weight_mode)
 
     def prefill(params, scales, prompt, true_len):
         b, s = prompt.shape
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
         x = _llama.embed(params, prompt, cfg, tp_axis=None)
-        x, (ks, vs) = _llama.scan_passes(
-            x, params, cfg,
-            lambda h, lp, _: _llama.block(h, lp, cfg, positions,
-                                          _gen._flash_keeping_kv, mm),
-            {**params["layers"], "scales": scales})
+        experts = _llama.expert_stack(params) if cfg.dropless else None
+
+        def layer(h, lp, _):
+            attend = _gen._prefill_attend(lp, cfg)
+            if cfg.dropless:        # the pad suffix routes nowhere
+                h, kept, counts = _llama.routed_block(
+                    h, lp, experts, cfg, positions, attend, mm,
+                    positions < true_len)
+                return h, (kept, counts)
+            return _llama.block(h, lp, cfg, positions, attend, mm)
+
+        x, kept = _llama.scan_passes(
+            x, params, cfg, layer, _llama.stacks(params, cfg, scales=scales))
+        (ks, vs), counts = kept if cfg.dropless else (kept, None)
         x_last = jax.lax.dynamic_slice_in_dim(x, true_len - 1, 1,
                                               axis=1)
         logits = _gen._logits(params, x_last, cfg)[:, 0]
         first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if cfg.dropless:
+            first = jnp.concatenate([first, jnp.sum(counts, axis=0)])
         return (first, ks[:, 0].astype(cfg.dtype),
                 vs[:, 0].astype(cfg.dtype))
 
@@ -358,8 +429,7 @@ class ContinuousBatchScheduler:
                  max_prompt_len: int = 64, max_new_cap: int = 32,
                  weight_mode: str = "native",
                  eos_id: Optional[int] = None):
-        if cfg.moe:
-            raise NotImplementedError("serving is dense-only")
+        _refuse_unserved(cfg, weight_mode)
         if max_batch < 1 or page_size < 1:
             raise ValueError("max_batch and page_size must be >= 1")
         self.params = params
@@ -383,6 +453,9 @@ class ContinuousBatchScheduler:
         # the layers of K and V a page holds
         self._depth = {"layer_passes": cfg.num_passes * cfg.num_layers,
                        "cache_layers": self.cache.layers}
+        if cfg.dropless:
+            self._depth.update(expert_layers=cfg.expert_layers,
+                               experts_held=cfg.held[1])
         self.queue: "collections.deque[Request]" = collections.deque()
         self.slots: List[Optional[Request]] = [None] * self.max_batch
         trash = self.cache.trash_page
@@ -498,7 +571,10 @@ class ContinuousBatchScheduler:
                            pages=n_prompt, cache_layers=self.cache.layers):
                 self.cache.write_prompt(pages[:n_prompt], ks, vs)
             with host_span("serving/first_token_fetch", rid=req.rid):
-                t0 = int(np.asarray(first)[0])
+                first = np.asarray(first)
+                t0 = int(first[0])
+            if self.cfg.dropless:
+                get_tracer().annotate(expert_tokens=int(first[1]))
             req.tokens = [t0]
             req.first_token_s = time.monotonic()
             if self._is_finished(req, t0):
@@ -518,11 +594,15 @@ class ContinuousBatchScheduler:
 
     # ---------------------------------------------------------- decode
 
-    def pages_live(self) -> int:
+    def pages_live(self, window: Optional[int] = None) -> int:
         """Pages the next decode step reads that hold a position some
         active row attends to: each row's ``ceil((pos + 1) / page_size)``,
-        from the host mirrors."""
-        return int(np.sum(self._pos[self._active] // self.page_size + 1))
+        from the host mirrors; with ``window``, those a sliding layer
+        reads: from the page of ``pos - window + 1`` on."""
+        pos = self._pos[self._active]
+        start = 0 if window is None else np.maximum(pos - window + 1, 0)
+        return int(np.sum(pos // self.page_size
+                          - start // self.page_size + 1))
 
     def step_decode(self) -> List[Request]:
         """One packed decode step; returns requests finished by it."""
@@ -530,10 +610,20 @@ class ContinuousBatchScheduler:
             return []
         # pages_gathered: what the step reads in every cache layer
         live = self.pages_live()
+        fields = dict(self._depth)
+        if self.cfg.windowed:
+            # what the sliding layers read, beside what the full ones do
+            near = self.pages_live(self.cfg.sliding_window)
+            seen = self._pos[self._active] + 1
+            fields.update(
+                pages_live_window=near,
+                pages_gathered_window=pages_read(near, self._tables.size),
+                positions=int(seen.sum()), positions_window=int(
+                    np.minimum(seen, self.cfg.sliding_window).sum()))
         with host_span("serving/decode", rows=self.num_active(),
                        pages_live=live,
                        pages_gathered=pages_read(live, self._tables.size),
-                       **self._depth):
+                       **fields):
             with host_span("serving/decode_upload"):
                 nxt, self.cache.k_pages, self.cache.v_pages = self._decode(
                     self.params, self._scales,
@@ -550,6 +640,9 @@ class ContinuousBatchScheduler:
                         "compile: the zero-retrace guard is blind")
             with host_span("serving/decode_fetch"):
                 nxt = np.asarray(nxt)
+            if self.cfg.dropless:
+                get_tracer().annotate(**dict(zip(
+                    EXPERT_COUNTS, map(int, nxt[self.max_batch:]))))
             finished = []
             with host_span("serving/decode_bookkeep"):
                 for slot, req in enumerate(self.slots):

@@ -21,6 +21,12 @@ C = per-expert capacity):
     x [T, h] --dispatch--> [E, C, h] --all_to_all--> [E_local, n*C, h]
       --expert mlp--> [E_local, n*C, h] --all_to_all--> [E, C, h]
       --combine--> [T, h]
+
+That is the layer as it is trained. :func:`dropless_experts` is the layer
+as it is deployed: token choice with no capacity, so no token is ever
+dropped; a chip that is told which of the experts it holds routes over all
+of them and computes its own experts' part of the result, by grouped matrix
+products over the assignments sorted by expert.
 """
 
 from __future__ import annotations
@@ -246,3 +252,91 @@ def moe_mlp(params, x, cfg: MoEConfig, ep_axis: Optional[str] = EXPERT_AXIS,
         expert_fn, {"wi": params["wi"], "wo": params["wo"]}, x,
         params["router"], cfg, ep_axis=ep_axis, router_key=router_key,
         with_stats=with_stats)
+
+
+# ------------------------------------------------- the layer as deployed
+
+
+def route(x, router, bias=None, *, top_k: int, score: str = "softmax",
+          route_norm: bool = True, route_scale: float = 1.0):
+    """Token-choice routing of ``x [T, h]`` over ALL ``E`` experts of
+    ``router [h, E]``: ``(weights [T, k] float32, experts [T, k] int32)``.
+
+    Scores are the softmax or the sigmoid of the float32 logits. ``bias``
+    ``[E]`` enters the selection only: the ``top_k`` are taken of ``score +
+    bias``, the weights are the scores themselves. ``route_norm`` divides a
+    token's weights by their sum; ``route_scale`` multiplies them. Softmax
+    with ``route_norm`` and ``k > 1`` is Mixtral's gate
+    (``generate._moe_router_weights``)."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"router score must be softmax or sigmoid, "
+                         f"got {score!r}")
+    with jax.named_scope("moe/route"):
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            router.astype(jnp.float32))
+        scores = (jax.nn.sigmoid(logits) if score == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        chosen = scores if bias is None else scores + bias.astype(
+            jnp.float32)
+        _, idx = jax.lax.top_k(chosen, top_k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        if route_norm:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return w * route_scale, idx.astype(jnp.int32)
+
+
+def dropless_experts(x, weights, idx, experts, held=None, valid=None, at=0):
+    """The routed part of an expert layer for the experts held here:
+    ``sum_k weights[t, k] * Expert_{idx[t, k]}(x[t])`` over the assignments
+    that fall on experts ``first .. first + count - 1`` (``held = (first,
+    count)``; all of them without it). ``experts`` holds the SwiGLU weights
+    of exactly those, stacked: ``wg``, ``wu`` ``[count, h, f]``, ``wd``
+    ``[count, f, h]``. What the other experts would add is left out: on a
+    chip of an expert-parallel deployment it is another chip's part, and
+    nothing here stands in for that chip or for the exchange with it.
+
+    ``experts`` may hold the experts of several layers end to end, ``[G, h,
+    f]`` with this layer's ``count`` from group ``at`` on (``at`` may be
+    traced: a scan step's own). The products then run over all ``G`` groups,
+    every group of another layer empty: a layer scan reads its experts where
+    they lie, with no ``[count, h, f]`` slice cut out of the stack a step.
+
+    No capacity and no drop, at static shapes: the ``T * k`` assignments
+    are sorted by expert, those to experts not held last; each of the three
+    products is one ``jax.lax.ragged_dot`` over the sorted rows, grouped by
+    the held experts' counts; the rows are put back in order, weighted and
+    summed a token (in float32, slot by slot: a token's result is made of
+    its own rows alone). ``valid [T]`` leaves a token's assignments out (a
+    padded position, an empty batch row).
+
+    Returns ``(y [T, h], counts)``; ``counts`` is int32 ``[2]``: the
+    assignments that fell on held experts, and the held experts that got at
+    least one."""
+    t, k = idx.shape
+    groups = experts["wg"].shape[0]
+    first, count = (0, groups) if held is None else held
+    if count > groups:
+        raise ValueError(f"{count} experts held, weights of {groups}")
+    with jax.named_scope("moe/experts"):
+        local = idx.reshape(-1) - first
+        mine = (local >= 0) & (local < count)
+        if valid is not None:
+            mine = mine & jnp.repeat(valid, k)
+        key = jnp.where(mine, at + local, groups)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        sizes = jnp.zeros(groups + 1, jnp.int32).at[key].add(1)[:groups]
+        xs = x[order // k]
+        wg, wu, wd = (experts[n].astype(x.dtype) for n in ("wg", "wu", "wd"))
+        g = jax.lax.ragged_dot(xs, wg, sizes)
+        u = jax.lax.ragged_dot(xs, wu, sizes)
+        ys = jax.lax.ragged_dot(jax.nn.silu(g) * u, wd, sizes)
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32))
+        # a row past the groups belongs to no product: whatever it holds,
+        # it counts for nothing
+        y = jnp.where(mine[:, None], ys[back].astype(jnp.float32), 0.0)
+        y = jnp.sum(y.reshape(t, k, -1) * jnp.where(
+            mine.reshape(t, k), weights, 0.0)[..., None], axis=1)
+        counts = jnp.stack([jnp.sum(mine, dtype=jnp.int32),
+                            jnp.sum(sizes > 0, dtype=jnp.int32)])
+        return y.astype(x.dtype), counts

@@ -20,6 +20,13 @@ supports (weights random, from a seed), and checks what comes out:
              Ouro-2.6B's widths (eight cache layers behind two layers of
              weights), four requests, checked on logits against the plain
              reference ``perfbench/references/ouro_looped.py``;
+- *afmoe*    ``ServingEngine`` on five layers at Trinity-Large-Preview's
+             widths (a dense lead, four dropless expert layers holding 8 of
+             256 sigmoid-routed experts beside a shared one; four sliding
+             layers with a window of 512 and one full), prompts of 1,024 so
+             that the windowed flash forward and a prefill cut by the window
+             run, checked on logits against
+             ``perfbench/references/afmoe.py``;
 - *mesh*     (4+ chips) the same GPT-2 step on a dp=2 x tp=2 mesh, and a
              dp=4 DDP step through ``sync_autodiff_gradients``.
 
@@ -592,7 +599,6 @@ def phase_serve_looped(cfg_dict=None, mix=LOOPED_MIX, max_batch=4,
     """A stack run several times over shared weights, through the engine,
     against the plain reference's full forward: logits, not tokens."""
     from perfbench.references import ouro_looped as ref
-    from perfbench.references.common import seed_words
 
     cfg_dict = dict(cfg_dict or LOOPED)
     cfg = llama.LlamaConfig(
@@ -604,20 +610,36 @@ def phase_serve_looped(cfg_dict=None, mix=LOOPED_MIX, max_batch=4,
         rope_theta=cfg_dict["rope_theta"], rms_eps=cfg_dict["rms_norm_eps"],
         dtype=jnp.dtype(cfg_dict["torch_dtype"]),
         num_passes=cfg_dict["total_ut_steps"], sandwich_norm=True)
+
+    def describe(engine):
+        cache = engine.scheduler.cache
+        if cache.layers != cfg.num_passes * cfg.num_layers:
+            raise AssertionError(f"the cache has {cache.layers} layers, not "
+                                 f"{cfg.num_passes} x {cfg.num_layers}")
+        say(f"  {cfg.num_layers} layers x {cfg.num_passes} passes: k_pages "
+            f"{tuple(cache.k_pages.shape)} {cache.k_pages.dtype}; page "
+            f"budget {engine.page_budget}")
+
+    return _serve_against_reference(ref, cfg_dict, cfg, mix, max_batch,
+                                    page_size, gap_limit, 2 ** 31 + 29, 64,
+                                    describe)
+
+
+def _serve_against_reference(ref, cfg_dict, cfg, mix, max_batch, page_size,
+                             gap_limit, seed, pad_to, describe):
+    """Seeded weights from the reference's ``init``, the ``mix`` of (prompt
+    length, new tokens) through a ``ServingEngine``, and every served token's
+    float32 reference logit against the reference's best at its position."""
+    from perfbench.references.common import seed_words
+
     params = jax.jit(lambda lo, hi: ref.init(lo, hi, cfg_dict))(
-        *seed_words(2 ** 31 + 29))
+        *seed_words(seed))
     max_prompt = max(p for p, _ in mix)
     max_new = max(n for _, n in mix)
     engine = ServingEngine(
         params, cfg, page_size=page_size, max_batch=max_batch, num_pages=None,
         max_prompt_len=max_prompt, max_new_cap=max_new)
-    cache = engine.scheduler.cache
-    if cache.layers != cfg.num_passes * cfg.num_layers:
-        raise AssertionError(f"the cache has {cache.layers} layers, not "
-                             f"{cfg.num_passes} x {cfg.num_layers}")
-    say(f"  {cfg.num_layers} layers x {cfg.num_passes} passes: k_pages "
-        f"{tuple(cache.k_pages.shape)} {cache.k_pages.dtype}; page budget "
-        f"{engine.page_budget}")
+    describe(engine)
     rng = np.random.default_rng(0)
     prompts = {}
     for p_len, new in mix:
@@ -625,7 +647,7 @@ def phase_serve_looped(cfg_dict=None, mix=LOOPED_MIX, max_batch=4,
         prompts[engine.submit(prompt, new)] = (prompt, new)
     results = engine.run()      # raises if the decode step retraced
 
-    length = -(-(max_prompt + max_new) // 64) * 64
+    length = -(-(max_prompt + max_new) // pad_to) * pad_to
 
     @jax.jit
     def gaps(params, tokens, rows, served):
@@ -653,6 +675,55 @@ def phase_serve_looped(cfg_dict=None, mix=LOOPED_MIX, max_batch=4,
             f"served tokens lie {widest:.4f} below the reference's best "
             f"logit, over the limit {gap_limit}")
     return {"widest_gap": widest}
+
+
+# Five layers at Trinity-Large-Preview's widths (perfbench/configs/
+# trinity_large_ep8_d5.json) with 8 of the 256 experts held and a window of
+# 512: prompts of 1,024 put the windowed flash forward and a prefill cut by
+# the window on the chip, which the benchmark's cell (window 4,096, prompts
+# up to 4,096) never does; the short prompt decodes across the window's edge.
+AFMOE = {"hidden_size": 3072, "intermediate_size": 12288,
+         "moe_intermediate_size": 3072, "num_attention_heads": 48,
+         "num_key_value_heads": 8, "head_dim": 128, "num_hidden_layers": 5,
+         "num_dense_layers": 1, "layer_types": ["sliding_attention"] * 4
+         + ["full_attention"], "sliding_window": 512, "num_experts": 8,
+         "experts_held": {"first": 0, "count": 8, "of": 256},
+         "num_experts_per_tok": 4, "num_shared_experts": 1,
+         "score_func": "sigmoid", "route_norm": True, "route_scale": 2.448,
+         "mup_enabled": True, "vocab_size": 25024, "rms_norm_eps": 1e-5,
+         "rope_theta": 10000, "max_position_embeddings": 4096,
+         "tie_word_embeddings": False, "torch_dtype": "bfloat16"}
+AFMOE_MIX = ((1024, 24), (1024, 16), (480, 48), (768, 8))
+# bf16 through five layers reads like the 16-layer Mistral cells; a token
+# whose fourth and fifth experts lie a rounding apart is routed otherwise
+# than the float32 reference routes it and moves a logit by an expert's
+# whole contribution, so the limit is the benchmark cell's (PERF.md, "How
+# correct is decided"); a model with the gate, the window or the shared
+# expert left out reads several times over it (tests/run_models).
+AFMOE_GAP = 0.5
+
+
+def phase_serve_afmoe(cfg_dict=None, mix=AFMOE_MIX, max_batch=4,
+                      page_size=128, gap_limit=AFMOE_GAP) -> dict:
+    """A dense lead and dropless expert layers, sliding and full attention
+    in one stack, through the engine, against the plain reference's full
+    forward: logits, not tokens."""
+    from perfbench.references import afmoe as ref
+    from perfbench.runners import serve_afmoe
+
+    cfg_dict = dict(cfg_dict or AFMOE)
+    cfg = serve_afmoe.model_config(cfg_dict)
+
+    def describe(engine):
+        cache = engine.scheduler.cache
+        say(f"  {cfg.num_dense_layers} dense + {cfg.expert_layers} expert "
+            f"layers, experts {cfg.held} of {cfg.num_experts}, window "
+            f"{cfg.sliding_window}: k_pages {tuple(cache.k_pages.shape)} "
+            f"{cache.k_pages.dtype}; page budget {engine.page_budget}")
+
+    return _serve_against_reference(ref, cfg_dict, cfg, mix, max_batch,
+                                    page_size, gap_limit, 2 ** 31 + 33,
+                                    ref.QUERY_BLOCK, describe)
 
 
 # --------------------------------------------------------------------- mesh
@@ -824,6 +895,7 @@ def main() -> int:
     train = run("train", phase_train)
     run("serve", phase_serve)
     run("looped", phase_serve_looped)
+    run("afmoe", phase_serve_afmoe)
     if device["count"] >= 4:
         run("mesh", phase_mesh, train["first_loss"])
     else:

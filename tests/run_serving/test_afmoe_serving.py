@@ -1,0 +1,306 @@
+"""A model with dropless experts and sliding-window layers through the
+scheduler and the paged cache (ISSUE 33): prefill, page write and paged decode
+across the window's edge, rows on both sides of the edge in one batch, judged
+as the benchmark judges its cell, by the widest gap of a served token's logit
+below the best of the plain reference (`perfbench/references/afmoe.py`) on the
+same seeded float32 weights; the counts in the spans against a count made on
+the host from the reference's own routing; the second list of live pages; a
+row's bits in any batch; planted faults; and what serving refuses."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu import observability as obs
+from apex_tpu.models import llama
+from apex_tpu.observability import SpanTracer, set_tracer
+from apex_tpu.serving import ServingEngine
+from apex_tpu.serving import scheduler as sched
+from apex_tpu.serving.scheduler import ContinuousBatchScheduler, Request
+from perfbench.references import afmoe as ref
+from perfbench.references.common import seed_words
+from perfbench.runners import serve_afmoe
+
+TINY = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+            head_dim=32, intermediate_size=128, moe_intermediate_size=48,
+            num_hidden_layers=5, num_dense_layers=1,
+            layer_types=["sliding_attention"] * 4 + ["full_attention"],
+            num_experts=8, experts_held={"first": 8, "count": 8, "of": 32},
+            num_experts_per_tok=4, num_shared_experts=1, rms_norm_eps=1e-5,
+            rope_theta=10000, route_norm=True, route_scale=2.448,
+            score_func="sigmoid", sliding_window=12, mup_enabled=True,
+            tie_word_embeddings=False, vocab_size=96, torch_dtype="float32",
+            max_position_embeddings=256)
+CFG = serve_afmoe.model_config(TINY)
+WINDOW, PAGE = 12, 4
+LIMIT = 0.01                      # the tiny cells' `logit_gap_limit`
+# (prompt, new): a row that stays under the window (3 + 6 positions), rows
+# that cross its edge while they decode (8 + 10, 11 + 9), rows whose prefill
+# is already cut by it (20, 16), all sharing batches of three
+JOBS = ((3, 6), (8, 10), (20, 10), (11, 9), (5, 12), (16, 12), (8, 4))
+
+
+@pytest.fixture(scope="module")
+def params():
+    return ref.init(*seed_words(2 ** 31 + 33), TINY)
+
+
+def jobs(seed=0):
+    rng = np.random.default_rng(seed)
+    return [(rng.integers(0, 96, size=p).astype(np.int32), new)
+            for p, new in JOBS]
+
+
+def engine_for(params, cfg=CFG, **kw):
+    kw.setdefault("registry", obs.MetricRegistry())
+    return ServingEngine(params, cfg, page_size=PAGE, max_batch=3,
+                         num_pages=27, max_prompt_len=20, max_new_cap=12,
+                         **kw)
+
+
+def serve_all(engine, work):
+    for prompt, new in work:
+        engine.submit(prompt, new)
+    return engine.run()
+
+
+def sequence(prompt, served):
+    """A request's whole sequence padded to the reference's query blocks."""
+    seq = np.concatenate([prompt, served[:-1]])
+    return seq, jnp.asarray(np.pad(seq, (0, -len(seq) % 8)))
+
+
+def widest_gap(params, work, results):
+    widest = 0.0
+    for rid, (prompt, new) in enumerate(work):
+        served = np.asarray(results[rid]["tokens"], np.int32)
+        assert len(served) == new
+        _, padded = sequence(prompt, served)
+        logits = ref.row_logits(params, padded,
+                                len(prompt) - 1 + jnp.arange(new), TINY)
+        widest = max(widest, float(jnp.max(
+            ref.served_gaps(logits, jnp.asarray(served)))))
+    return widest
+
+
+def test_served_tokens_lie_within_the_limit_of_the_reference(params,
+                                                             monkeypatch):
+    monkeypatch.setattr(ref, "QUERY_BLOCK", 8)
+    work = jobs()
+    engine = engine_for(params)
+    results = serve_all(engine, work)
+    assert engine.scheduler.cache.k_pages.shape == (5, 28, 4, 2, 32)
+    assert engine.scheduler.decode_retraces() == 0
+    assert widest_gap(params, work, results) < LIMIT / 10
+
+
+def without_the_second_list(tables, pos, active, page_size, trash,
+                            start=None):
+    return TRUE_LIST(tables, pos, active, page_size, trash)
+
+
+TRUE_LIST = sched._live_page_list
+
+
+@pytest.mark.parametrize("fault", [
+    "decode_reads_below_the_window", "prefill_not_cut", "every_layer_rotates",
+    "no_shared_expert", "another_share"])
+def test_planted_faults_fail_the_limit(params, fault, monkeypatch):
+    monkeypatch.setattr(ref, "QUERY_BLOCK", 8)
+    cfg = CFG
+    if fault == "decode_reads_below_the_window":
+        monkeypatch.setattr(sched, "_live_page_list", without_the_second_list)
+    elif fault == "prefill_not_cut":
+        monkeypatch.setattr(llama, "causal_attention", lambda q, k, v, lp, c:
+                            llama.flash_attention(q, k, v, causal=True,
+                                                  scale=c.head_dim ** -0.5))
+    elif fault == "every_layer_rotates":
+        cfg = dataclasses.replace(CFG, rope_full_attention=True)
+    elif fault == "no_shared_expert":
+        cfg = dataclasses.replace(CFG, num_shared_experts=0)
+    else:
+        cfg = dataclasses.replace(CFG, experts_held=(16, 8))
+    work = jobs()
+    results = serve_all(engine_for(params, cfg), work)
+    assert widest_gap(params, work, results) > LIMIT
+
+
+def test_the_spans_counts_are_the_hosts(params, monkeypatch):
+    """Every admission's `expert_tokens` and every decode step's
+    `expert_tokens`, `experts_hit`, `pages_live_window` and
+    `positions_window` against a count made here: from the reference's own
+    routing of each request's sequence, and from the rows' positions."""
+    monkeypatch.setattr(ref, "QUERY_BLOCK", 8)
+    tracer = SpanTracer()
+    previous = set_tracer(tracer)
+    try:
+        s = ContinuousBatchScheduler(params, CFG, num_pages=27,
+                                     page_size=PAGE, max_batch=3,
+                                     max_prompt_len=20, max_new_cap=12)
+        work = jobs(1)
+        requests = [Request(rid, prompt, new)
+                    for rid, (prompt, new) in enumerate(work)]
+        for r in requests:
+            s.submit(r)
+        steps = []               # per decode step: [(rid, position)]
+        while s.has_work():
+            s.try_admit()
+            steps.append([(r.rid, int(s._pos[slot]))
+                          for slot, r in enumerate(s.slots) if r is not None])
+            s.step_decode()
+        spans = tracer.completed()
+    finally:
+        set_tracer(previous)
+    held = slice(8, 16)
+    chosen = {}                  # rid -> bool [expert layers, seq, held]
+    for r in requests:
+        got = []
+        ref.hidden(params, sequence(r.prompt, np.asarray(r.tokens))[1], TINY,
+                   chosen=got)
+        chosen[r.rid] = np.stack([np.asarray(c)[:, held] for c in got])
+    admits = {sp.rid: sp.args for sp in spans if sp.name == "serving/admit"}
+    for r in requests:
+        p = len(r.prompt)
+        assert admits[r.rid]["expert_tokens"] == chosen[r.rid][:, :p].sum()
+        assert (admits[r.rid]["expert_layers"],
+                admits[r.rid]["experts_held"]) == (4, 8)
+    decodes = [sp.args for sp in spans if sp.name == "serving/decode"]
+    assert len(decodes) == len([rows for rows in steps if rows])
+    crossed = 0
+    for args, rows in zip(decodes, [rows for rows in steps if rows]):
+        picks = np.stack([chosen[rid][:, pos] for rid, pos in rows])
+        assert args["expert_tokens"] == picks.sum()
+        assert args["experts_hit"] == picks.any(axis=0).sum()
+        pos = np.array([p for _, p in rows])
+        assert args["rows"] == len(rows)
+        assert args["positions"] == (pos + 1).sum()
+        assert args["positions_window"] == np.minimum(pos + 1, WINDOW).sum()
+        assert args["pages_live"] == (pos // PAGE + 1).sum()
+        first = np.maximum(pos - WINDOW + 1, 0)
+        assert args["pages_live_window"] == (pos // PAGE - first // PAGE
+                                             + 1).sum()
+        crossed += bool((pos >= WINDOW).any() and (pos < WINDOW).any())
+    assert crossed > 3           # rows on both sides of the edge in one step
+    assert sum(a["expert_tokens"] for a in decodes) > 0
+
+
+def test_a_dense_model_with_a_window_is_served_as_generate_decodes_it():
+    """The window without the experts: a sliding and a full layer of a dense
+    stack, through the engine's two lists and through `generate`'s contiguous
+    cache: the same greedy tokens, and not those of the model without it."""
+    from apex_tpu.models import generate as gen
+
+    cfg = llama.tiny(layer_types=("sliding_attention", "full_attention"),
+                     sliding_window=6)
+    weights = llama.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(0)
+    work = [(rng.integers(0, cfg.vocab_size, size=p).astype(np.int32), new)
+            for p, new in ((3, 8), (12, 7), (20, 9))]
+    engine = ServingEngine(weights, cfg, page_size=4, max_batch=2,
+                           num_pages=32, max_prompt_len=24, max_new_cap=12,
+                           registry=obs.MetricRegistry())
+    results = serve_all(engine, work)
+    for rid, (prompt, new) in enumerate(work):
+        want = np.asarray(gen.generate(weights, jnp.asarray(prompt)[None],
+                                       cfg, new))[0, len(prompt):]
+        assert results[rid]["tokens"] == [int(t) for t in want]
+    full = dataclasses.replace(cfg, layer_types=(), sliding_window=None)
+    other = np.asarray(gen.generate(weights, jnp.asarray(work[2][0])[None],
+                                    full, 9))[0, 20:]
+    assert results[2]["tokens"] != [int(t) for t in other]
+
+
+ROWS, WIDTH = 5, 6                         # tables [5, 6]: 30 slots
+
+
+@pytest.mark.parametrize("chunk", [4, 7, 64])
+def test_the_second_list_holds_what_a_sliding_layer_reads(chunk, monkeypatch):
+    monkeypatch.setattr(sched, "LIST_CHUNK", chunk)
+    pos = np.array([3, 11, 12, 23, 17], np.int32)
+    active = np.array([1, 1, 1, 1, 0], bool)
+    tables = np.arange(ROWS * WIDTH, dtype=np.int32).reshape(ROWS, WIDTH)
+    start = np.maximum(pos - WINDOW + 1, 0)
+    *entries, n_chunks = map(np.asarray, sched._live_page_list(
+        jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(active), PAGE, 30,
+        jnp.asarray(start)))
+    row, slot, page, keys = (a.reshape(-1, *a.shape[2:]) for a in entries)
+    live = [(r, c) for r in range(ROWS) if active[r]
+            for c in range(start[r] // PAGE, pos[r] // PAGE + 1)]
+    n = len(live)
+    assert n == 1 + 3 + 4 + 3 and int(n_chunks) == -(-n // min(chunk, 30))
+    assert [(r, r * WIDTH + c, tables[r, c]) for r, c in live] == list(
+        zip(row[:n], slot[:n], page[:n]))
+    assert (page[n:] == 30).all() and (slot[n:] == tables.size).all()
+    for e, (r, c) in enumerate(live):
+        assert keys[e].tolist() == [start[r] <= c * PAGE + t <= pos[r]
+                                    for t in range(PAGE)]
+    # every row reads exactly its window's positions
+    for r in range(ROWS):
+        seen = sum(int(keys[e].sum()) for e, (rr, _) in enumerate(live)
+                   if rr == r)
+        assert seen == (min(pos[r] + 1, WINDOW) if active[r] else 0)
+
+
+def one_step(step, params, placed, others_active):
+    """One decode step in which two requests (at positions 9, inside the
+    window, and 21, past it) sit in the table rows `placed`, three other rows
+    beside them active or not: each one's next token and what it wrote of K
+    and V in every cache layer."""
+    shape = (CFG.cache_layers, ROWS * WIDTH + 1, PAGE, 2, 32)
+    k0 = jax.random.normal(jax.random.PRNGKey(1), shape)
+    v0 = jax.random.normal(jax.random.PRNGKey(2), shape)
+    pos = np.array([9, 21, 5, 14, 23], np.int32)
+    tokens = np.array([7, 50, 3, 90, 11], np.int32)
+    tables = np.arange(ROWS * WIDTH, dtype=np.int32).reshape(ROWS, WIDTH)
+    source = np.zeros(ROWS, int)
+    source[list(placed)] = 0, 1
+    source[[at for at in range(ROWS) if at not in placed]] = 2, 3, 4
+    active = np.full(ROWS, others_active)
+    active[list(placed)] = True
+    nxt, k1, v1 = step(params, {}, k0, v0, *(
+        jnp.asarray(a[source]) for a in (tokens, tables, pos)),
+        jnp.asarray(active))
+    wrote = [(slice(None), tables[r, pos[r] // PAGE], pos[r] % PAGE)
+             for r in (0, 1)]
+    return [(int(nxt[at]), np.asarray(k1[w]), np.asarray(v1[w]))
+            for at, w in zip(placed, wrote)], np.asarray(nxt)[ROWS:]
+
+
+def test_a_row_gets_the_same_bits_in_any_batch(params, monkeypatch):
+    """PR 32's property through the grouped products and the two lists: what
+    one compiled step gives a row is made of the row's own query, pages,
+    position and routing, the same to the bit alone in the batch and among
+    other rows, in whichever table row it sits. (On the CPU: a grouped
+    product whose tiles a row shares with other rows is XLA's to keep so on
+    the chip; PERF.md says what was read there.)"""
+    monkeypatch.setattr(sched, "LIST_CHUNK", 4)
+    step = jax.jit(sched.build_decode_step(CFG, PAGE))
+    want, alone = one_step(step, params, (0, 1), False)
+    for placed, others_active in (((0, 1), True), ((4, 2), True),
+                                  ((3, 0), False)):
+        got, counts = one_step(step, params, placed, others_active)
+        for (t0, k0, v0), (t1, k1, v1) in zip(want, got):
+            assert t0 == t1
+            np.testing.assert_array_equal(k0, k1)
+            np.testing.assert_array_equal(v0, v1)
+        # rows that are not active route nowhere
+        assert (counts == alone).all() or others_active
+    assert step._cache_size() == 1
+
+
+def test_what_serving_refuses_it_refuses_by_name(params):
+    mixtral = llama.tiny(num_experts=4)
+    for build in (lambda: sched.build_decode_step(mixtral, 8),
+                  lambda: sched.build_prefill(mixtral, 16),
+                  lambda: ContinuousBatchScheduler(
+                      None, mixtral, num_pages=8)):
+        with pytest.raises(NotImplementedError, match="capacity-dropped"):
+            build()
+    with pytest.raises(NotImplementedError, match="fp8"):
+        sched.build_decode_step(CFG, PAGE, weight_mode="fp8")
+    # a model of this form is served: nothing raises
+    sched.build_decode_step(CFG, PAGE)
+    sched.build_prefill(CFG, 16)

@@ -2,7 +2,7 @@
 changed.
 
 The step is compiled for a v5e that is described and not attached, from
-shapes, at the engine keys of the three serving cells: the page buffers ride
+shapes, at the engine keys of the serving cells: the page buffers ride
 the layer scan's carry and are updated in place, so the program holds them
 once. A scan that took them as `xs` and gave them back as `ys` held a second
 copy of both (2.5 and 3.84 GiB of temporaries) and copied them whole every
@@ -114,6 +114,56 @@ def test_the_compiled_step_holds_the_page_buffers_once(one_chip, config, mix,
         r" while\(.*body=(%?[\w.\-]+)", text)]
     nested = [sum(" while(" in line for line in body) for body in bodies]
     assert sorted(nested) == [0, 1]
+
+
+def test_the_expert_models_step_reads_its_experts_where_they_lie(one_chip):
+    """ISSUE 33's cell: the step of a model with a dense lead and four expert
+    layers holds the page buffers once too, and no layer's experts are cut
+    out of their stack: a scan that took the `[4, 32, 3072, 3072]` weights as
+    its `xs` compiled to a 604 MB dynamic-slice a weight and layer (0.57 GiB
+    of temporaries, 14 GB moved a step). The grouped products are XLA's
+    ragged dot over the whole stack, one a weight."""
+    from perfbench.runners import serve_afmoe
+
+    cfg = serve_afmoe.model_config(read("configs", "trinity_large_ep8_d5"))
+    eng = read("traffic", "longctx_backlog")["engine"]
+    page, rows = eng["page_size"], eng["max_batch"]
+    table = sched.pages_per_request(eng["max_prompt_len"], eng["max_new_cap"],
+                                    page)
+    assert (rows, table, rows * table) == (32, 56, eng["num_pages"])
+    shape = (cfg.cache_layers, eng["num_pages"] + 1, page, cfg.num_kv_heads,
+             cfg.head_dim)
+
+    def struct(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+    assert sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(
+        shapes)) == 4_321_902_848 + 4 * 256
+    params = jax.tree_util.tree_map(lambda a: struct(a.shape, a.dtype),
+                                    shapes)
+    pages = struct(shape, cfg.dtype)
+    step = jax.jit(sched.build_decode_step(cfg, page), donate_argnums=(2, 3))
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = step.lower(
+            params, {}, pages, pages, struct((rows,), jnp.int32),
+            struct((rows, table), jnp.int32), struct((rows,), jnp.int32),
+            struct((rows,), jnp.bool_)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 2 * int(np.prod(shape)) * 2
+    # the slot tables of `[32 x 56]` slots are 44 MB of float32 a layer
+    assert memory.temp_size_in_bytes < 0.1 * 2 ** 30
+    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes
+    assert 8.0 < weights / 2 ** 30 < 8.1
+    text = compiled.as_text()
+    assert len(re.findall(r" custom-call\(.*ragged-dot", text)) >= 3
+    assert not re.findall(
+        r"= bf16\[(?:1,)?32,3072,3072\]\S* (?:dynamic-slice|copy|fusion)\(",
+        text)
 
 
 TINY = {"hidden_size": 64, "intermediate_size": 128,

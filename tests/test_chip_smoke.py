@@ -3,6 +3,7 @@ mode, plus the compile-cache helper's placement policy. The script's own
 entry still refuses a non-TPU backend — that is the point of it; these
 tests keep the phase code from rotting between chip runs."""
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -75,6 +76,30 @@ def test_phase_serve_looped_tiny(interpret, monkeypatch):
         **{**k, "num_passes": k["num_passes"] - 1}))
     with pytest.raises(AssertionError, match="over the limit"):
         chip_smoke.phase_serve_looped(tiny, gap_limit=1e-3, **kw)
+
+
+def test_phase_serve_afmoe_tiny(interpret, monkeypatch):
+    """A dense lead and four expert layers holding 4 of 16 experts, a window
+    of 12 under prompts of 24 and 32 (the windowed flash forward in interpret
+    mode, a prefill cut by the window, decode across its edge), float32: the
+    engine's tokens are the plain reference's to round-off; with the window
+    left out of the program they are not."""
+    tiny = dict(chip_smoke.AFMOE, hidden_size=64, intermediate_size=128,
+                moe_intermediate_size=48, num_attention_heads=4,
+                num_key_value_heads=2, head_dim=32, sliding_window=12,
+                num_experts=4,
+                experts_held={"first": 4, "count": 4, "of": 16},
+                num_experts_per_tok=2, vocab_size=256, torch_dtype="float32")
+    kw = dict(mix=((24, 8), (8, 10), (32, 4)), max_batch=2, page_size=8)
+    out = chip_smoke.phase_serve_afmoe(tiny, gap_limit=1e-3, **kw)
+    assert 0 <= out["widest_gap"] <= 1e-3
+    from perfbench.runners import serve_afmoe
+
+    real = serve_afmoe.model_config
+    monkeypatch.setattr(serve_afmoe, "model_config", lambda c: dataclasses.replace(
+        real(c), layer_types=("full_attention",) * 5))
+    with pytest.raises(AssertionError, match="over the limit"):
+        chip_smoke.phase_serve_afmoe(tiny, gap_limit=1e-3, **kw)
 
 
 def test_phases_refuse_the_jnp_path():
