@@ -2040,8 +2040,11 @@ def _serving_decode_fixture():
 
     def serve_step(carry, params, tables, active):
         tokens, k_pages, v_pages, pos = carry
+        # tokens ride the carry as they do in the engine: the step
+        # before's output, no row fresh
         nxt, k_pages, v_pages = decode(params, {}, k_pages, v_pages,
-                                       tokens, tables, pos, active)
+                                       tokens, tables, pos, active,
+                                       ~active, tokens)
         return nxt, k_pages, v_pages, pos + 1
 
     return cfg, params, serve_step, carry, tables, active
@@ -2105,7 +2108,7 @@ def _spmd_serving_decode_step():
         def local_step(params, k_pages, v_pages, tokens, tables, pos,
                        active):
             return decode(params, {}, k_pages, v_pages, tokens,
-                          tables, pos, active)
+                          tables, pos, active, ~active, tokens)
 
         shape = (cfg.num_layers, dp * (num_pages + 1), page_size,
                  cfg.num_kv_heads, cfg.head_dim)

@@ -4,14 +4,25 @@
 weights, publishes the ``serving/*`` metric family on the registry,
 and implements the PR 5 preemption contract for servers: when the
 watcher (or a seeded fault plan) trips between iterations, the engine
-stops admitting, drains (the decode loop is host-synchronous, so the
-in-flight step has already landed by the time the flag is polled),
-emergency-dumps queue + in-flight cache state, and raises
+stops admitting, drains (one decode step is in flight between
+iterations: its tokens are landed first, so the dump holds every token
+made and the pages as that step left them), emergency-dumps queue +
+in-flight cache state, and raises
 :class:`~apex_tpu.resilience.loop.Preempted` (exit code 75 via
 ``exit_on_preempt=True`` for process-level supervisors).
 :meth:`ServingEngine.resume` rebuilds from the dump — restored K/V
 pages land by scatter, not re-prefill, so every resumed request's
 remaining tokens are bit-identical to the uninterrupted run.
+
+An iteration (:meth:`ServingEngine.step`) polls preemption, admits, and
+decodes: the scheduler dispatches the next step and only then reads the
+tokens of the step before (``scheduler.step_decode``), so the device has
+its next program queued while the host fetches, book-keeps, polls and
+uploads. What an iteration returns, stamps and counts is what has landed
+on the host: a request's tokens, ``finish_s`` and the ``serving/*``
+metrics are one step behind the device, never ahead of the host.
+``pending`` stays true while a step's tokens are unlanded, so
+:meth:`ServingEngine.run` (and any loop on ``pending``) lands the last.
 
 The dump layout under ``dump_dir``:
 
@@ -66,12 +77,21 @@ class ServerMetrics:
         self.registry = registry
         self._occupancy = registry.gauge("serving/batch_occupancy")
         self._page_utilization = registry.gauge("serving/page_utilization")
+        self._steps_in_flight = registry.counter(
+            "serving/decode_steps_in_flight")
+        self._rows_past_eos = registry.counter("serving/rows_past_eos")
 
     def submitted(self) -> None:
         self.registry.counter("serving/requests_submitted").inc()
 
     def admitted(self) -> None:
         self.registry.counter("serving/requests_admitted").inc()
+
+    def decoded(self, in_flight: int, rows_past_eos: int) -> None:
+        """What one iteration's decode added: steps dispatched with the
+        step before unlanded, rows run a step past their EOS."""
+        self._steps_in_flight.inc(in_flight)
+        self._rows_past_eos.inc(rows_past_eos)
 
     def completed(self, req: Request) -> None:
         self.registry.counter("serving/requests_completed").inc()
@@ -186,11 +206,12 @@ class ServingEngine:
     # ------------------------------------------------------------ loop
 
     def step(self) -> List[Request]:
-        """One engine iteration: poll preemption, admit, decode, evict.
-        Returns the requests finished this iteration. In the span ring
-        it is one ``serving/step`` whose children say what it did: a
-        ``serving/admit`` per admission, a ``serving/decode`` if it
-        decoded."""
+        """One engine iteration: poll preemption, admit, dispatch a
+        decode step, land the step before, evict. Returns the requests
+        finished this iteration: by their own prefill, or by the tokens
+        that landed. In the span ring it is one ``serving/step`` whose
+        children say what it did: a ``serving/admit`` per admission, a
+        ``serving/decode`` if it dispatched a step."""
         with host_span("serving/step"):
             self._poll_preemption()
             admitted, finished = self.scheduler.try_admit()
@@ -201,7 +222,11 @@ class ServingEngine:
                 self._occ_sum += occ
                 self._occ_steps += 1
             self.metrics.step(occ, self.scheduler.cache.utilization())
-            finished = finished + self.scheduler.step_decode()
+            sched = self.scheduler
+            before = sched.steps_in_flight, sched.rows_past_eos
+            finished = finished + sched.step_decode()
+            self.metrics.decoded(sched.steps_in_flight - before[0],
+                                 sched.rows_past_eos - before[1])
             for req in finished:
                 self._finish(req)
             self.iteration += 1
@@ -254,9 +279,11 @@ class ServingEngine:
             self._drain(reason)
 
     def _drain(self, reason: str) -> None:
-        """The server drain: stop admitting (in-flight decode has
-        already landed — the loop is host-synchronous), dump, exit."""
+        """The server drain: stop admitting, land the decode step in
+        flight (what it finished is completed, not dumped), dump, exit."""
         self.draining = True
+        for req in self.scheduler.land():
+            self._finish(req)
         queued, inflight, arrays = self.scheduler.export_requests()
         path = self.dump_dir
         if path is not None:

@@ -16,6 +16,14 @@ The serving loop has exactly two compiled shapes:
   that hold a position some active row attends to (a list made once a
   step, walked in chunks by a loop whose trip count follows it).
 
+The engine keeps exactly one decode step in flight: an iteration
+dispatches step ``k`` from what the host already knows (positions, tables
+and the active mask are host mirrors; a row's next token is the output of
+step ``k-1``, read where it lies on the device) and only then lands step
+``k-1``'s array on the host, appends its tokens and retires what it
+finished. The device has its next program queued when the current one ends;
+the host's fetch, book-keeping, admission polling and uploads run beside it.
+
 Every decode op is per-slot independent (row-wise gemms, per-row
 attention over the row's own block table, per-row argmax), which is
 what makes a request's token stream bit-identical regardless of what
@@ -45,7 +53,10 @@ layer's window stay allocated: one table and one pool for all layers.
 Admission is FCFS: a request enters when a slot is free AND its whole
 page worst case (padded prompt + max_new_tokens) can be allocated, so
 an admitted request can never deadlock on pages mid-decode. Eviction
-(EOS or length cap) frees pages and refills from the queue.
+(EOS or length cap) frees pages and refills from the queue. A row that
+ends by length is known by count and leaves the batch with its last
+dispatch; one that ends by ``eos_id`` is seen when its token lands, a step
+late, and has by then run one step more, whose token is dropped.
 """
 
 from __future__ import annotations
@@ -271,17 +282,25 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     """The ONE jit-compiled decode step (jit + donation is the
     caller's: ``jax.jit(step, donate_argnums=(2, 3))``).
 
-    ``(params, scales, k_pages, v_pages, tokens, tables, pos, active)
-    -> (next_tokens, k_pages, v_pages)`` — all batch inputs are packed
-    ``[max_batch]`` slot arrays; ``tables`` is ``[max_batch,
-    max_pages]`` of page indices (trash-padded). Inactive slots write
-    their k/v to the trash page and pass their token through, so the
-    step is total over any batch composition: one program, whose only
-    data-dependent control is the trip count of the attention's loop.
-    Greedy (argmax) by design — the bit-reproducibility contract. For a
-    dropless expert model ``next_tokens`` is two entries longer: the
-    assignments that fell on held experts and the held experts touched,
-    each summed over the expert layers (:data:`EXPERT_COUNTS`).
+    ``(params, scales, k_pages, v_pages, tokens, tables, pos, active,
+    fresh, first) -> (next_tokens, k_pages, v_pages)`` — all batch
+    inputs are packed ``[max_batch]`` slot arrays; ``tables`` is
+    ``[max_batch, max_pages]`` of page indices (trash-padded). Inactive
+    slots write their k/v to the trash page and pass their token
+    through, so the step is total over any batch composition: one
+    program, whose only data-dependent control is the trip count of the
+    attention's loop. Greedy (argmax) by design — the
+    bit-reproducibility contract. For a dropless expert model
+    ``next_tokens`` is two entries longer: the assignments that fell on
+    held experts and the held experts touched, each summed over the
+    expert layers (:data:`EXPERT_COUNTS`).
+
+    ``tokens`` is the ``next_tokens`` of the step before, as it lies on
+    the device (the step reads its first ``max_batch`` entries): the
+    host need not have seen them to dispatch this step. What the host
+    alone knows is merged here, in the one program: a row where
+    ``fresh`` is set (admitted or restored since that step) takes its
+    token from ``first``.
 
     Arguments 2 and 3 are the whole cache and come back updated. The
     layer scan carries them beside the residual stream and scans over
@@ -303,7 +322,8 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     mm = _products(weight_mode)
 
     def _decode_step(params, scales, k_pages, v_pages, tokens, tables,
-                     pos, active):
+                     pos, active, fresh, first):
+        tokens = jnp.where(fresh, first, tokens[:tables.shape[0]])
         x = _llama.embed(params, tokens[:, None], cfg, tp_axis=None)
         shape = k_pages.shape           # [CL, P + 1, page, nkv, d]
         stride, trash = shape[1], shape[1] - 1
@@ -419,9 +439,17 @@ def build_prefill(cfg, bucket_len: int, weight_mode: str = "native"):
 class ContinuousBatchScheduler:
     """Queue + slots + paged cache behind the two compiled shapes.
 
-    Host mirrors (numpy) of the slot arrays are the source of truth;
-    each decode step re-wraps them as device arrays (same shapes every
-    step — data changes, shapes never do).
+    Host mirrors (numpy) of the positions, the tables and the active
+    mask are the source of truth; each decode step re-wraps them as
+    device arrays (same shapes every step — data changes, shapes never
+    do). They say what the next dispatch runs: a position moves on when
+    its step is dispatched, not when its token lands. The tokens are not
+    mirrored: a row's next input is the newest step's output, kept on
+    the device (``_newest``), and the host holds only the first token of
+    each row admitted or restored since the last dispatch (``_first``
+    under the mask ``_fresh``). One step's output at most is unlanded
+    (``_unlanded``): :meth:`step_decode` dispatches, then lands the step
+    before; :meth:`land` lands without dispatching.
     """
 
     def __init__(self, params, cfg, *, num_pages: int,
@@ -459,11 +487,19 @@ class ContinuousBatchScheduler:
         self.queue: "collections.deque[Request]" = collections.deque()
         self.slots: List[Optional[Request]] = [None] * self.max_batch
         trash = self.cache.trash_page
-        self._tokens = np.zeros(self.max_batch, np.int32)
         self._pos = np.zeros(self.max_batch, np.int32)
         self._tables = np.full(
             (self.max_batch, self.max_pages_per_req), trash, np.int32)
         self._active = np.zeros(self.max_batch, bool)
+        self._first = np.zeros(self.max_batch, np.int32)
+        self._fresh = np.zeros(self.max_batch, bool)
+        # the newest step's output, on the device: the next step's tokens
+        self._newest = jnp.zeros(
+            self.max_batch + len(EXPERT_COUNTS) * bool(cfg.dropless),
+            jnp.int32)
+        # the one step whose output the host has not read: its array and
+        # the (slot, request) pairs of the rows it ran
+        self._unlanded: Optional[Tuple[jax.Array, list]] = None
         self._scales = (fp8_weight_scales(params)
                         if self.weight_mode == "fp8" else {})
         self._decode = jax.jit(
@@ -472,6 +508,10 @@ class ContinuousBatchScheduler:
         self._prefills: Dict[int, object] = {}
         self.decode_steps = 0
         self.prefill_count = 0
+        # steps dispatched while the one before was unlanded; rows that ran
+        # one step past their EOS (their extra token dropped)
+        self.steps_in_flight = 0
+        self.rows_past_eos = 0
         # installed before anything compiles, so the guard below can tell
         # "no retrace" from "the listener never saw a compile"
         from apex_tpu.observability import recompile
@@ -487,7 +527,8 @@ class ContinuousBatchScheduler:
         return float(np.count_nonzero(self._active)) / self.max_batch
 
     def has_work(self) -> bool:
-        return bool(self.queue) or any(
+        """Queued or running requests, or a step's tokens to land."""
+        return bool(self.queue) or self._unlanded is not None or any(
             r is not None for r in self.slots)
 
     def num_active(self) -> int:
@@ -580,17 +621,23 @@ class ContinuousBatchScheduler:
             if self._is_finished(req, t0):
                 self._retire(req)
                 return False
-            slot = self.slots.index(None)
-            self.slots[slot] = req
-            req.state = "active"
-            self._tokens[slot] = t0
-            self._pos[slot] = p
-            row = np.full(self.max_pages_per_req, self.cache.trash_page,
-                          np.int32)
-            row[:len(pages)] = pages
-            self._tables[slot] = row
-            self._active[slot] = True
+            self._seat(req, pages, p)
             return True
+
+    def _seat(self, req: Request, pages, pos: int) -> None:
+        """Give ``req`` a free slot: its next step reads its newest token
+        from the host and writes position ``pos`` of ``pages``."""
+        slot = self.slots.index(None)
+        self.slots[slot] = req
+        req.state = "active"
+        self._first[slot] = req.tokens[-1]
+        self._fresh[slot] = True
+        self._pos[slot] = pos
+        row = np.full(self.max_pages_per_req, self.cache.trash_page,
+                      np.int32)
+        row[:len(pages)] = pages
+        self._tables[slot] = row
+        self._active[slot] = True
 
     # ---------------------------------------------------------- decode
 
@@ -605,9 +652,23 @@ class ContinuousBatchScheduler:
                           - start // self.page_size + 1))
 
     def step_decode(self) -> List[Request]:
-        """One packed decode step; returns requests finished by it."""
+        """Dispatch one packed decode step, then land the step before:
+        append its tokens and return the requests they finished. The
+        tokens a call appends are those of the step the call before
+        dispatched; with nothing unlanded it appends none. With no row to
+        run it lands what is unlanded (:meth:`land`).
+
+        In the span ring a dispatched step is one ``serving/decode``:
+        ``rows`` and the page counts are the dispatched step's,
+        ``in_flight`` is 1 when the step before was still unlanded at the
+        dispatch (the device had its next program queued), and what only
+        the landed array can say is **of the array landed during the
+        record**, the step before's (zeros when nothing was unlanded):
+        ``rows_past_eos``, the rows of the dispatched step that the landed
+        tokens show to be past their EOS, and a dropless expert model's
+        :data:`EXPERT_COUNTS`."""
         if not self._active.any():
-            return []
+            return self.land()
         # pages_gathered: what the step reads in every cache layer
         live = self.pages_live()
         fields = dict(self._depth)
@@ -620,16 +681,22 @@ class ContinuousBatchScheduler:
                 pages_gathered_window=pages_read(near, self._tables.size),
                 positions=int(seen.sum()), positions_window=int(
                     np.minimum(seen, self.cfg.sliding_window).sum()))
+        in_flight = int(self._unlanded is not None)
+        self.steps_in_flight += in_flight
         with host_span("serving/decode", rows=self.num_active(),
                        pages_live=live,
                        pages_gathered=pages_read(live, self._tables.size),
-                       **fields):
+                       in_flight=in_flight, **fields):
             with host_span("serving/decode_upload"):
-                nxt, self.cache.k_pages, self.cache.v_pages = self._decode(
-                    self.params, self._scales,
-                    self.cache.k_pages, self.cache.v_pages,
-                    jnp.asarray(self._tokens), jnp.asarray(self._tables),
-                    jnp.asarray(self._pos), jnp.asarray(self._active))
+                # copies: the mirrors change while the step is queued, and
+                # a transfer may read its host array after the call returns
+                mirrors = (jnp.asarray(a.copy()) for a in (
+                    self._tables, self._pos, self._active, self._fresh,
+                    self._first))
+                self._newest, self.cache.k_pages, self.cache.v_pages = \
+                    self._decode(
+                        self.params, self._scales, self.cache.k_pages,
+                        self.cache.v_pages, self._newest, *mirrors)
             self.decode_steps += 1
             if self._decode_compiles0 is None:
                 self._decode_compiles0 = self._recompiles.compiles(
@@ -638,25 +705,66 @@ class ContinuousBatchScheduler:
                     raise RuntimeError(
                         "the recompile listener did not see _decode_step "
                         "compile: the zero-retrace guard is blind")
-            with host_span("serving/decode_fetch"):
-                nxt = np.asarray(nxt)
-            if self.cfg.dropless:
-                get_tracer().annotate(**dict(zip(
-                    EXPERT_COUNTS, map(int, nxt[self.max_batch:]))))
-            finished = []
-            with host_span("serving/decode_bookkeep"):
-                for slot, req in enumerate(self.slots):
-                    if req is None or not self._active[slot]:
-                        continue
-                    t = int(nxt[slot])
-                    req.tokens.append(t)
-                    self._tokens[slot] = t
-                    self._pos[slot] += 1
-                    if self._is_finished(req, t):
-                        self._free_slot(slot)
-                        self._retire(req)
-                        finished.append(req)
+            dispatched = (self._newest, self._advance())
+            finished, said = self._land(ran_on=True)
+            self._unlanded = dispatched
+            get_tracer().annotate(**said)
             return finished
+
+    def _advance(self) -> List[Tuple[int, Request]]:
+        """The host mirrors after a dispatch: the (slot, request) pairs of
+        the rows it ran, each a position on; no row is fresh; a row whose
+        token in flight is its last by count runs no more (its slot is
+        held until that token lands)."""
+        rows = [(int(slot), self.slots[slot])
+                for slot in np.flatnonzero(self._active)]
+        self._pos[self._active] += 1
+        self._fresh[:] = False
+        for slot, req in rows:
+            # made, landed or in flight: the first with the prompt, then one
+            # a position
+            if self._pos[slot] - len(req.prompt) + 1 >= req.max_new_tokens:
+                self._active[slot] = False
+        return rows
+
+    def land(self) -> List[Request]:
+        """Read the unlanded step's tokens without dispatching another:
+        what ends a run of steps (no row left to run) and what a dump
+        does first. Returns the requests the tokens finished."""
+        return self._land()[0]
+
+    def _land(self, ran_on: bool = False
+              ) -> Tuple[List[Request], Dict[str, int]]:
+        """The unlanded step's array onto the host: (the requests its
+        tokens finished, what the array says for the span record:
+        ``rows_past_eos`` and a dropless expert model's counts of that
+        step; zeros with nothing unlanded). A row that an earlier landing
+        retired (its EOS came a step before) ran this step for nothing:
+        its token is dropped. ``ran_on``: a step has been dispatched
+        since, with every row not yet at its length in it."""
+        said = dict.fromkeys(
+            ("rows_past_eos",) + EXPERT_COUNTS * bool(self.cfg.dropless), 0)
+        if self._unlanded is None:
+            return [], said
+        (nxt, rows), self._unlanded = self._unlanded, None
+        with host_span("serving/decode_fetch"):
+            nxt = np.asarray(nxt)
+        said.update(zip(EXPERT_COUNTS, map(int, nxt[self.max_batch:])))
+        finished = []
+        with host_span("serving/decode_bookkeep"):
+            for slot, req in rows:
+                if req.state == "done":
+                    continue
+                t = int(nxt[slot])
+                req.tokens.append(t)
+                if self._is_finished(req, t):
+                    said["rows_past_eos"] += int(
+                        ran_on and len(req.tokens) < req.max_new_tokens)
+                    self._free_slot(slot)
+                    self._retire(req)
+                    finished.append(req)
+        self.rows_past_eos += said["rows_past_eos"]
+        return finished, said
 
     def _is_finished(self, req: Request, token: int) -> bool:
         return (len(req.tokens) >= req.max_new_tokens
@@ -675,7 +783,6 @@ class ContinuousBatchScheduler:
         self.slots[slot] = None
         self._active[slot] = False
         self._tables[slot] = self.cache.trash_page
-        self._tokens[slot] = 0
         self._pos[slot] = 0
 
     # --------------------------------------------------- dump / resume
@@ -690,7 +797,13 @@ class ContinuousBatchScheduler:
         """Emergency-dump payload: (queued records, inflight records,
         {name: numpy} page arrays). Inflight k/v pages are gathered so
         resume restores them by scatter — re-prefilling would re-run
-        float math and forfeit bit-identical resumption."""
+        float math and forfeit bit-identical resumption. Every token made
+        has to be on the host: the caller lands the step in flight first
+        (:meth:`land`), so a record's ``tokens`` and ``pos`` agree."""
+        if self._unlanded is not None:
+            raise RuntimeError(
+                "a decode step's tokens are unlanded: land() first, and "
+                "keep what it finished")
         queued = [self._req_record(r) for r in self.queue]
         inflight, arrays = [], {}
         for slot, req in enumerate(self.slots):
@@ -716,18 +829,9 @@ class ContinuousBatchScheduler:
                       arrival_s=rec.get("arrival_s", 0.0),
                       submit_s=time.monotonic())
         req.admit_s = req.submit_s
-        slot = self.slots.index(None)
         pages = self.cache.alloc.alloc(rec["npages"], req.rid)
         self.cache.restore_pages(pages, k, v)
         req.tokens = list(rec["tokens"])
-        req.state = "active"
         req.first_token_s = time.monotonic()
-        self.slots[slot] = req
-        self._tokens[slot] = req.tokens[-1]
-        self._pos[slot] = rec["pos"]
-        row = np.full(self.max_pages_per_req, self.cache.trash_page,
-                      np.int32)
-        row[:len(pages)] = pages
-        self._tables[slot] = row
-        self._active[slot] = True
+        self._seat(req, pages, rec["pos"])
         return req

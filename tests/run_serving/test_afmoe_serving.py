@@ -132,7 +132,9 @@ def test_the_spans_counts_are_the_hosts(params, monkeypatch):
     """Every admission's `expert_tokens` and every decode step's
     `expert_tokens`, `experts_hit`, `pages_live_window` and
     `positions_window` against a count made here: from the reference's own
-    routing of each request's sequence, and from the rows' positions."""
+    routing of each request's sequence, and from the rows' positions. A
+    decode record carries the routing counts of the array it landed: the
+    step before's (ISSUE 34), zeros on the first."""
     monkeypatch.setattr(ref, "QUERY_BLOCK", 8)
     tracer = SpanTracer()
     previous = set_tracer(tracer)
@@ -148,8 +150,8 @@ def test_the_spans_counts_are_the_hosts(params, monkeypatch):
         steps = []               # per decode step: [(rid, position)]
         while s.has_work():
             s.try_admit()
-            steps.append([(r.rid, int(s._pos[slot]))
-                          for slot, r in enumerate(s.slots) if r is not None])
+            steps.append([(s.slots[slot].rid, int(s._pos[slot]))
+                          for slot in np.flatnonzero(s._active)])
             s.step_decode()
         spans = tracer.completed()
     finally:
@@ -170,10 +172,14 @@ def test_the_spans_counts_are_the_hosts(params, monkeypatch):
     decodes = [sp.args for sp in spans if sp.name == "serving/decode"]
     assert len(decodes) == len([rows for rows in steps if rows])
     crossed = 0
-    for args, rows in zip(decodes, [rows for rows in steps if rows]):
-        picks = np.stack([chosen[rid][:, pos] for rid, pos in rows])
-        assert args["expert_tokens"] == picks.sum()
-        assert args["experts_hit"] == picks.any(axis=0).sum()
+    steps = [rows for rows in steps if rows]
+    assert (decodes[0]["expert_tokens"], decodes[0]["experts_hit"]) == (0, 0)
+    assert [a["in_flight"] for a in decodes] == [0] + [1] * (len(steps) - 1)
+    for args, landed, rows in zip(decodes, [[]] + steps, steps):
+        if landed:
+            picks = np.stack([chosen[rid][:, pos] for rid, pos in landed])
+            assert args["expert_tokens"] == picks.sum()
+            assert args["experts_hit"] == picks.any(axis=0).sum()
         pos = np.array([p for _, p in rows])
         assert args["rows"] == len(rows)
         assert args["positions"] == (pos + 1).sum()
@@ -262,7 +268,7 @@ def one_step(step, params, placed, others_active):
     active[list(placed)] = True
     nxt, k1, v1 = step(params, {}, k0, v0, *(
         jnp.asarray(a[source]) for a in (tokens, tables, pos)),
-        jnp.asarray(active))
+        jnp.asarray(active), jnp.zeros(ROWS, bool), jnp.zeros(ROWS, jnp.int32))
     wrote = [(slice(None), tables[r, pos[r] // PAGE], pos[r] % PAGE)
              for r in (0, 1)]
     return [(int(nxt[at]), np.asarray(k1[w]), np.asarray(v1[w]))

@@ -93,7 +93,8 @@ def test_the_compiled_step_holds_the_page_buffers_once(one_chip, config, mix,
         compiled = step.lower(
             params, {}, pages, pages, struct((rows,), jnp.int32),
             struct((rows, table), jnp.int32), struct((rows,), jnp.int32),
-            struct((rows,), jnp.bool_)).compile()
+            struct((rows,), jnp.bool_), struct((rows,), jnp.bool_),
+            struct((rows,), jnp.int32)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
     both = 2 * int(np.prod(shape)) * jnp.dtype(cfg.dtype).itemsize
@@ -148,9 +149,11 @@ def test_the_expert_models_step_reads_its_experts_where_they_lie(one_chip):
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         compiled = step.lower(
-            params, {}, pages, pages, struct((rows,), jnp.int32),
+            params, {}, pages, pages,
+            struct((rows + len(sched.EXPERT_COUNTS),), jnp.int32),
             struct((rows, table), jnp.int32), struct((rows,), jnp.int32),
-            struct((rows,), jnp.bool_)).compile()
+            struct((rows,), jnp.bool_), struct((rows,), jnp.bool_),
+            struct((rows,), jnp.int32)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
     memory = compiled.memory_analysis()

@@ -24,6 +24,8 @@ from apex_tpu.serving import scheduler as sched
 
 PAGE, ROWS, WIDTH = 4, 6, 5                # tables [6, 5]: 30 slots
 PAGES = ROWS * WIDTH                       # page 30 is the trash page
+# the step's last two arguments when every row's token is in `tokens`
+NONE_FRESH = jnp.zeros(ROWS, bool), jnp.zeros(ROWS, jnp.int32)
 STACKS = {
     "plain": llama.tiny(num_kv_heads=4),
     "grouped": llama.tiny(),               # 2 KV heads for 4
@@ -106,7 +108,8 @@ def test_the_list_step_is_the_whole_table_step(stack, case, monkeypatch):
     tokens, tables, pos, active = map(jnp.asarray, batch(case))
     want = whole_table_step(cfg)(params, k0, v0, tokens, tables, pos, active)
     step = jax.jit(sched.build_decode_step(cfg, PAGE), donate_argnums=(2, 3))
-    got = step(params, {}, k0 + 0, v0 + 0, tokens, tables, pos, active)
+    got = step(params, {}, k0 + 0, v0 + 0, tokens, tables, pos, active,
+               *NONE_FRESH)
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
     for g, w in zip(got[1:], want[1:]):
         assert np.isfinite(np.asarray(g)).all()
@@ -171,7 +174,7 @@ def two_rows_of(placed, others_active, step, cfg):
     active[list(placed)] = True
     nxt, k1, v1 = step(params_of(cfg), {}, k0, v0, *(
         jnp.asarray(a[source]) for a in (tokens, tables, pos)),
-        jnp.asarray(active))
+        jnp.asarray(active), *NONE_FRESH)
     wrote = [(slice(None), tables[r, pos[r] // PAGE], pos[r] % PAGE)
              for r in (0, 4)]
     return [(int(nxt[at]), np.asarray(k1[w]), np.asarray(v1[w]))

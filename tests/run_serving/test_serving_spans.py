@@ -1,8 +1,10 @@
 """What the serving engine writes into the span ring (ISSUE 26): one
 `serving/step` per iteration with the admissions and the decode it made as
 its children, every request's queue wait, admission and whole life under one
-request id, and the decode step's page counts. Counts and order only: a CPU
-run says nothing about a time."""
+request id, and the decode step's page counts. Since ISSUE 34 a
+`serving/decode` record is a dispatched step and covers the landing of the
+step before: the fields only a landed array can give are that step's. Counts
+and order only: a CPU run says nothing about a time."""
 
 import math
 
@@ -78,13 +80,28 @@ def test_one_step_per_iteration_with_its_admissions_and_decode(served):
     for parent, children in (
             (admits, ("serving/prefill_dispatch", "serving/write_prompt",
                       "serving/first_token_fetch")),
-            (decodes, ("serving/decode_upload", "serving/decode_fetch",
-                       "serving/decode_bookkeep"))):
+            (decodes, ("serving/decode_upload",))):
         ids = {s.id for s in parent}
         for name in children:
             kids = by_name(spans, name)
             assert len(kids) == len(parent)
             assert all(k.parent in ids for k in kids)
+    # every dispatched step's array is landed once: under the record of the
+    # step dispatched next, or, with no row left to run, under the iteration
+    decode_ids = {s.id: s for s in decodes}
+    for name in ("serving/decode_fetch", "serving/decode_bookkeep"):
+        kids = by_name(spans, name)
+        assert len(kids) == len(decodes)
+        assert all(k.parent in decode_ids or k.parent in step_ids
+                   for k in kids)
+        under = [k.parent for k in kids if k.parent in decode_ids]
+        assert len(under) == len(set(under))
+        # a record landed a step exactly where one was in flight
+        assert {i for i, s in decode_ids.items() if s.args["in_flight"]} \
+            == set(under)
+    assert not decodes[0].args["in_flight"]
+    assert sum(s.args["in_flight"] for s in decodes) \
+        == engine.scheduler.steps_in_flight >= len(decodes) - 2
 
 
 def test_every_request_has_its_spans_under_one_rid(served):
@@ -137,19 +154,19 @@ def test_decode_records_count_live_and_gathered_pages(model, tracer,
             np.int32), max_new)
     chunks = set()
     while engine.pending:
-        before, done = sched.decode_steps, len(engine.completed)
+        before = sched.decode_steps
         mark = tracer.mark()
         engine.step()
         if sched.decode_steps == before:
             continue
         (record,) = by_name(tracer.completed(mark), "serving/decode")
-        # after the step each surviving row's position has moved by one,
-        # so its count before the step is ceil(pos / page); rows the step
-        # retired are gone from the slots and counted from their requests
-        live = sum(math.ceil(int(sched._pos[i]) / PAGE)
-                   for i, r in enumerate(sched.slots) if r is not None)
-        live += sum(math.ceil((len(r.prompt) + len(r.tokens) - 1) / PAGE)
-                    for r in engine.completed[done:] if len(r.tokens) > 1)
+        # the record counts the step it dispatched, whose rows are those
+        # of the array now unlanded: each one's position has moved by one,
+        # so its count before the step is ceil(pos / page)
+        rows = sched._unlanded[1]
+        live = sum(math.ceil(int(sched._pos[slot]) / PAGE)
+                   for slot, _ in rows)
+        assert record.args["rows"] == len(rows)
         assert record.args["pages_live"] == live
         assert 0 < record.args["pages_live"] <= record.args["pages_gathered"]
         assert record.args["pages_gathered"] == math.ceil(live / 4) * 4
@@ -157,3 +174,68 @@ def test_decode_records_count_live_and_gathered_pages(model, tracer,
         assert 0 < record.args["rows"] <= 3
         chunks.add(record.args["pages_gathered"] // 4)
     assert len(chunks) > 1 and sched.decode_retraces() == 0
+
+
+DENSE_FIELDS = {"rows", "pages_live", "pages_gathered", "layer_passes",
+                "cache_layers", "in_flight", "rows_past_eos"}
+EXPERT_FIELDS = DENSE_FIELDS | {
+    "expert_layers", "experts_held", "expert_tokens", "experts_hit",
+    "pages_live_window", "pages_gathered_window", "positions",
+    "positions_window"}
+
+
+def stack_of(kind):
+    """(params, cfg, vocabulary) of a tiny model of each kind served."""
+    if kind == "expert":
+        from perfbench.references import afmoe as ref
+        from perfbench.references.common import seed_words
+        from perfbench.runners import serve_afmoe
+        from test_afmoe_serving import TINY
+
+        return (ref.init(*seed_words(2 ** 31 + 33), TINY),
+                serve_afmoe.model_config(TINY), TINY["vocab_size"])
+    cfg = llama.tiny() if kind == "dense" else llama.tiny(
+        num_passes=3, sandwich_norm=True)
+    return llama.init_params(jax.random.PRNGKey(0), cfg), cfg, cfg.vocab_size
+
+
+@pytest.mark.parametrize("kind", ["dense", "looped", "expert"])
+def test_every_decode_record_carries_every_field(kind, tracer):
+    """ISSUE 34 (f): a `serving/decode` record has the fields it had and
+    `in_flight` and `rows_past_eos`, on every record, the first (nothing
+    landed) and the rest alike: a reader that finds one record without a
+    field gives no value for the whole window. An expert model's routing
+    counts are those of the array landed during the record: each step's own,
+    one record on, zeros on the first, the last step's on none."""
+    params, cfg, vocab = stack_of(kind)
+    engine = ServingEngine(params, cfg, page_size=4, max_batch=3,
+                           num_pages=27, max_prompt_len=20, max_new_cap=12,
+                           registry=obs.MetricRegistry())
+    sched = engine.scheduler
+    outputs, decode = [], sched._decode
+
+    def dispatch(*args):
+        out = decode(*args)
+        outputs.append(out[0])
+        return out
+
+    sched._decode = dispatch
+    rng = np.random.default_rng(2)
+    for p, new in ((3, 6), (8, 10), (20, 10), (11, 9), (5, 12)):
+        engine.submit(rng.integers(0, vocab, size=p).astype(np.int32), new)
+    engine.run()
+    decodes = [s.args for s in by_name(tracer.completed(), "serving/decode")]
+    assert len(decodes) == len(outputs) == sched.decode_steps > 10
+    want = EXPERT_FIELDS if kind == "expert" else DENSE_FIELDS
+    assert all(set(a) == want for a in decodes)
+    assert all(type(v) is int for a in decodes for v in a.values())
+    assert {a["layer_passes"] for a in decodes} == {
+        cfg.num_passes * cfg.num_layers}
+    assert [a["in_flight"] for a in decodes] == [0] + [1] * (len(decodes) - 1)
+    if kind == "expert":
+        own = np.stack([np.asarray(o)[3:] for o in outputs])   # [steps, 2]
+        got = np.array([[a["expert_tokens"], a["experts_hit"]]
+                        for a in decodes])
+        assert (got[0] == 0).all() and (got[1:] == own[:-1]).all()
+        assert (got.sum(axis=0) == own.sum(axis=0) - own[-1]).all()
+        assert own.sum() > 0

@@ -32,6 +32,7 @@ import argparse
 import base64
 import collections
 import hashlib
+import inspect
 import json
 import os
 import pathlib
@@ -87,15 +88,20 @@ def serving_programs():
         pages = struct((cfg.cache_layers, eng["num_pages"] + 1, page,
                         cfg.num_kv_heads, cfg.head_dim), cfg.dtype)
         bucket = eng["max_prompt_len"]
+        step = sched.build_decode_step(cfg, page)
+        batch = [struct((rows,), jnp.int32), struct((rows, table), jnp.int32),
+                 struct((rows,), jnp.int32), struct((rows,), jnp.bool_)]
+        if len(inspect.signature(step).parameters) == 10:
+            # since PR 34: the step before's tokens as the device holds them
+            # (the counts an expert model appends with them), and the host's
+            # patch for the rows admitted since
+            batch[0] = struct(
+                (rows + 2 * bool(getattr(cfg, "dropless", False)),), jnp.int32)
+            batch += [struct((rows,), jnp.bool_), struct((rows,), jnp.int32)]
         with pallas_config.force("on"):
             programs = {
-                "decode": jax.jit(
-                    sched.build_decode_step(cfg, page),
-                    donate_argnums=(2, 3)).lower(
-                        params, {}, pages, pages, struct((rows,), jnp.int32),
-                        struct((rows, table), jnp.int32),
-                        struct((rows,), jnp.int32),
-                        struct((rows,), jnp.bool_)),
+                "decode": jax.jit(step, donate_argnums=(2, 3)).lower(
+                    params, {}, pages, pages, *batch),
                 f"prefill{bucket}": sched.build_prefill(cfg, bucket).lower(
                     params, {}, struct((1, bucket), jnp.int32),
                     struct((), jnp.int32))}
