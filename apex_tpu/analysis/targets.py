@@ -2042,9 +2042,9 @@ def _serving_decode_fixture():
         tokens, k_pages, v_pages, pos = carry
         # tokens ride the carry as they do in the engine: the step
         # before's output, no row fresh
-        nxt, k_pages, v_pages = decode(params, {}, k_pages, v_pages,
-                                       tokens, tables, pos, active,
-                                       ~active, tokens)
+        nxt, k_pages, v_pages, _ = decode(params, {}, k_pages, v_pages,
+                                          None, tokens, tables, pos, active,
+                                          ~active, tokens)
         return nxt, k_pages, v_pages, pos + 1
 
     return cfg, params, serve_step, carry, tables, active
@@ -2107,8 +2107,8 @@ def _spmd_serving_decode_step():
 
         def local_step(params, k_pages, v_pages, tokens, tables, pos,
                        active):
-            return decode(params, {}, k_pages, v_pages, tokens,
-                          tables, pos, active, ~active, tokens)
+            return decode(params, {}, k_pages, v_pages, None, tokens,
+                          tables, pos, active, ~active, tokens)[:3]
 
         shape = (cfg.num_layers, dp * (num_pages + 1), page_size,
                  cfg.num_kv_heads, cfg.head_dim)

@@ -141,9 +141,13 @@ def _flash_keeping_kv(q, k, v):
     return o, (k, v)
 
 
-def _prefill_attend(lp, cfg):
+def _prefill_attend(lp, cfg, length=None):
     """The prefills' ``attend`` for this layer: :func:`_flash_keeping_kv`,
-    within the window where the layer is a sliding one."""
+    within the window where the layer is a sliding one; on a conv layer the
+    whole sequence's window, its state kept at ``length`` (the prompt's true
+    length under a padded bucket; the sequence's end without one)."""
+    if _llama.operator_of(lp) == "conv":
+        return _llama.conv_window(cfg, length=length)
     if not cfg.windowed:
         return _flash_keeping_kv
     return lambda q, k, v: (_llama.causal_attention(q, k, v, lp, cfg),
@@ -168,9 +172,10 @@ def _attend_cache_at(pos, k_cache, v_cache, start=None):
 
 
 def _logits(params, x, cfg):
-    x = _llama._rmsnorm(x, params["final_norm"], cfg.rms_eps)
-    w = _llama.lm_head_weight(params, cfg)
-    return jnp.matmul(x, w.astype(x.dtype)).astype(jnp.float32)
+    with jax.named_scope("llama/head"):
+        x = _llama._rmsnorm(x, params["final_norm"], cfg.rms_eps)
+        w = _llama.lm_head_weight(params, cfg)
+        return jnp.matmul(x, w.astype(x.dtype)).astype(jnp.float32)
 
 
 def _sample(logits, temperature, key):
@@ -180,25 +185,25 @@ def _sample(logits, temperature, key):
 
 
 def _autoregress(embed_step, decode_stack, logits_fn,
-                 k_cache, v_cache, logits0, prompt_tokens,
+                 caches, logits0, prompt_tokens,
                  max_new_tokens, temperature, key):
     """The shared decode loop: max_new-1 scan steps, each consuming the
     previous token and emitting the next (the final token needs no
-    decode pass). ``decode_stack(x, (k, v), pos) -> (x, (k, v))`` is the
-    model's whole depth for one token."""
+    decode pass). ``decode_stack(x, caches, pos) -> (x, caches)`` is the
+    model's whole depth for one token; ``caches`` is what it keeps between
+    steps (K and V; a stack with conv layers, their states beside them)."""
     key, key0 = jax.random.split(key)
     first = _sample(logits0, temperature, key0)[:, None]
 
     def step(carry, key_t):
-        token, kc, vc, pos = carry
-        x, (kc, vc) = decode_stack(embed_step(token, pos), (kc, vc), pos)
+        token, kept, pos = carry
+        x, kept = decode_stack(embed_step(token, pos), kept, pos)
         nxt = _sample(logits_fn(x)[:, 0], temperature, key_t)
-        return (nxt[:, None], kc, vc, pos + 1), nxt
+        return (nxt[:, None], kept, pos + 1), nxt
 
     p = prompt_tokens.shape[1]
     keys = jax.random.split(key, max_new_tokens - 1)
-    _, toks = jax.lax.scan(
-        step, (first, k_cache, v_cache, jnp.int32(p)), keys)
+    _, toks = jax.lax.scan(step, (first, caches, jnp.int32(p)), keys)
     new = jnp.concatenate([first, toks.T], axis=1)  # [b, max_new]
     return jnp.concatenate([prompt_tokens, new], axis=1)
 
@@ -220,7 +225,8 @@ def generate(params, prompt_tokens, cfg, max_new_tokens: int,
     top-k experts with NO capacity drop (the training path's drops are a
     throughput artifact, not an inference semantic); a dropless expert
     model runs the layer it is served by (``llama.moe_ffn``), and sliding
-    layers read their window of the cache.
+    layers read their window of the cache. A conv layer has no K and V: it
+    keeps ``[b, conv_L_cache - 1, h]`` of state, rewritten every step.
     """
     b, p = prompt_tokens.shape
     key = _check_sampling_args(temperature, key)
@@ -233,19 +239,25 @@ def generate(params, prompt_tokens, cfg, max_new_tokens: int,
         return _block(h, lp, params, cfg, positions,
                       _prefill_attend(lp, cfg), _moe_prefill_ffn)
 
-    x, (ks, vs) = _llama.scan_passes(x, params, cfg, pre_body,
-                                     _llama.stacks(params, cfg))
+    x, kept = _llama.scan_passes(x, params, cfg, pre_body,
+                                 _llama.stacks(params, cfg))
     pad = [(0, 0), (0, 0), (0, max_new_tokens), (0, 0), (0, 0)]
-    k_cache = jnp.pad(ks.astype(cfg.dtype), pad)  # [T*L, b, max_len, ...]
-    v_cache = jnp.pad(vs.astype(cfg.dtype), pad)
+    # [T*L, b, max_len, ...] of K and of V
+    caches = tuple(jnp.pad(a.astype(cfg.dtype), pad) for a in (
+        kept["attention"] if cfg.hybrid else kept))
+    if cfg.hybrid:
+        caches = {"attention": caches, "conv": kept["conv"]}
     logits0 = _logits(params, x[:, -1:], cfg)[:, 0]
 
     def decode_stack(x, caches, pos):
         def layer(h, lp, cache):
+            if _llama.operator_of(lp) == "conv":
+                attend = _llama.conv_window(cfg, state=cache)
+            else:
+                attend = _attend_cache_at(
+                    pos, *cache, _llama.window_start(lp, cfg, pos))
             return _block(h, lp, params, cfg,
-                          jnp.full((b, 1), pos, jnp.int32),
-                          _attend_cache_at(
-                              pos, *cache, _llama.window_start(lp, cfg, pos)),
+                          jnp.full((b, 1), pos, jnp.int32), attend,
                           _moe_decode_ffn)
 
         return _llama.scan_passes(x, params, cfg, layer,
@@ -255,7 +267,7 @@ def generate(params, prompt_tokens, cfg, max_new_tokens: int,
         lambda token, pos: _llama.embed(params, token, cfg, tp_axis=None),
         decode_stack,
         lambda x: _logits(params, x, cfg),
-        k_cache, v_cache, logits0, prompt_tokens,
+        caches, logits0, prompt_tokens,
         max_new_tokens, temperature, key)
 
 
@@ -360,5 +372,5 @@ def gpt2_generate(params, prompt_tokens, cfg, max_new_tokens: int,
 
     return _autoregress(
         lambda token, pos: embed(token, pos), decode_stack,
-        logits_fn, k_cache, v_cache, logits0,
+        logits_fn, (k_cache, v_cache), logits0,
         prompt_tokens, max_new_tokens, temperature, key)

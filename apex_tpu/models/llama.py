@@ -56,8 +56,24 @@ weights, so a layer's kind is data of its scan step (``lp["sliding"]``, a
 bool beside the weights): a window bound that is ``pos - window + 1`` or 0,
 a select between the rotated and the plain heads. ``qk_norm`` (RMSNorm on
 each query and key head), ``attn_output_gate`` (a sigmoid gate on the
-heads' output) and ``embed_scale`` are written once, in :func:`block` and
-:func:`embed`. With the defaults none of this is in any program.
+heads' output) and ``embed_scale`` are written once, in :func:`attention`
+and :func:`embed`. With the defaults none of this is in any program.
+
+Stacks of two weight shapes (the ``lfm2`` form). *The operator:*
+``layer_types`` may name a layer ``conv``: a gated short convolution
+(:func:`short_conv`: an input projection to three parts, a depthwise causal
+convolution of ``conv_L_cache`` taps on the product of two of them, gated by
+the third, an output projection) where the others have attention. It keeps
+no K and V (``cfg.cache_layers`` counts the attention layers) and
+``conv_L_cache - 1`` positions of state a row (``cfg.conv_layers``).
+:func:`block`'s first half is "the operator on the normed stream", and the
+caller's ``attend`` says what it keeps, for either kind. A conv layer's
+weights have other shapes than an attention layer's, so such a model
+(``cfg.hybrid``) is kept and scanned run by run: ``params["runs"]`` holds one
+stack a run of consecutive layers of one shape (``cfg.runs``), none carrying
+another shape's weights, ``params["experts"]`` the routed experts of all its
+expert layers in one stack, and :func:`scan_passes` gives each run its own
+slice of what its operator's layers own.
 """
 
 from __future__ import annotations
@@ -120,8 +136,9 @@ class LlamaConfig:
     sandwich_norm: bool = False
     # a head's width where it is not hidden_size / num_heads
     attn_head_dim: Optional[int] = None
-    # each layer's attention, "sliding_attention" or "full_attention"; ()
-    # is full attention everywhere. A sliding layer's query i reads keys
+    # each layer's operator: "sliding_attention" or "full_attention", or
+    # "conv" (a gated short convolution, no K and V); () is full attention
+    # everywhere. A sliding layer's query i reads keys
     # i - sliding_window < j <= i
     layer_types: Tuple[str, ...] = ()
     sliding_window: Optional[int] = None
@@ -149,13 +166,31 @@ class LlamaConfig:
     # routed over (one chip's share of an expert-parallel deployment);
     # None holds them all
     experts_held: Optional[Tuple[int, int]] = None
+    # what route_norm adds to the sum it divides a token's weights by
+    route_norm_eps: float = 1e-20
+    # a "conv" layer: the taps of its depthwise causal convolution (it keeps
+    # the conv_L_cache - 1 positions before the newest); a bias a channel is
+    # a key the published configurations carry, and none sets it
+    conv_L_cache: int = 3
+    conv_bias: bool = False
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.num_layers:
             raise ValueError(f"{len(self.layer_types)} layer_types for "
                              f"{self.num_layers} layers")
-        if set(self.layer_types) - {"sliding_attention", "full_attention"}:
+        if set(self.layer_types) - {"sliding_attention", "full_attention",
+                                    "conv"}:
             raise ValueError(f"unknown layer type in {self.layer_types}")
+        if self.conv_bias:
+            raise ValueError("conv_bias: no configuration or reference "
+                             "states a conv layer with a bias")
+        if self.hybrid and self.moe and not self.dropless:
+            raise ValueError("conv layers beside capacity-dropped experts: "
+                             "a stack of two layer shapes has dense or "
+                             "dropless expert FFNs")
+        if self.hybrid and self.num_passes > 1:
+            raise NotImplementedError(
+                "a looped stack with conv layers: no model has one")
         if self.windowed and not self.sliding_window:
             raise ValueError("sliding_attention layers need a "
                              "sliding_window")
@@ -194,10 +229,40 @@ class LlamaConfig:
         return self.num_layers - self.num_dense_layers if self.moe else 0
 
     @property
+    def hybrid(self) -> bool:
+        """Whether some layers are gated short convolutions: a stack of two
+        weight shapes, kept and scanned run by run (:attr:`runs`)."""
+        return "conv" in self.layer_types
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers that keep conv state (``[conv_L_cache - 1, hidden]`` a
+        row) and no K and V."""
+        return self.layer_types.count("conv")
+
+    @property
     def cache_layers(self) -> int:
-        """Layers of K and V behind ``num_layers`` layers of weights: pass
-        ``t`` of layer ``l`` is cache layer ``t * num_layers + l``."""
-        return self.num_passes * self.num_layers
+        """Layers of K and V behind ``num_layers`` layers of weights, of
+        which the conv layers have none: pass ``t`` of attention layer ``l``
+        is cache layer ``t * (num_layers - conv_layers) + l``."""
+        return self.num_passes * (self.num_layers - self.conv_layers)
+
+    @property
+    def runs(self) -> Tuple[Tuple[str, bool, int], ...]:
+        """The stack as runs of consecutive layers of one weight shape, in
+        order: ``(operator, routed, layers)``, ``operator`` ``"conv"`` or
+        ``"attention"`` (sliding and full layers share a shape), ``routed``
+        whether the FFN is an expert layer."""
+        out = []
+        for at in range(self.num_layers):
+            kind = ("conv" if self.layer_types[at:at + 1] == ("conv",)
+                    else "attention",
+                    self.moe and at >= self.num_dense_layers)
+            if out and out[-1][0] == kind:
+                out[-1][1] += 1
+            else:
+                out.append([kind, 1])
+        return tuple(kind + (n,) for kind, n in out)
 
     @property
     def head_dim(self) -> int:
@@ -245,7 +310,7 @@ def init_params(key, cfg: LlamaConfig):
     def norm(k, *shape, fan_in=None):
         return fan_in_normal(k, *shape, fan_in=fan_in, dtype=dt)
 
-    if cfg.dropless:
+    if cfg.dropless or cfg.hybrid:
         return _init_dropless(key, cfg)
     layers = {
         "attn_norm": jnp.ones((L, h), dt),
@@ -293,11 +358,26 @@ def init_params(key, cfg: LlamaConfig):
     return params
 
 
-def _init_dropless(key, cfg: LlamaConfig):
-    """:func:`init_params` of a dropless expert model: the dense lead in
-    ``dense_layers`` (absent without one), the expert layers in ``layers``,
-    each stacked on dim 0; the expert weights are those of
-    ``cfg.experts_held`` alone, the router's all ``num_experts`` wide."""
+EXPERT_WEIGHTS = ("wg", "wu", "wd")
+
+
+def _experts_init(keys, n, cfg: LlamaConfig):
+    """The routed experts of ``n`` expert layers, ``[n, held, ...]`` a
+    weight (``EXPERT_WEIGHTS``), one key each."""
+    h, held = cfg.hidden_size, cfg.held[1]
+    f = cfg.moe_intermediate_size or cfg.intermediate_size
+    return {name: fan_in_normal(k, *shape, dtype=cfg.dtype)
+            for name, k, shape in zip(EXPERT_WEIGHTS, keys, (
+                (n, held, h, f), (n, held, h, f), (n, held, f, h)))}
+
+
+def _stack_init(key, n, cfg: LlamaConfig, operator: str, routed: bool,
+                experts: bool = True):
+    """``n`` layers of one weight shape, stacked on dim 0: the operator's
+    weights (attention's, or a gated short convolution's: ``conv_in`` ``[h,
+    3h]`` whose thirds are B, C and X, the taps ``conv_w`` ``[h,
+    conv_L_cache]``, ``conv_out``), the norms, and the FFN's (dense SwiGLU,
+    or the router and, with ``experts``, those of ``cfg.experts_held``)."""
     h, d, dt = cfg.hidden_size, cfg.head_dim, cfg.dtype
     nq, nkv = cfg.num_heads, cfg.num_kv_heads
     f = cfg.moe_intermediate_size or cfg.intermediate_size
@@ -305,56 +385,77 @@ def _init_dropless(key, cfg: LlamaConfig):
     def norm(k, *shape, fan_in=None):
         return fan_in_normal(k, *shape, fan_in=fan_in, dtype=dt)
 
-    def stack(key, n, ffn):
-        ks = jax.random.split(key, 6)
-        out = {"attn_norm": jnp.ones((n, h), dt),
-               "mlp_norm": jnp.ones((n, h), dt),
-               "wq": norm(ks[0], n, h, nq * d),
-               "wk": norm(ks[1], n, h, nkv * d),
-               "wv": norm(ks[2], n, h, nkv * d),
-               "wo": norm(ks[3], n, nq * d, h)}
-        if cfg.sandwich_norm:
-            out["attn_post_norm"] = jnp.ones((n, h), dt)
-            out["mlp_post_norm"] = jnp.ones((n, h), dt)
+    ks = jax.random.split(key, 6)
+    out = {"attn_norm": jnp.ones((n, h), dt),
+           "mlp_norm": jnp.ones((n, h), dt)}
+    if operator == "conv":
+        taps = cfg.conv_L_cache
+        out.update(conv_in=norm(ks[0], n, h, 3 * h),
+                   conv_w=norm(ks[1], n, h, taps, fan_in=taps),
+                   conv_out=norm(ks[2], n, h, h))
+    else:
+        out.update(wq=norm(ks[0], n, h, nq * d), wk=norm(ks[1], n, h, nkv * d),
+                   wv=norm(ks[2], n, h, nkv * d), wo=norm(ks[3], n, nq * d, h))
         if cfg.qk_norm:
             out["q_norm"] = jnp.ones((n, d), dt)
             out["k_norm"] = jnp.ones((n, d), dt)
         if cfg.attn_output_gate:
             out["wgate"] = norm(ks[4], n, h, nq * d)
-        out.update(ffn(ks[5], n))
+    if cfg.sandwich_norm:
+        out["attn_post_norm"] = jnp.ones((n, h), dt)
+        out["mlp_post_norm"] = jnp.ones((n, h), dt)
+    if not routed:
+        k, i = jax.random.split(ks[5], 3), cfg.intermediate_size
+        out.update(wg=norm(k[0], n, h, i), wu=norm(k[1], n, h, i),
+                   wd=norm(k[2], n, i, h))
         return out
+    k = jax.random.split(ks[5], 8)
+    fs = f * cfg.num_shared_experts
+    out["router"] = (jax.random.normal(k[0], (n, h, cfg.num_experts))
+                     * 0.02).astype(dt)
+    if experts:
+        out.update(_experts_init(k[1:4], n, cfg))
+    if cfg.router_bias:
+        out["router_bias"] = jnp.zeros((n, cfg.num_experts), jnp.float32)
+    if fs:
+        out.update(shared_wg=norm(k[4], n, h, fs),
+                   shared_wu=norm(k[5], n, h, fs),
+                   shared_wd=norm(k[6], n, fs, h))
+    return out
 
-    def dense(key, n):
-        k = jax.random.split(key, 3)
-        i = cfg.intermediate_size
-        return {"wg": norm(k[0], n, h, i), "wu": norm(k[1], n, h, i),
-                "wd": norm(k[2], n, i, h)}
 
-    def routed(key, n):
-        k = jax.random.split(key, 8)
-        held, fs = cfg.held[1], f * cfg.num_shared_experts
-        out = {"router": (jax.random.normal(k[0], (n, h, cfg.num_experts))
-                          * 0.02).astype(dt),
-               "wg": norm(k[1], n, held, h, f),
-               "wu": norm(k[2], n, held, h, f),
-               "wd": norm(k[3], n, held, f, h)}
-        if cfg.router_bias:
-            out["router_bias"] = jnp.zeros((n, cfg.num_experts),
-                                           jnp.float32)
-        if fs:
-            out.update(shared_wg=norm(k[4], n, h, fs),
-                       shared_wu=norm(k[5], n, h, fs),
-                       shared_wd=norm(k[6], n, fs, h))
-        return out
-
+def _init_dropless(key, cfg: LlamaConfig):
+    """:func:`init_params` of a dropless expert model, or of one with conv
+    layers. Where every layer is an attention layer: the dense lead in
+    ``dense_layers`` (absent without one), the expert layers in ``layers``,
+    each stacked on dim 0. Where some are conv layers: ``runs``, a tuple of
+    such stacks, one a run of ``cfg.runs``, none holding another shape's
+    weights; the routed experts of all its expert layers lie in one stack of
+    their own, ``experts`` (``[expert layers, held, ...]`` a weight), which
+    no scan slices (:func:`expert_stack`). The expert weights are those of
+    ``cfg.experts_held`` alone, the router's all ``num_experts`` wide."""
+    h, dt = cfg.hidden_size, cfg.dtype
     ks = jax.random.split(key, 4)
-    params = {"embed": norm(ks[0], cfg.vocab_size, h, fan_in=h),
-              "layers": stack(ks[1], cfg.expert_layers, routed),
+    params = {"embed": fan_in_normal(ks[0], cfg.vocab_size, h, fan_in=h,
+                                     dtype=dt),
               "final_norm": jnp.ones((h,), dt)}
-    if cfg.num_dense_layers:
-        params["dense_layers"] = stack(ks[2], cfg.num_dense_layers, dense)
+    if cfg.hybrid:
+        params["runs"] = tuple(
+            _stack_init(k, n, cfg, operator, routed, experts=False)
+            for k, (operator, routed, n) in zip(
+                jax.random.split(ks[1], len(cfg.runs)), cfg.runs))
+        if cfg.moe:
+            params["experts"] = _experts_init(
+                jax.random.split(ks[2], 3), cfg.expert_layers, cfg)
+    else:
+        params["layers"] = _stack_init(ks[1], cfg.expert_layers, cfg,
+                                       "attention", True)
+        if cfg.num_dense_layers:
+            params["dense_layers"] = _stack_init(
+                ks[2], cfg.num_dense_layers, cfg, "attention", False)
     if not cfg.tie_embeddings:
-        params["lm_head"] = norm(ks[3], h, cfg.vocab_size, fan_in=h)
+        params["lm_head"] = fan_in_normal(ks[3], h, cfg.vocab_size, fan_in=h,
+                                          dtype=dt)
     return params
 
 
@@ -414,16 +515,90 @@ def causal_attention(q, k, v, lp, cfg: LlamaConfig):
         lambda: flash_attention(q, k, v, causal=True, scale=scale))
 
 
+def conv_window(cfg: LlamaConfig, state=None, length=None):
+    """A conv layer's ``attend`` (:func:`block`) for a whole sequence or a
+    step from a row's state: ``window(u) -> (full, kept)``. ``full`` is ``u``
+    ``[b, s, h]`` behind the ``conv_L_cache - 1`` positions before it:
+    ``state`` ``[b, conv_L_cache - 1, h]``, or zeros at a sequence's start.
+    ``kept`` is the state a step at position ``length`` starts from (``u`` at
+    ``length - conv_L_cache + 1 .. length - 1``, zeros before position 0);
+    without ``length``, at the end of ``u``. A prompt padded to a bucket gives
+    its true length: the state is not the bucket's end's."""
+    past = cfg.conv_L_cache - 1
+
+    def window(u):
+        before = (jnp.zeros((u.shape[0], past, u.shape[2]), u.dtype)
+                  if state is None else state.astype(u.dtype))
+        full = jnp.concatenate([before, u], axis=1)
+        at = u.shape[1] if length is None else length
+        return full, jax.lax.dynamic_slice_in_dim(full, at, past, axis=1)
+
+    return window
+
+
+def short_conv(h, lp, cfg: LlamaConfig, window, mm=products):
+    """The gated short convolution on the normed stream ``h [b, s, h]``:
+    ``[B | C | X] = h W_in``; ``u = B * X``; ``c_t = sum_j w[:, j] *
+    u_{t - (K - 1) + j}`` (depthwise, causal, ``K = conv_L_cache`` taps, in
+    float32); ``y = (C * c) W_out``. ``window(u) -> (full, kept)`` is the
+    caller's (:func:`conv_window`): it puts ``u`` behind the ``K - 1``
+    positions before it and says what state is kept, as ``attend`` does for K
+    and V. One wording for a whole sequence and for one position from a
+    state. Returns ``(y, kept)``."""
+    with jax.named_scope("llama/short_conv"):
+        (bcx,) = mm(h, lp, "conv_in")
+        gate_in, gate_out, x = jnp.split(bcx, 3, axis=-1)
+        full, kept = window(gate_in * x)
+        s = h.shape[1]
+        taps = lp["conv_w"].astype(jnp.float32)
+        c = sum(taps[:, j] * full[:, j:j + s].astype(jnp.float32)
+                for j in range(cfg.conv_L_cache))
+        (y,) = mm(gate_out * c.astype(h.dtype), lp, "conv_out")
+        return y, kept
+
+
+def attention(h, lp, cfg: LlamaConfig, positions, attend, mm=products):
+    """Grouped-query attention on the normed stream: the projections, the
+    norm on each query and key head (``qk_norm``), the rotation, which a
+    layer of a kind that does not rotate passes by (:func:`_rotate`),
+    ``attend`` on the rotated heads, the sigmoid gate on the heads' output
+    (``attn_output_gate``) and the output projection. The window of a
+    sliding layer is ``attend``'s: it alone knows the keys. Returns ``(y,
+    kept)``."""
+    d = cfg.head_dim
+    with jax.named_scope("llama/attention"):
+        q, k, v, *gate = (
+            y.reshape(*y.shape[:2], -1, d) for y in mm(
+                h, lp, "wq", "wk", "wv",
+                *(("wgate",) if cfg.attn_output_gate else ())))
+        if cfg.qk_norm:
+            q = _rmsnorm(q, lp["q_norm"], cfg.rms_eps)
+            k = _rmsnorm(k, lp["k_norm"], cfg.rms_eps)
+        q, k = _rotate(q, k, lp, cfg, positions)
+        o, kept = attend(q, k, v)
+        o = o.reshape(*o.shape[:2], -1)
+        if gate:
+            o = o * jax.nn.sigmoid(gate[0].reshape(o.shape))
+        (y,) = mm(o, lp, "wo")
+        return y, kept
+
+
 def block(x, lp, cfg: LlamaConfig, positions, attend, mm=products, ffn=None):
     """The Llama decoder block on one layer's (unstacked) weights ``lp``:
-    the one place the sub-layer sequence is written. Training, ``generate``
-    and both serving programs call it and pass in what differs:
+    the one place the sub-layer sequence is written: the operator on the
+    normed stream, then the FFN on the normed stream, each added to the
+    residual. Training, ``generate`` and both serving programs call it and
+    pass in what differs:
 
-    - ``attend(q, k, v) -> (o, kept)``: attention on the rotated heads
+    - ``attend``, what the operator keeps and where. On an attention layer
+      ``attend(q, k, v) -> (o, kept)``: attention on the rotated heads
       (``q`` ``[b, s, nq, d]``, ``k`` and ``v`` ``[b, s, nkv, d]``) and where
       K and V live: nowhere (training), returned whole (the prefills), put
-      into a cache that is then attended to (the decode steps). ``kept`` is
-      handed back beside the stream, untouched.
+      into a cache that is then attended to (the decode steps). On a conv
+      layer (``lp`` has ``conv_in``: :func:`short_conv`) ``attend(u) ->
+      (full, kept)``: the positions before ``u`` and the state kept
+      (:func:`conv_window`). ``kept`` is handed back beside the stream,
+      untouched.
     - ``mm(x, lp, *names)``: ``x`` times each named weight, an iterable of
       one product a name. The names of one call share their input, so a
       hook that has to gather it (sequence parallelism) does so once a
@@ -431,28 +606,12 @@ def block(x, lp, cfg: LlamaConfig, positions, attend, mm=products, ffn=None):
     - ``ffn(h, lp)``: the feed-forward on the normed stream; without one,
       the dense SwiGLU through ``mm``.
 
-    What the config states of the attention block is here too: the norm on
-    each query and key head (``qk_norm``), the rotation, which a layer of
-    a kind that does not rotate passes by (:func:`_rotate`), and the
-    sigmoid gate on the heads' output (``attn_output_gate``). The window of
-    a sliding layer is ``attend``'s: it alone knows the keys.
-
     Returns ``(x, kept)``."""
-    d = cfg.head_dim
     h = _rmsnorm(x, lp["attn_norm"], cfg.rms_eps)
-    q, k, v, *gate = (
-        y.reshape(*y.shape[:2], -1, d) for y in mm(
-            h, lp, "wq", "wk", "wv",
-            *(("wgate",) if cfg.attn_output_gate else ())))
-    if cfg.qk_norm:
-        q = _rmsnorm(q, lp["q_norm"], cfg.rms_eps)
-        k = _rmsnorm(k, lp["k_norm"], cfg.rms_eps)
-    q, k = _rotate(q, k, lp, cfg, positions)
-    o, kept = attend(q, k, v)
-    o = o.reshape(*o.shape[:2], -1)
-    if gate:
-        o = o * jax.nn.sigmoid(gate[0].reshape(o.shape))
-    (y,) = mm(o, lp, "wo")
+    if "conv_in" in lp:
+        y, kept = short_conv(h, lp, cfg, attend, mm)
+    else:
+        y, kept = attention(h, lp, cfg, positions, attend, mm)
     x = x + post_norm(y, lp, "attn_post_norm", cfg)
     h = _rmsnorm(x, lp["mlp_norm"], cfg.rms_eps)
     if ffn is None:
@@ -465,13 +624,15 @@ def block(x, lp, cfg: LlamaConfig, positions, attend, mm=products, ffn=None):
 
 def expert_stack(params):
     """The routed experts' weights of every expert layer end to end, ``[L *
-    held, ...]`` (a bitcast of the ``[L, held, ...]`` stacks): what
-    :func:`moe_ffn` multiplies by, whole, the layer's own experts found in
-    it at ``lp["expert_at"]``. A scan over the layers that took them as its
-    ``xs`` would cut a layer's experts out of the stack every step, 1.8 GB
-    at Trinity's widths; the grouped products read them where they lie."""
-    return {n: params["layers"][n].reshape(
-        (-1,) + params["layers"][n].shape[2:]) for n in ("wg", "wu", "wd")}
+    held, ...]`` (a bitcast of the ``[L, held, ...]`` stacks, which a model
+    with conv layers keeps apart from its runs, in ``params["experts"]``):
+    what :func:`moe_ffn` multiplies by, whole, the layer's own experts found
+    in it at ``lp["expert_at"]``. A scan over the layers that took them as
+    its ``xs`` would cut a layer's experts out of the stack every step, 1.8
+    GB at Trinity's widths; the grouped products read them where they lie."""
+    held = params.get("experts", params.get("layers"))
+    return {n: held[n].reshape((-1,) + held[n].shape[2:])
+            for n in EXPERT_WEIGHTS}
 
 
 def moe_ffn(h, lp, cfg: LlamaConfig, experts, mm=products, valid=None):
@@ -489,7 +650,8 @@ def moe_ffn(h, lp, cfg: LlamaConfig, experts, mm=products, valid=None):
     w, idx = route(xt, lp["router"],
                    lp["router_bias"] if cfg.router_bias else None,
                    top_k=cfg.moe_top_k, score=cfg.router_score,
-                   route_norm=cfg.route_norm, route_scale=cfg.route_scale)
+                   route_norm=cfg.route_norm, route_scale=cfg.route_scale,
+                   norm_eps=cfg.route_norm_eps)
     y, counts = dropless_experts(
         xt, w, idx, experts, cfg.held,
         None if valid is None else valid.reshape(-1), lp["expert_at"])
@@ -582,12 +744,12 @@ def decoder_layer(x, lp, cfg: LlamaConfig, positions,
             return gather_from_sequence_parallel_region(h, tp_axis, seq_dim=1)
         return h
 
-    if (cfg.dropless or cfg.windowed) and (
+    if (cfg.dropless or cfg.windowed or cfg.hybrid) and (
             tp > 1 or sequence_parallel or _axis_bound(cp_axis)):
         raise NotImplementedError(
             "tensor, sequence and context parallelism of a model with "
-            "dropless experts or sliding-window layers are not built: its "
-            "plain forward runs, nothing trains it yet")
+            "dropless experts, sliding-window layers or conv layers are "
+            "not built: its plain forward runs, nothing trains it yet")
 
     def mm(h, lp, *names):
         if names[0] in ("wo", "wd", "shared_wd"):  # row kernels: sharded input
@@ -607,6 +769,10 @@ def decoder_layer(x, lp, cfg: LlamaConfig, positions,
         # GQA-aware flash attention: online softmax, no [s, s] matrix in
         # HBM fwd or bwd (jnp fallback off-TPU is the same math)
         return causal_attention(q, k, v, lp, cfg), None
+
+    if operator_of(lp) == "conv":       # a whole sequence, nothing kept
+        def attend(u):
+            return conv_window(cfg)(u)[0], None
 
     aux = jnp.zeros((), jnp.float32)
 
@@ -674,11 +840,11 @@ def run_layers(x, stacked, cfg: LlamaConfig, positions,
     (``jax.checkpoint_policies.dots_with_no_batch_dims_saveable``) — the
     usual best memory/MFU trade on TPU, where the recompute that hurts is
     the MXU work, not the VPU chains."""
-    if cfg.dropless or cfg.windowed:
+    if cfg.dropless or cfg.windowed or cfg.hybrid:
         raise NotImplementedError(
             "run_layers scans one stack of one layer kind; a model with a "
-            "dense lead or sliding-window layers goes through scan_passes "
-            "over stacks(params, cfg)")
+            "dense lead, sliding-window layers or conv layers goes through "
+            "scan_passes over stacks(params, cfg)")
     body = _layer_body(cfg, positions, tp_axis, cp_axis, sequence_parallel,
                        remat, ep_axis)
     x, auxs = jax.lax.scan(body, _ep_varying(x, cfg, ep_axis), stacked)
@@ -689,29 +855,41 @@ def stacks(params, cfg: LlamaConfig, **riders):
     """What :func:`scan_passes` takes as ``shared``: the model's layer
     stacks in the order a token goes through them. A model of one stack
     gives ``params["layers"]``; one with a dense lead gives the tuple
-    ``(params["dense_layers"], params["layers"])``. ``riders`` are put
-    among each stack's weights (the serving programs' ``scales``); where
-    the model has sliding layers every stack also carries ``sliding``, its
-    layers' kinds as a bool a layer, so that a scan step reads its kind as
-    it reads its weights. A dropless expert stack goes without its routed
-    experts' weights, which no scan may slice (:func:`expert_stack`), and
-    carries ``expert_at``, where each layer's experts begin in them."""
-    names = [n for n in ("dense_layers", "layers") if n in params]
-    out, first = [], 0
-    for name in names:
-        stack = {**params[name], **riders} if riders else params[name]
+    ``(params["dense_layers"], params["layers"])``; one with conv layers the
+    tuple of its runs (``params["runs"]``, one stack a run of ``cfg.runs``).
+    ``riders`` are put among each stack's weights (the serving programs'
+    ``scales``); where the model has sliding layers every stack also carries
+    ``sliding``, its layers' kinds as a bool a layer, so that a scan step
+    reads its kind as it reads its weights. A dropless expert stack goes
+    without its routed experts' weights, which no scan may slice
+    (:func:`expert_stack`), and carries ``expert_at``, where each layer's
+    experts begin in them."""
+    runs = params["runs"] if cfg.hybrid else [
+        params[n] for n in ("dense_layers", "layers") if n in params]
+    out, first, routed = [], 0, 0
+    for stack in runs:
+        if riders:
+            stack = {**stack, **riders}
         n = stack["attn_norm"].shape[0]
         if cfg.dropless and "router" in stack:
             stack = {k: v for k, v in stack.items()
-                     if k not in ("wg", "wu", "wd")}
-            stack["expert_at"] = jnp.arange(n, dtype=jnp.int32) * cfg.held[1]
+                     if k not in EXPERT_WEIGHTS}
+            stack["expert_at"] = jnp.arange(
+                routed, routed + n, dtype=jnp.int32) * cfg.held[1]
+            routed += n
         if cfg.windowed:
             kinds = np.asarray(cfg.layer_types[first:first + n])
             stack = {**stack, "sliding": jnp.asarray(
                 kinds == "sliding_attention")}
         out.append(stack)
         first += n
-    return out[0] if len(out) == 1 else tuple(out)
+    return out[0] if len(out) == 1 and not cfg.hybrid else tuple(out)
+
+
+def operator_of(stack) -> str:
+    """Which operator a stack's (or a layer's) weights are of: ``"conv"`` or
+    ``"attention"``. What a layer keeps follows it."""
+    return "conv" if "conv_in" in stack else "attention"
 
 
 def scan_passes(x, params, cfg: LlamaConfig, layer_fn, shared,
@@ -739,20 +917,30 @@ def scan_passes(x, params, cfg: LlamaConfig, layer_fn, shared,
     ``shared`` may be a tuple of stacks (:func:`stacks`: a dense lead, then
     the expert layers): one scan each, in turn, over its own slice of
     ``per_layer``; ``outs`` are the scans' laid end to end.
+
+    A model with conv layers (``cfg.hybrid``) is such a tuple, of runs of two
+    operators. What a layer owns and gives back follows its operator (K and
+    V, or a conv state), so there ``per_layer`` and ``outs`` are dicts by
+    operator (:func:`operator_of`), each with the leading axis of that
+    operator's layers, in layer order: a run takes its slice of its own
+    operator's entry, and the runs' outputs are laid end to end by operator.
     """
     L, T = cfg.num_layers, cfg.num_passes
     if isinstance(shared, tuple):
-        outs, first = [], 0
+        outs, first = {}, {}
         for stack in shared:
-            n = stack["attn_norm"].shape[0]
-            mine = jax.tree_util.tree_map(lambda a: a[first:first + n],
-                                          per_layer)
+            n, op = stack["attn_norm"].shape[0], operator_of(stack)
+            at = first.get(op, 0)
+            mine = jax.tree_util.tree_map(
+                lambda a: a[at:at + n],
+                per_layer[op] if cfg.hybrid and per_layer else per_layer)
             x, out = jax.lax.scan(lambda h, xs: layer_fn(h, *xs), x,
                                   (stack, mine))
-            outs.append(out)
-            first += n
-        return x, jax.tree_util.tree_map(
-            lambda *a: jnp.concatenate(a), *outs)
+            outs.setdefault(op, []).append(out)
+            first[op] = at + n
+        outs = {op: jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *o)
+                for op, o in outs.items()}
+        return x, outs if cfg.hybrid else outs["attention"]
     if T == 1:
         return jax.lax.scan(lambda h, xs: layer_fn(h, *xs), x,
                             (shared, per_layer))
@@ -825,6 +1013,8 @@ def hidden_states(params, tokens, cfg: LlamaConfig,
                        expert_stack(params) if cfg.dropless else None)
     x, auxs = scan_passes(_ep_varying(x, cfg, ep_axis), params, cfg,
                           lambda h, lp, _: body(h, lp), stacks(params, cfg))
+    if cfg.hybrid:                      # the runs' losses, by operator
+        auxs = jnp.concatenate(jax.tree_util.tree_leaves(auxs))
     return x, jnp.sum(auxs)
 
 
@@ -895,11 +1085,13 @@ def loss_fn(params, batch, cfg: LlamaConfig,
     so the fp32 ``[b·s, vocab]`` logits — the largest live buffer of an
     LLM step — are never materialized (functional/chunked_ce.py). With a
     bound ``tp_axis`` the per-rank streams merge vocab-parallel."""
-    if cfg.dropless:
+    if cfg.dropless or cfg.hybrid:
         raise NotImplementedError(
-            "training a dropless expert model is not built: its balance "
-            "loss and the router bias's update are training's, and the "
-            "Pallas backward of a windowed flash call raises")
+            "training a dropless expert model or a stack with conv layers "
+            "is not built: the balance loss and the router bias's update "
+            "are training's, the Pallas backward of a windowed flash call "
+            "raises, and no gradient of a stack of two layer shapes is "
+            "tested")
     tokens, targets = batch
     if vocab_chunks:
         from apex_tpu.transformer.functional.chunked_ce import (
@@ -930,50 +1122,51 @@ def param_specs(cfg: LlamaConfig, tp_axis: str = "tp",
     from jax.sharding import PartitionSpec as P
 
     t = tp_axis
-    layer_specs = {
-        "attn_norm": P(), "mlp_norm": P(),
-        "wq": P(None, None, t), "wk": P(None, None, t),
-        "wv": P(None, None, t), "wo": P(None, t, None),
-    }
+    norms = {"attn_norm": P(), "mlp_norm": P()}
     if cfg.sandwich_norm:
-        layer_specs.update(attn_post_norm=P(), mlp_post_norm=P())
+        norms.update(attn_post_norm=P(), mlp_post_norm=P())
+    attention = {"wq": P(None, None, t), "wk": P(None, None, t),
+                 "wv": P(None, None, t), "wo": P(None, t, None)}
     if cfg.qk_norm:
-        layer_specs.update(q_norm=P(), k_norm=P())
+        attention.update(q_norm=P(), k_norm=P())
     if cfg.attn_output_gate:
-        layer_specs["wgate"] = P(None, None, t)
+        attention["wgate"] = P(None, None, t)
+    # a conv layer's channels are its own: nothing of it is sharded
+    conv = {"conv_in": P(), "conv_w": P(), "conv_out": P()}
     dense_ffn = {"wg": P(None, None, t), "wu": P(None, None, t),
                  "wd": P(None, t, None)}
-    lead = None
     if cfg.dropless:
         # the experts held are this rank's own: nothing of them is sharded
         # further; the shared expert shards like a dense FFN
-        if cfg.num_dense_layers:
-            lead = {**layer_specs, **dense_ffn}
-        layer_specs.update(router=P(), wg=P(), wu=P(), wd=P())
+        routed_ffn = {"router": P(), "wg": P(), "wu": P(), "wd": P()}
         if cfg.router_bias:
-            layer_specs["router_bias"] = P()
+            routed_ffn["router_bias"] = P()
         if cfg.num_shared_experts:
-            layer_specs.update(shared_wg=P(None, None, t),
-                               shared_wu=P(None, None, t),
-                               shared_wd=P(None, t, None))
+            routed_ffn.update(shared_wg=P(None, None, t),
+                              shared_wu=P(None, None, t),
+                              shared_wd=P(None, t, None))
     elif cfg.moe:
         # experts shard over ep_axis (orthogonal to tp); router replicates
         e = ep_axis
-        layer_specs.update({
-            "router": P(),
-            "wg": P(None, e, None, None),
-            "wu": P(None, e, None, None),
-            "wd": P(None, e, None, None),
-        })
+        routed_ffn = {"router": P(), "wg": P(None, e, None, None),
+                      "wu": P(None, e, None, None),
+                      "wd": P(None, e, None, None)}
+    specs = {"embed": P(t, None), "final_norm": P()}
+    if cfg.hybrid:
+        runs = [{**norms, **(conv if operator == "conv" else attention),
+                 **(routed_ffn if routed else dense_ffn)}
+                for operator, routed, _ in cfg.runs]
+        if cfg.moe:
+            specs["experts"] = {n: P() for n in EXPERT_WEIGHTS}
+            runs = [{k: v for k, v in run.items()
+                     if not (kind[1] and k in EXPERT_WEIGHTS)}
+                    for run, kind in zip(runs, cfg.runs)]
+        specs["runs"] = tuple(runs)
     else:
-        layer_specs.update(dense_ffn)
-    specs = {
-        "embed": P(t, None),
-        "layers": layer_specs,
-        "final_norm": P(),
-    }
-    if lead is not None:
-        specs["dense_layers"] = lead
+        specs["layers"] = {**norms, **attention,
+                           **(routed_ffn if cfg.moe else dense_ffn)}
+        if cfg.dropless and cfg.num_dense_layers:
+            specs["dense_layers"] = {**norms, **attention, **dense_ffn}
     if not cfg.tie_embeddings:
         specs["lm_head"] = P(None, t)
     if cfg.num_passes > 1:

@@ -26,7 +26,9 @@ metrics are one step behind the device, never ahead of the host.
 
 The dump layout under ``dump_dir``:
 
-- ``kv_pages.npz`` — per-request gathered page arrays (written first);
+- ``kv_pages.npz`` — per-request gathered page arrays (``k_<rid>``,
+  ``v_<rid>``) and, for a model with conv layers, each request's conv state
+  as it lay in its slot (``s_<rid>``), restored bit for bit (written first);
 - ``state.json`` — schema, engine geometry, queued + in-flight request
   records, completed results (written LAST, atomically: its presence
   marks a complete dump).
@@ -77,9 +79,17 @@ class ServerMetrics:
         self.registry = registry
         self._occupancy = registry.gauge("serving/batch_occupancy")
         self._page_utilization = registry.gauge("serving/page_utilization")
+        self._page_pool_bytes = registry.gauge("serving/page_pool_bytes")
+        self._conv_state_bytes = registry.gauge("serving/conv_state_bytes")
         self._steps_in_flight = registry.counter(
             "serving/decode_steps_in_flight")
         self._rows_past_eos = registry.counter("serving/rows_past_eos")
+
+    def cache_built(self, cache) -> None:
+        """What the cache holds on the device: the page pool, and the conv
+        layers' state buffer beside it (0 for a model without)."""
+        self._page_pool_bytes.set(cache.page_pool_bytes())
+        self._conv_state_bytes.set(cache.state_bytes())
 
     def submitted(self) -> None:
         self.registry.counter("serving/requests_submitted").inc()
@@ -145,7 +155,8 @@ class ServingEngine:
                                              max_new_cap, page_size)
         if num_pages is None:
             self.page_budget = derive_page_budget(cfg, page_size,
-                                                  safety=hbm_safety)
+                                                  safety=hbm_safety,
+                                                  state_rows=max_batch)
             num_pages = min(self.page_budget.pages, need)
             one = pages_per_request(max_prompt_len, max_new_cap,
                                     page_size)
@@ -161,6 +172,7 @@ class ServingEngine:
             max_new_cap=max_new_cap, weight_mode=weight_mode,
             eos_id=eos_id)
         self.metrics = ServerMetrics(registry)
+        self.metrics.cache_built(self.scheduler.cache)
         self.watcher = watcher
         self.fault_plan = fault_plan
         self.dump_dir = dump_dir
@@ -340,9 +352,11 @@ class ServingEngine:
         pages_path = os.path.join(dump_dir, _PAGES_FILE)
         with np.load(pages_path) as pages:
             for rec in state["inflight"]:
+                conv = f"s_{rec['rid']}"
                 engine.scheduler.import_request(
                     rec, pages[f"k_{rec['rid']}"],
-                    pages[f"v_{rec['rid']}"])
+                    pages[f"v_{rec['rid']}"],
+                    pages[conv] if conv in pages else None)
                 engine.metrics.submitted()
                 engine.metrics.admitted()
         for rec in state["queued"]:

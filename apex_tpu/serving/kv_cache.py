@@ -10,6 +10,17 @@ own page lists; the scheduler maps them into a static ``[B, max_pages]``
 block table consumed by the jit decode step, so the device side never
 sees a dynamic shape.
 
+Layers that are gated short convolutions (``cfg.conv_layers`` of them) own
+no page: such a layer keeps ``[conv_L_cache - 1, hidden]`` a row, whatever
+the context. Their states lie beside the pages in one buffer ``[conv layers,
+conv_L_cache - 1, max_batch, hidden]`` (:attr:`PagedKVCache.conv_state`; the
+rows second to last, as the device tiles them: with the positions there the
+decode step converted the buffer on its way in and out), addressed by batch
+slot: written whole when a row is seated, rewritten by
+every decode step, dead when the row leaves (the next row's seating
+overwrites it), and carried by a dump beside the row's pages. ``defrag`` moves
+pages, not slots, and leaves it alone.
+
 Page ``P`` (the last one) is the *trash page*: inactive batch slots
 scatter their (masked, never-read) k/v writes there, which keeps the
 decode step total — no ``lax.cond`` per slot, no out-of-bounds scatter.
@@ -43,7 +54,9 @@ __all__ = [
     "PageBudget",
     "PagedKVCache",
     "derive_page_budget",
+    "page_dims",
     "page_hbm_bytes",
+    "state_hbm_bytes",
 ]
 
 
@@ -53,6 +66,29 @@ def page_hbm_bytes(cfg, page_size: int, dtype=None) -> int:
     itemsize = jnp.dtype(dtype).itemsize
     return (2 * cfg.cache_layers * page_size * cfg.num_kv_heads
             * cfg.head_dim * itemsize)
+
+
+def page_dims(cfg):
+    """The dims of one position of a page: ``(nkv, d)``; the heads side by
+    side, ``(nkv * d,)``, where one head is narrower than the 128 lanes of
+    the device's tiles and a position's heads together fill whole tiles. A
+    ``[..., 8, 64]`` buffer is padded to twice its bytes in the device's
+    default layout, and a decode step that prefers another converts the
+    whole pool on its way in and out (1.6 GB of copies a step at LFM2's
+    widths); ``[..., 512]`` is neither. The decode step and the transfers
+    follow the buffer's shape; K and V of a position are the same numbers in
+    the same order either way."""
+    nkv, d = cfg.num_kv_heads, cfg.head_dim
+    return (nkv * d,) if d % 128 and not (nkv * d) % 128 else (nkv, d)
+
+
+def state_hbm_bytes(cfg, rows: int, dtype=None) -> int:
+    """Modeled HBM bytes of the conv layers' state of ``rows`` batch rows:
+    ``conv_L_cache - 1`` positions of ``hidden`` a layer and row; 0 for a
+    model without conv layers."""
+    dtype = cfg.dtype if dtype is None else dtype
+    return (cfg.conv_layers * rows * (cfg.conv_L_cache - 1)
+            * cfg.hidden_size * jnp.dtype(dtype).itemsize)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,8 +101,9 @@ class PageBudget:
     ratio: float             # measured/modeled correction (1.0 = none)
     hbm_bytes: int           # device HBM limit used
     watermark_bytes: int     # bytes the device already holds, subtracted
-    usable_bytes: int        # hbm * safety - watermark (floored at 0)
+    usable_bytes: int        # hbm * safety - watermark - state (floored at 0)
     safety: float
+    state_bytes: int = 0     # the conv layers' state buffer, subtracted
 
 
 def derive_page_budget(cfg, page_size: int, *,
@@ -74,10 +111,12 @@ def derive_page_budget(cfg, page_size: int, *,
                        watermark_bytes: Optional[int] = None,
                        priors: Optional[dict] = None,
                        safety: float = 0.90,
-                       dtype=None) -> PageBudget:
+                       dtype=None, state_rows: int = 0) -> PageBudget:
     """Page budget from the live memory tier.
 
-    ``pages = floor((hbm × safety − watermark) / (page_bytes × ratio))``.
+    ``pages = floor((hbm × safety − watermark − state) / (page_bytes ×
+    ratio))``, ``state`` being the conv layers' state of ``state_rows`` batch
+    rows (:func:`state_hbm_bytes`), which lies beside the pages.
     Every input is overridable for tests; defaults read the live tier:
     ``device_hbm_bytes()``; the active ``MemoryMonitor`` watermark, or
     with no monitor attached the allocator's ``bytes_in_use`` (0 where
@@ -105,12 +144,15 @@ def derive_page_budget(cfg, page_size: int, *,
     if priors.get("backend") == jax.default_backend():
         ratio = prior_for("serving_decode_step", priors, default=True)
     page_bytes = page_hbm_bytes(cfg, page_size, dtype=dtype)
-    usable = max(0, int(hbm_bytes * safety) - int(watermark_bytes))
+    state_bytes = state_hbm_bytes(cfg, state_rows, dtype=dtype)
+    usable = max(0, int(hbm_bytes * safety) - int(watermark_bytes)
+                 - state_bytes)
     pages = int(usable // max(1, int(math.ceil(page_bytes * ratio))))
     return PageBudget(pages=pages, page_bytes=page_bytes, ratio=ratio,
                       hbm_bytes=int(hbm_bytes),
                       watermark_bytes=int(watermark_bytes),
-                      usable_bytes=usable, safety=safety)
+                      usable_bytes=usable, safety=safety,
+                      state_bytes=state_bytes)
 
 
 class PageAllocator:
@@ -176,24 +218,38 @@ def _serving_write_pages(buf, idx, payload):
     long as the write ran, which does not fit once the cache is most of
     the chip (a looped stack's is). ``payload`` is
     ``[L, n * page_size, nkv, d]`` (prefill's) or ``[L, n, page_size, nkv,
-    d]`` (a dump's). One compile per number of pages, named apart from the
+    d]`` (a dump's), laid out as the buffer's pages are (:func:`page_dims`).
+    One compile per number of pages, named apart from the
     decode step for the recompile listener."""
     pages = payload.astype(buf.dtype).reshape(
         buf.shape[0], idx.shape[0], *buf.shape[2:])
     return buf.at[:, idx].set(pages)
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _serving_write_state(buf, slot, state):
+    """``buf[:, :, slot] = state`` on the donated state buffer, in place: a
+    row's conv state ``[conv layers, conv_L_cache - 1, hidden]`` into its
+    batch slot. One compile, whatever the slot."""
+    return buf.at[:, :, slot].set(state.astype(buf.dtype))
+
+
 class PagedKVCache:
     """The device-side paged cache + its allocator.
 
-    Buffers are ``[L, P + 1, page_size, nkv, d]`` in ``cfg.dtype``, ``L``
-    being :attr:`layers` (``cfg.cache_layers``); the
+    Buffers are ``[L, P + 1, page_size, nkv, d]`` in ``cfg.dtype`` (``[L, P
+    + 1, page_size, nkv * d]`` where heads are narrow: :func:`page_dims`),
+    ``L`` being :attr:`layers` (``cfg.cache_layers``); the
     extra page at index ``P`` (:attr:`trash_page`) absorbs inactive-slot
     scatter writes. The scheduler donates both buffers into the decode
-    jit each step and stores the outputs back here.
+    jit each step and stores the outputs back here. A model with conv
+    layers has :attr:`conv_state` beside them, ``[cfg.conv_layers,
+    conv_L_cache - 1, max_batch, hidden]``, donated and stored back alike
+    (None for a model without).
     """
 
-    def __init__(self, cfg, num_pages: int, page_size: int, dtype=None):
+    def __init__(self, cfg, num_pages: int, page_size: int, dtype=None,
+                 max_batch: int = 0):
         self.cfg = cfg
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
@@ -201,9 +257,17 @@ class PagedKVCache:
         self.alloc = PageAllocator(self.num_pages)
         self.layers = int(cfg.cache_layers)
         shape = (self.layers, self.num_pages + 1, self.page_size,
-                 cfg.num_kv_heads, cfg.head_dim)
+                 *page_dims(cfg))
         self.k_pages = jnp.zeros(shape, self.dtype)
         self.v_pages = jnp.zeros(shape, self.dtype)
+        self.conv_state = None
+        if cfg.conv_layers:
+            if max_batch < 1:
+                raise ValueError("a model with conv layers keeps state a "
+                                 "batch row: the cache needs max_batch")
+            self.conv_state = jnp.zeros(
+                (cfg.conv_layers, cfg.conv_L_cache - 1, int(max_batch),
+                 cfg.hidden_size), self.dtype)
 
     @property
     def trash_page(self) -> int:
@@ -212,9 +276,17 @@ class PagedKVCache:
     def utilization(self) -> float:
         return self.alloc.num_used / self.num_pages
 
-    def hbm_bytes(self) -> int:
+    def page_pool_bytes(self) -> int:
         return 2 * int(np.prod(self.k_pages.shape)) * jnp.dtype(
             self.dtype).itemsize
+
+    def state_bytes(self) -> int:
+        """Bytes of the conv layers' state buffer (0 without one)."""
+        return 0 if self.conv_state is None else int(
+            self.conv_state.size) * jnp.dtype(self.dtype).itemsize
+
+    def hbm_bytes(self) -> int:
+        return self.page_pool_bytes() + self.state_bytes()
 
     # --------------------------------------------------------- transfers
 
@@ -254,13 +326,32 @@ class PagedKVCache:
         self.k_pages = _serving_write_pages(self.k_pages, idx, k)
         self.v_pages = _serving_write_pages(self.v_pages, idx, v)
 
+    # -------------------------------------------------------- conv state
+
+    def write_state(self, slot: int, state) -> None:
+        """A row's conv state ``[conv layers, conv_L_cache - 1, hidden]``
+        (its prefill's, or a dump's) into batch slot ``slot``, whole: what
+        the slot's last row left there is never read again."""
+        want = self.conv_state.shape[:2] + self.conv_state.shape[3:]
+        if tuple(state.shape) != want:
+            raise ValueError(f"conv state {tuple(state.shape)} does not fit "
+                             f"this cache ({want}: conv layers, positions "
+                             f"kept, hidden)")
+        self.conv_state = _serving_write_state(
+            self.conv_state, np.int32(slot), jnp.asarray(state))
+
+    def gather_state(self, slot: int):
+        """Batch slot ``slot``'s conv state to the host, as
+        :meth:`write_state` takes it: the dump's payload beside the pages."""
+        return np.asarray(self.conv_state[:, :, slot])
+
     # ------------------------------------------------------------ defrag
 
     def defrag(self) -> Dict[int, int]:
         """Compact live pages to the front; returns {old: new} so the
         caller can rewrite block tables. A no-op ({}), when already
         compact. One gather-permute per buffer — O(P), no per-page
-        copies."""
+        copies. Conv state is a slot's, not a page's: it stays."""
         live = self.alloc.live_pages()
         mapping = {old: new for new, old in enumerate(live)}
         if all(old == new for old, new in mapping.items()):

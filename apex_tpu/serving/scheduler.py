@@ -50,6 +50,17 @@ live pages, bounded below by each row's window, and a layer walks the list
 of its kind; the kind is data of the scan step. Pages below every sliding
 layer's window stay allocated: one table and one pool for all layers.
 
+Layers that are gated short convolutions (``cfg.hybrid``: ``llama.
+short_conv``) go through the same two shapes and own no page. Prefill hands
+back each conv layer's state at the prompt's true length beside K and V, the
+seating writes it to the row's batch slot of the cache's state buffer, and
+the decode step carries that buffer beside the page buffers, donated too: a
+conv layer's scan step reads its layer's ``[K - 1, max_batch, hidden]`` of
+it, puts the step's position behind it and writes the newest ``K - 1`` back
+where they lay (an inactive row's stay as they were). The stack is scanned
+run by run (``llama.stacks``: runs of one weight shape), K and V and the
+states indexed by their own operator's layers.
+
 Admission is FCFS: a request enters when a slot is free AND its whole
 page worst case (padded prompt + max_new_tokens) can be allocated, so
 an admitted request can never deadlock on pages mid-decode. Eviction
@@ -74,7 +85,7 @@ import numpy as np
 from apex_tpu.models import generate as _gen
 from apex_tpu.models import llama as _llama
 from apex_tpu.observability import get_tracer, host_span
-from apex_tpu.serving.kv_cache import PagedKVCache
+from apex_tpu.serving.kv_cache import PagedKVCache, state_hbm_bytes
 
 __all__ = [
     "ContinuousBatchScheduler",
@@ -239,13 +250,15 @@ def _attend_live_pages(q, kp, vp, at, live_pages, table_slots: int):
     row with no live page (not active) comes out as zeros."""
     row, slot, page, keys, n_chunks = live_pages
     b, _, nq, d = q.shape
-    nkv = kp.shape[2]
+    nkv = int(np.prod(kp.shape[2:])) // d
     qg = q.astype(jnp.float32).reshape(b, nkv, nq // nkv, d)
+
+    def heads(pages):               # [C, page, nkv, d], however a page lies
+        return pages.astype(jnp.float32).reshape(*pages.shape[:2], nkv, d)
 
     def chunk(c, parts):
         r, ok = row[c], keys[c]
-        kc = kp[at + page[c]].astype(jnp.float32)       # [C, page, nkv, d]
-        vc = vp[at + page[c]].astype(jnp.float32)
+        kc, vc = heads(kp[at + page[c]]), heads(vp[at + page[c]])
         s = jnp.einsum("ckrd,ctkd->ckrt", qg[r], kc) * (d ** -0.5)
         s = jnp.where(ok[:, None, None], s, _MASKED)
         m = jnp.max(s, -1)
@@ -272,6 +285,14 @@ def _refuse_unserved(cfg, weight_mode: str = "native") -> None:
             "serving runs dropless expert layers (moe_capacity_factor "
             "None): the capacity-dropped GShard/Mixtral form is training's "
             "and is not served")
+    if cfg.hybrid and not cfg.cache_layers:
+        raise NotImplementedError(
+            "a stack of conv layers alone: the page pool and both programs "
+            "count on at least one layer of K and V")
+    if cfg.hybrid and _normalize_weight_mode(weight_mode) == "fp8":
+        raise NotImplementedError(
+            "conv layers with fp8 weights: no static scales are made for "
+            "a stack kept in runs, nor for a conv operator's projections")
     if cfg.dropless and _normalize_weight_mode(weight_mode) == "fp8":
         raise NotImplementedError(
             "fp8 weights for a dropless expert model: no static scales "
@@ -280,10 +301,13 @@ def _refuse_unserved(cfg, weight_mode: str = "native") -> None:
 
 def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     """The ONE jit-compiled decode step (jit + donation is the
-    caller's: ``jax.jit(step, donate_argnums=(2, 3))``).
+    caller's: ``jax.jit(step, donate_argnums=(2, 3, 4))``).
 
-    ``(params, scales, k_pages, v_pages, tokens, tables, pos, active,
-    fresh, first) -> (next_tokens, k_pages, v_pages)`` — all batch
+    ``(params, scales, k_pages, v_pages, conv_state, tokens, tables, pos,
+    active, fresh, first) -> (next_tokens, k_pages, v_pages, conv_state)``
+    — one signature for every model, ``conv_state`` ``None`` in and out
+    where the model has no conv layers (no parameter, carry or output of
+    the traced program: such a model's program is what it was). All batch
     inputs are packed ``[max_batch]`` slot arrays; ``tables`` is
     ``[max_batch, max_pages]`` of page indices (trash-padded). Inactive
     slots write their k/v to the trash page and pass their token
@@ -301,6 +325,13 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     alone knows is merged here, in the one program: a row where
     ``fresh`` is set (admitted or restored since that step) takes its
     token from ``first``.
+
+    A model with conv layers (``cfg.hybrid``) gives the cache's
+    ``conv_state`` ``[conv layers, K - 1, max_batch, hidden]``, donated
+    like the pages, and gets it back updated. It rides the scan's carry
+    as the page buffers do: conv layer ``i``'s step reads ``conv_state[i]``,
+    puts the step's position behind it and writes the newest ``K - 1`` of
+    each active row back where they lay.
 
     Arguments 2 and 3 are the whole cache and come back updated. The
     layer scan carries them beside the residual stream and scans over
@@ -321,8 +352,8 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
     _refuse_unserved(cfg, weight_mode)
     mm = _products(weight_mode)
 
-    def _decode_step(params, scales, k_pages, v_pages, tokens, tables,
-                     pos, active, fresh, first):
+    def _decode_step(params, scales, k_pages, v_pages, conv_state, tokens,
+                     tables, pos, active, fresh, first):
         tokens = jnp.where(fresh, first, tokens[:tables.shape[0]])
         x = _llama.embed(params, tokens[:, None], cfg, tp_axis=None)
         shape = k_pages.shape           # [CL, P + 1, page, nkv, d]
@@ -347,7 +378,7 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
         flat = (shape[0] * stride,) + shape[2:]
 
         def body(carry, lp, i):
-            h, kp, vp = carry
+            h, kp, vp, *states = carry
             at = i * stride
             rows = at + page_idx
 
@@ -357,33 +388,58 @@ def build_decode_step(cfg, page_size: int, weight_mode: str = "native"):
                 mine = jax.tree_util.tree_map(lambda a: a[kind], live_pages)
 
             def attend(q, k, v):
-                kp1 = kp.at[rows, off].set(k[:, 0].astype(kp.dtype))
-                vp1 = vp.at[rows, off].set(v[:, 0].astype(vp.dtype))
+                kp1, vp1 = (buf.at[rows, off].set(new[:, 0].astype(
+                    buf.dtype).reshape(-1, *shape[3:]))
+                    for buf, new in ((kp, k), (vp, v)))
                 o = _attend_live_pages(q, kp1, vp1, at, mine, tables.size)
-                return o.astype(q.dtype), (kp1, vp1)
+                return o.astype(q.dtype), (kp1, vp1, *states)
+
+            if _llama.operator_of(lp) == "conv":
+                # conv layer i's rows of the carried state: read, the step's
+                # position put behind them, the newest written back in place
+                def attend(u):
+                    (buf,) = states                 # [layers, K - 1, b, h]
+                    past = jnp.swapaxes(buf[i], 0, 1)
+                    full, new = _llama.conv_window(cfg, state=past)(u)
+                    new = jnp.where(active[:, None, None],
+                                    new.astype(buf.dtype), past)
+                    return full, (kp, vp,
+                                  buf.at[i].set(jnp.swapaxes(new, 0, 1)))
 
             if cfg.dropless:
-                h, (kp, vp), counts = _llama.routed_block(
+                h, kept, counts = _llama.routed_block(
                     h, lp, experts, cfg, pos[:, None], attend, mm,
                     active[:, None])
-                return (h, kp, vp), counts
-            h, (kp, vp) = _llama.block(h, lp, cfg, pos[:, None], attend, mm)
-            return (h, kp, vp), None
+                return (h, *kept), counts
+            h, kept = _llama.block(h, lp, cfg, pos[:, None], attend, mm)
+            return (h, *kept), None
 
-        (x, k_pages, v_pages), counts = _llama.scan_passes(
-            (x, k_pages.reshape(flat), v_pages.reshape(flat)), params, cfg,
-            body, _llama.stacks(params, cfg, scales=scales),
-            jnp.arange(shape[0]))
+        per_layer = jnp.arange(shape[0])
+        if cfg.hybrid:
+            per_layer = {"attention": per_layer,
+                         "conv": jnp.arange(cfg.conv_layers)}
+        (x, k_pages, v_pages, *states), counts = _llama.scan_passes(
+            (x, k_pages.reshape(flat), v_pages.reshape(flat),
+             *(() if conv_state is None else (conv_state,))), params, cfg,
+            body, _llama.stacks(params, cfg, scales=scales), per_layer)
         k_pages, v_pages = k_pages.reshape(shape), v_pages.reshape(shape)
         logits = _gen._logits(params, x, cfg)[:, 0]
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         nxt = jnp.where(active, nxt, tokens)
         if cfg.dropless:
             # the counts ride the array the host fetches anyway
-            nxt = jnp.concatenate([nxt, jnp.sum(counts, axis=0)])
-        return nxt, k_pages, v_pages
+            nxt = jnp.concatenate([nxt, _summed(counts)])
+        return nxt, k_pages, v_pages, (states[0] if states else None)
 
     return _decode_step
+
+
+def _summed(counts):
+    """A program's per-layer expert counts ``[layers, 2]``, summed over the
+    layers; a stack with conv layers gives them by operator, as a dict."""
+    leaves = jax.tree_util.tree_leaves(counts)
+    return jnp.sum(leaves[0] if len(leaves) == 1
+                   else jnp.concatenate(leaves), axis=0)
 
 
 def build_prefill(cfg, bucket_len: int, weight_mode: str = "native"):
@@ -393,11 +449,16 @@ def build_prefill(cfg, bucket_len: int, weight_mode: str = "native"):
     being ``cfg.cache_layers``.
 
     For a dropless expert model ``first_token`` is two entries longer,
-    as the decode step's array is (:data:`EXPERT_COUNTS`).
+    as the decode step's array is (:data:`EXPERT_COUNTS`). For a model with
+    conv layers a fourth output follows ``vs``: each conv layer's state
+    ``[conv layers, K - 1, hidden]`` at ``true_len``.
 
     Causal flash attention means the pad suffix never contaminates
     real positions; the pad k/v land in the request's pages but decode
-    overwrites index ``p + t`` before ever unmasking it. The jit is
+    overwrites index ``p + t`` before ever unmasking it. A causal
+    convolution's outputs are as safe; the state it hands on is not the
+    bucket's end's: it is taken at ``true_len`` (``llama.conv_window``),
+    zeros before position 0 for a prompt shorter than ``K - 1``. The jit is
     named per bucket so prefill compiles never count against the
     decode step's zero-retrace guard.
     """
@@ -411,7 +472,7 @@ def build_prefill(cfg, bucket_len: int, weight_mode: str = "native"):
         experts = _llama.expert_stack(params) if cfg.dropless else None
 
         def layer(h, lp, _):
-            attend = _gen._prefill_attend(lp, cfg)
+            attend = _gen._prefill_attend(lp, cfg, true_len)
             if cfg.dropless:        # the pad suffix routes nowhere
                 h, kept, counts = _llama.routed_block(
                     h, lp, experts, cfg, positions, attend, mm,
@@ -421,15 +482,21 @@ def build_prefill(cfg, bucket_len: int, weight_mode: str = "native"):
 
         x, kept = _llama.scan_passes(
             x, params, cfg, layer, _llama.stacks(params, cfg, scales=scales))
-        (ks, vs), counts = kept if cfg.dropless else (kept, None)
+        # what the layers kept, by operator; a dropless layer's counts beside
+        by_op = kept if cfg.hybrid else {"attention": kept}
+        if cfg.dropless:
+            counts = _summed({op: c for op, (_, c) in by_op.items()})
+            by_op = {op: k for op, (k, _) in by_op.items()}
+        ks, vs = by_op["attention"]
         x_last = jax.lax.dynamic_slice_in_dim(x, true_len - 1, 1,
                                               axis=1)
         logits = _gen._logits(params, x_last, cfg)[:, 0]
         first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         if cfg.dropless:
-            first = jnp.concatenate([first, jnp.sum(counts, axis=0)])
+            first = jnp.concatenate([first, counts])
         return (first, ks[:, 0].astype(cfg.dtype),
-                vs[:, 0].astype(cfg.dtype))
+                vs[:, 0].astype(cfg.dtype)) + (
+            (by_op["conv"][:, 0].astype(cfg.dtype),) if cfg.hybrid else ())
 
     prefill.__name__ = f"_serving_prefill_s{bucket_len}"
     prefill.__qualname__ = prefill.__name__
@@ -475,12 +542,18 @@ class ContinuousBatchScheduler:
                 f"num_pages={num_pages} cannot hold even one "
                 f"worst-case request ({self.max_pages_per_req} pages "
                 f"for prompt {max_prompt_len} + {max_new_cap} new)")
-        self.cache = PagedKVCache(cfg, num_pages, page_size)
+        self.cache = PagedKVCache(cfg, num_pages, page_size,
+                                  max_batch=self.max_batch)
         # what the admit and decode records say of the model's depth: the
-        # layers a token goes through (the stack's, times its passes) and
-        # the layers of K and V a page holds
+        # layers a token goes through (the stack's, times its passes), the
+        # layers of K and V a page holds and, where there are any, the conv
+        # layers, which hold state a row and no page
         self._depth = {"layer_passes": cfg.num_passes * cfg.num_layers,
                        "cache_layers": self.cache.layers}
+        if cfg.hybrid:
+            self._depth["conv_layers"] = cfg.conv_layers
+        # one row's conv state over all conv layers, in bytes (0 without)
+        self._row_state_bytes = state_hbm_bytes(cfg, 1, self.cache.dtype)
         if cfg.dropless:
             self._depth.update(expert_layers=cfg.expert_layers,
                                experts_held=cfg.held[1])
@@ -504,7 +577,7 @@ class ContinuousBatchScheduler:
                         if self.weight_mode == "fp8" else {})
         self._decode = jax.jit(
             build_decode_step(cfg, self.page_size, self.weight_mode),
-            donate_argnums=(2, 3))
+            donate_argnums=(2, 3, 4))
         self._prefills: Dict[int, object] = {}
         self.decode_steps = 0
         self.prefill_count = 0
@@ -603,7 +676,7 @@ class ContinuousBatchScheduler:
                                                req.rid)
                 prompt = np.zeros((1, s_pad), np.int32)
                 prompt[0, :p] = req.prompt
-                first, ks, vs = self._prefill_for(s_pad)(
+                first, ks, vs, *state = self._prefill_for(s_pad)(
                     self.params, self._scales, jnp.asarray(prompt),
                     np.int32(p))
             self.prefill_count += 1
@@ -621,13 +694,20 @@ class ContinuousBatchScheduler:
             if self._is_finished(req, t0):
                 self._retire(req)
                 return False
-            self._seat(req, pages, p)
+            self._seat(req, pages, p, *state)
             return True
 
-    def _seat(self, req: Request, pages, pos: int) -> None:
+    def _seat(self, req: Request, pages, pos: int, state=None) -> None:
         """Give ``req`` a free slot: its next step reads its newest token
-        from the host and writes position ``pos`` of ``pages``."""
+        from the host and writes position ``pos`` of ``pages``; ``state``,
+        its conv layers' at ``pos``, goes into the slot whole, over whatever
+        the slot's last row left."""
         slot = self.slots.index(None)
+        if state is not None:
+            with host_span("serving/write_state", rid=req.rid,
+                           conv_layers=self.cfg.conv_layers,
+                           bytes=self._row_state_bytes):
+                self.cache.write_state(slot, state)
         self.slots[slot] = req
         req.state = "active"
         self._first[slot] = req.tokens[-1]
@@ -681,6 +761,12 @@ class ContinuousBatchScheduler:
                 pages_gathered_window=pages_read(near, self._tables.size),
                 positions=int(seen.sum()), positions_window=int(
                     np.minimum(seen, self.cfg.sliding_window).sum()))
+        if self.cfg.hybrid:
+            # the conv state the step reads and rewrites: its active rows';
+            # the positions its attention layers read
+            fields.update(
+                state_bytes=self._row_state_bytes * self.num_active(),
+                positions=int((self._pos[self._active] + 1).sum()))
         in_flight = int(self._unlanded is not None)
         self.steps_in_flight += in_flight
         with host_span("serving/decode", rows=self.num_active(),
@@ -693,10 +779,11 @@ class ContinuousBatchScheduler:
                 mirrors = (jnp.asarray(a.copy()) for a in (
                     self._tables, self._pos, self._active, self._fresh,
                     self._first))
-                self._newest, self.cache.k_pages, self.cache.v_pages = \
-                    self._decode(
-                        self.params, self._scales, self.cache.k_pages,
-                        self.cache.v_pages, self._newest, *mirrors)
+                cache = self.cache
+                (self._newest, cache.k_pages, cache.v_pages,
+                 cache.conv_state) = self._decode(
+                    self.params, self._scales, cache.k_pages, cache.v_pages,
+                    cache.conv_state, self._newest, *mirrors)
             self.decode_steps += 1
             if self._decode_compiles0 is None:
                 self._decode_compiles0 = self._recompiles.compiles(
@@ -797,7 +884,9 @@ class ContinuousBatchScheduler:
         """Emergency-dump payload: (queued records, inflight records,
         {name: numpy} page arrays). Inflight k/v pages are gathered so
         resume restores them by scatter — re-prefilling would re-run
-        float math and forfeit bit-identical resumption. Every token made
+        float math and forfeit bit-identical resumption; a row's conv
+        state (``s_<rid>``, where the model has conv layers) goes beside
+        them, as it lies in the row's slot. Every token made
         has to be on the host: the caller lands the step in flight first
         (:meth:`land`), so a record's ``tokens`` and ``pos`` agree."""
         if self._unlanded is not None:
@@ -813,6 +902,8 @@ class ContinuousBatchScheduler:
             k, v = self.cache.gather_pages(pages)
             arrays[f"k_{req.rid}"] = k
             arrays[f"v_{req.rid}"] = v
+            if self.cfg.hybrid:
+                arrays[f"s_{req.rid}"] = self.cache.gather_state(slot)
             rec = self._req_record(req)
             rec.update(pos=int(self._pos[slot]),
                        tokens=[int(t) for t in req.tokens],
@@ -820,9 +911,13 @@ class ContinuousBatchScheduler:
             inflight.append(rec)
         return queued, inflight, arrays
 
-    def import_request(self, rec: dict, k, v) -> Request:
+    def import_request(self, rec: dict, k, v, state=None) -> Request:
         """Rebuild one in-flight request from a dump record + its
-        gathered pages (resume path)."""
+        gathered pages and, where the model has conv layers, its conv
+        state (resume path)."""
+        if (state is None) == self.cfg.hybrid:
+            raise ValueError("a dumped request has conv state if, and only "
+                             "if, the model has conv layers")
         req = Request(rid=rec["rid"],
                       prompt=np.asarray(rec["prompt"], np.int32),
                       max_new_tokens=rec["max_new_tokens"],
@@ -833,5 +928,5 @@ class ContinuousBatchScheduler:
         self.cache.restore_pages(pages, k, v)
         req.tokens = list(rec["tokens"])
         req.first_token_s = time.monotonic()
-        self._seat(req, pages, rec["pos"])
+        self._seat(req, pages, rec["pos"], state)
         return req

@@ -258,14 +258,16 @@ def moe_mlp(params, x, cfg: MoEConfig, ep_axis: Optional[str] = EXPERT_AXIS,
 
 
 def route(x, router, bias=None, *, top_k: int, score: str = "softmax",
-          route_norm: bool = True, route_scale: float = 1.0):
+          route_norm: bool = True, route_scale: float = 1.0,
+          norm_eps: float = 1e-20):
     """Token-choice routing of ``x [T, h]`` over ALL ``E`` experts of
     ``router [h, E]``: ``(weights [T, k] float32, experts [T, k] int32)``.
 
     Scores are the softmax or the sigmoid of the float32 logits. ``bias``
     ``[E]`` enters the selection only: the ``top_k`` are taken of ``score +
     bias``, the weights are the scores themselves. ``route_norm`` divides a
-    token's weights by their sum; ``route_scale`` multiplies them. Softmax
+    token's weights by their sum plus ``norm_eps`` (as published: 1e-20, or
+    1e-6); ``route_scale`` multiplies them. Softmax
     with ``route_norm`` and ``k > 1`` is Mixtral's gate
     (``generate._moe_router_weights``)."""
     if score not in ("softmax", "sigmoid"):
@@ -281,7 +283,7 @@ def route(x, router, bias=None, *, top_k: int, score: str = "softmax",
         _, idx = jax.lax.top_k(chosen, top_k)
         w = jnp.take_along_axis(scores, idx, axis=-1)
         if route_norm:
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + norm_eps)
         return w * route_scale, idx.astype(jnp.int32)
 
 

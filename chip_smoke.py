@@ -27,6 +27,13 @@ supports (weights random, from a seed), and checks what comes out:
              that the windowed flash forward and a prefill cut by the window
              run, checked on logits against
              ``perfbench/references/afmoe.py``;
+- *lfm2*     ``ServingEngine`` on the nine layers of
+             ``perfbench/configs/lfm2_24b_a2b_d9.json`` at LFM2-24B-A2B's
+             widths (seven gated short-convolution layers beside two
+             attention layers, a dense lead, all 64 experts of eight expert
+             layers held: 9.64 GiB of weights), four rows, prompts that end
+             inside their bucket and one shorter than the convolution,
+             checked on logits against ``perfbench/references/lfm2_moe.py``;
 - *mesh*     (4+ chips) the same GPT-2 step on a dp=2 x tp=2 mesh, and a
              dp=4 DDP step through ``sync_autodiff_gradients``.
 
@@ -726,6 +733,58 @@ def phase_serve_afmoe(cfg_dict=None, mix=AFMOE_MIX, max_batch=4,
                                     ref.QUERY_BLOCK, describe)
 
 
+# The cut of LFM2-24B-A2B as the benchmark serves it (its configuration file,
+# every width and all 64 experts a layer), a small batch: prompts that end 24
+# and 36 short of their buckets (the conv state is taken at the true length
+# under padding), one on a page's edge and one shorter than the convolution.
+LFM2_MIX = ((1000, 16), (1500, 24), (640, 8), (2, 12))
+# bf16 through nine layers, all experts held: a token whose fourth and fifth
+# expert scores lie a rounding apart is routed otherwise than the float32
+# reference routes it and moves a logit by an expert's whole contribution, in
+# every one of eight layers. These 60 tokens read 0.13-0.48 (my chip run, PR
+# 35); the benchmark's samples of 700 read up to 1.94 on sound runs, and a
+# token drawn at random 4.3 in the mean (PERF.md, How correct is decided): the
+# limit stands between, no limit on precision. A conv state taken at the
+# bucket's end, B and C swapped or the taps reversed read like a wrong token
+# (tests/run_serving, float32: over 0.01 against 0).
+LFM2_GAP = 2.5
+
+
+def phase_serve_lfm2(cfg_dict=None, mix=LFM2_MIX, max_batch=4,
+                     page_size=128, gap_limit=LFM2_GAP) -> dict:
+    """Gated short-convolution layers beside attention layers, a dense lead
+    and dropless experts all held, through the engine, its pages and its
+    state buffer, against the plain reference's full forward: logits, not
+    tokens."""
+    from perfbench.references import lfm2_moe as ref
+    from perfbench.runners import serve_lfm2
+
+    if cfg_dict is None:
+        with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               "perfbench", "configs",
+                               "lfm2_24b_a2b_d9.json")) as f:
+            cfg_dict = json.load(f)
+    cfg = serve_lfm2.model_config(cfg_dict)
+
+    def describe(engine):
+        cache = engine.scheduler.cache
+        if (cache.layers, cache.conv_state.shape[0]) != (
+                cfg.cache_layers, cfg.conv_layers):
+            raise AssertionError(
+                f"the cache has {cache.layers} layers of pages and "
+                f"{cache.conv_state.shape[0]} of state, not "
+                f"{cfg.cache_layers} and {cfg.conv_layers}")
+        say(f"  {cfg.conv_layers} conv + {cfg.cache_layers} attention "
+            f"layers, {cfg.expert_layers} of them with {cfg.num_experts} "
+            f"experts: k_pages {tuple(cache.k_pages.shape)}, conv_state "
+            f"{tuple(cache.conv_state.shape)} {cache.k_pages.dtype}; page "
+            f"budget {engine.page_budget}")
+
+    return _serve_against_reference(ref, cfg_dict, cfg, mix, max_batch,
+                                    page_size, gap_limit, 2 ** 31 + 35,
+                                    ref.QUERY_BLOCK, describe)
+
+
 # --------------------------------------------------------------------- mesh
 
 
@@ -896,6 +955,7 @@ def main() -> int:
     run("serve", phase_serve)
     run("looped", phase_serve_looped)
     run("afmoe", phase_serve_afmoe)
+    run("lfm2", phase_serve_lfm2)
     if device["count"] >= 4:
         run("mesh", phase_mesh, train["first_loss"])
     else:

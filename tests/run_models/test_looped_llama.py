@@ -153,7 +153,7 @@ def test_a_plain_config_is_what_it_was():
                 (8,) + pages.shape[1:], cfg.dtype),
             pages if passes == 1 else jax.ShapeDtypeStruct(
                 (8,) + pages.shape[1:], cfg.dtype),
-            i32(3), i32(3, 2), i32(3), jax.ShapeDtypeStruct((3,), bool),
+            None, i32(3), i32(3, 2), i32(3), jax.ShapeDtypeStruct((3,), bool),
             jax.ShapeDtypeStruct((3,), bool), i32(3))
         scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
         assert [e.params["length"] for e in scans] == [2 * passes]

@@ -266,7 +266,7 @@ def one_step(step, params, placed, others_active):
     source[[at for at in range(ROWS) if at not in placed]] = 2, 3, 4
     active = np.full(ROWS, others_active)
     active[list(placed)] = True
-    nxt, k1, v1 = step(params, {}, k0, v0, *(
+    nxt, k1, v1, _ = step(params, {}, k0, v0, None, *(
         jnp.asarray(a[source]) for a in (tokens, tables, pos)),
         jnp.asarray(active), jnp.zeros(ROWS, bool), jnp.zeros(ROWS, jnp.int32))
     wrote = [(slice(None), tables[r, pos[r] // PAGE], pos[r] % PAGE)

@@ -91,7 +91,7 @@ def test_the_compiled_step_holds_the_page_buffers_once(one_chip, config, mix,
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         compiled = step.lower(
-            params, {}, pages, pages, struct((rows,), jnp.int32),
+            params, {}, pages, pages, None, struct((rows,), jnp.int32),
             struct((rows, table), jnp.int32), struct((rows,), jnp.int32),
             struct((rows,), jnp.bool_), struct((rows,), jnp.bool_),
             struct((rows,), jnp.int32)).compile()
@@ -149,7 +149,7 @@ def test_the_expert_models_step_reads_its_experts_where_they_lie(one_chip):
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         compiled = step.lower(
-            params, {}, pages, pages,
+            params, {}, pages, pages, None,
             struct((rows + len(sched.EXPERT_COUNTS),), jnp.int32),
             struct((rows, table), jnp.int32), struct((rows,), jnp.int32),
             struct((rows,), jnp.bool_), struct((rows,), jnp.bool_),
@@ -167,6 +167,92 @@ def test_the_expert_models_step_reads_its_experts_where_they_lie(one_chip):
     assert not re.findall(
         r"= bf16\[(?:1,)?32,3072,3072\]\S* (?:dynamic-slice|copy|fusion)\(",
         text)
+
+
+def test_the_hybrid_models_step_holds_its_state_buffer_once(one_chip):
+    """ISSUE 35's cell: nine layers at LFM2-24B-A2B's widths, seven of them
+    gated short convolutions. The conv layers' state buffer `[7, 2, 32,
+    2048]` is donated and rides the scan's carry beside the page buffers:
+    the outputs alias all three arguments and no second copy of it is made;
+    the pool has pages of the two attention layers alone (0.5 MiB a page:
+    ISSUE 35's "1 MiB" doubles it); all
+    64 experts of the eight expert layers are read where they lie, `[512,
+    ...]` a weight, and no layer's are cut out; the runs' scans are five
+    loops, the list's loop inside the attention runs' alone."""
+    from perfbench.runners import serve_lfm2
+
+    cfg = serve_lfm2.model_config(read("configs", "lfm2_24b_a2b_d9"))
+    eng = read("traffic", "docextract_backlog")["engine"]
+    page, rows = eng["page_size"], eng["max_batch"]
+    table = sched.pages_per_request(eng["max_prompt_len"], eng["max_new_cap"],
+                                    page)
+    assert (rows, table, rows * table) == (32, 33, eng["num_pages"])
+    assert (cfg.cache_layers, cfg.conv_layers) == (2, 7)
+    from apex_tpu.serving.kv_cache import (page_dims, page_hbm_bytes,
+                                           state_hbm_bytes)
+    # heads of 64 lie side by side in a page: `[..., 8, 64]` would be padded
+    # to twice its bytes and converted whole, in and out, every step
+    assert page_dims(cfg) == (512,)
+    shape = (2, eng["num_pages"] + 1, page, 512)
+    state_shape = (7, 2, rows, cfg.hidden_size)
+    assert page_hbm_bytes(cfg, page) == 2 ** 19
+    assert state_hbm_bytes(cfg, rows) == int(np.prod(state_shape)) * 2
+
+    def struct(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+    assert sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(
+        shapes)) == 5_177_950_464 + 8 * 64
+    params = jax.tree_util.tree_map(lambda a: struct(a.shape, a.dtype),
+                                    shapes)
+    pages, state = struct(shape, cfg.dtype), struct(state_shape, cfg.dtype)
+    step = jax.jit(sched.build_decode_step(cfg, page),
+                   donate_argnums=(2, 3, 4))
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = step.lower(
+            params, {}, pages, pages, state,
+            struct((rows + len(sched.EXPERT_COUNTS),), jnp.int32),
+            struct((rows, table), jnp.int32), struct((rows,), jnp.int32),
+            struct((rows,), jnp.bool_), struct((rows,), jnp.bool_),
+            struct((rows,), jnp.int32)).compile()
+        prefill = sched.build_prefill(cfg, 4096).lower(
+            params, {}, struct((1, 4096), jnp.int32),
+            struct((), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    memory = compiled.memory_analysis()
+    state_bytes = int(np.prod(state_shape)) * 2
+    assert memory.alias_size_in_bytes == (
+        2 * int(np.prod(shape)) * 2 + state_bytes)
+    assert memory.temp_size_in_bytes < 0.1 * 2 ** 30
+    weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes
+    assert 9.6 < weights / 2 ** 30 < 9.7
+    text = compiled.as_text()
+    assert "jit__decode_step" in text
+    assert len(re.findall(r" custom-call\(.*ragged-dot", text)) >= 3
+    # neither the state buffer, the pool nor a layer's experts is ever
+    # copied whole
+    assert not re.findall(
+        r"= bf16\[(?:7,2,32,2048|2,1057,128,512|2114,128,512)\]\S* copy\(",
+        text)
+    assert not re.findall(
+        r"= bf16\[(?:1,)?64,(?:2048,1536|1536,2048)\]\S* "
+        r"(?:dynamic-slice|copy|fusion)\(", text)
+    bodies = [computations(text)[name] for name in re.findall(
+        r" while\(.*body=(%?[\w.\-]+)", text)]
+    nested = [sum(" while(" in line for line in body) for body in bodies]
+    print("while loops and those nested in each:", sorted(nested))
+    # a prefill of the longest bucket fits beside the weights and the cache
+    pre = prefill.memory_analysis()
+    print("decode temp", memory.temp_size_in_bytes / 2 ** 20, "MiB; prefill",
+          "temp", pre.temp_size_in_bytes / 2 ** 20, "MiB, out",
+          pre.output_size_in_bytes / 2 ** 20, "MiB")
+    held = weights + memory.alias_size_in_bytes
+    assert (held + pre.temp_size_in_bytes + pre.output_size_in_bytes
+            ) / 2 ** 30 < 14.0
 
 
 TINY = {"hidden_size": 64, "intermediate_size": 128,

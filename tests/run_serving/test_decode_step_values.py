@@ -85,9 +85,11 @@ def test_the_carried_cache_equals_the_plain_loop(stack, mode):
     want = jax.jit(plain_loop, static_argnums=2)(params, scales, cfg, k0, v0)
     step = jax.jit(sched.build_decode_step(cfg, PAGE, mode),
                    donate_argnums=(2, 3))
-    got = step(params, scales, k0 + 0, v0 + 0, jnp.asarray(TOKENS),
-               jnp.asarray(TABLES), jnp.asarray(POS), jnp.asarray(ACTIVE),
-               jnp.zeros(len(TOKENS), bool), jnp.zeros(len(TOKENS), jnp.int32))
+    *got, no_state = step(
+        params, scales, k0 + 0, v0 + 0, None, jnp.asarray(TOKENS),
+        jnp.asarray(TABLES), jnp.asarray(POS), jnp.asarray(ACTIVE),
+        jnp.zeros(len(TOKENS), bool), jnp.zeros(len(TOKENS), jnp.int32))
+    assert no_state is None
     for name, g, w in zip(("tokens", "k_pages", "v_pages"), got, want):
         assert g.shape == w.shape and g.dtype == w.dtype, name
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))

@@ -108,8 +108,8 @@ def test_the_list_step_is_the_whole_table_step(stack, case, monkeypatch):
     tokens, tables, pos, active = map(jnp.asarray, batch(case))
     want = whole_table_step(cfg)(params, k0, v0, tokens, tables, pos, active)
     step = jax.jit(sched.build_decode_step(cfg, PAGE), donate_argnums=(2, 3))
-    got = step(params, {}, k0 + 0, v0 + 0, tokens, tables, pos, active,
-               *NONE_FRESH)
+    got = step(params, {}, k0 + 0, v0 + 0, None, tokens, tables, pos, active,
+               *NONE_FRESH)[:3]
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
     for g, w in zip(got[1:], want[1:]):
         assert np.isfinite(np.asarray(g)).all()
@@ -172,7 +172,7 @@ def two_rows_of(placed, others_active, step, cfg):
     source[[at for at in range(ROWS) if at not in placed]] = 1, 2, 3, 5
     active = np.full(ROWS, others_active)
     active[list(placed)] = True
-    nxt, k1, v1 = step(params_of(cfg), {}, k0, v0, *(
+    nxt, k1, v1, _ = step(params_of(cfg), {}, k0, v0, None, *(
         jnp.asarray(a[source]) for a in (tokens, tables, pos)),
         jnp.asarray(active), *NONE_FRESH)
     wrote = [(slice(None), tables[r, pos[r] // PAGE], pos[r] % PAGE)

@@ -73,3 +73,18 @@ def test_the_expert_cells_order_is_the_steadier_one():
     # quartile distance and deviation, as shares: the mix's note and PERF.md
     assert own[0] < 0.007 and own[1] < 0.0045
     assert zero[0] > 0.011 and zero[1] > 0.008
+
+
+def test_the_hybrid_cells_order_is_the_steadier_one():
+    """`lfm2_24b_a2b_d9.docextract_backlog` (PR 35): the mix's own order of
+    its 4,096 requests against the order of seed 0, at that cell's prices and
+    with pages on its two attention layers alone."""
+    with open(os.path.join(ROOT, "perfbench", "traffic",
+                           "docextract_backlog.json")) as f:
+        mix = json.load(f)
+    name = "docextract_backlog"
+    assert tool.CELLS[name][1] == dict(sliding=0, full=2, window=1 << 30)
+    own = tool.of_order(mix, mix["order_seed"], 45.0, name)
+    zero = tool.of_order(mix, 0, 45.0, name)
+    assert own[0] < 0.0065 and own[1] < 0.005
+    assert zero[0] > 0.009 and zero[1] > 0.006

@@ -102,6 +102,39 @@ def test_phase_serve_afmoe_tiny(interpret, monkeypatch):
         chip_smoke.phase_serve_afmoe(tiny, gap_limit=1e-3, **kw)
 
 
+def test_phase_serve_lfm2_tiny(interpret, monkeypatch):
+    """The cut's nine layers at tiny widths (seven conv layers, two attention
+    layers whose heads lie side by side in a page, a dense lead, eight
+    experts all held), prompts that end inside their bucket and one shorter
+    than the convolution, float32: the engine's tokens are the plain
+    reference's to round-off; with the conv state taken at the bucket's end
+    they are not."""
+    from perfbench.references import lfm2_moe
+
+    tiny = dict(hidden_size=64, num_attention_heads=8, num_key_value_heads=4,
+                head_dim=32, intermediate_size=128, moe_intermediate_size=48,
+                num_hidden_layers=9, num_dense_layers=1,
+                layer_types=["conv"] + ["full_attention", "conv", "conv",
+                                        "conv"] * 2,
+                num_experts=8, num_experts_per_tok=4, norm_eps=1e-5,
+                rope_parameters={"rope_theta": 1000000}, norm_topk_prob=True,
+                routed_scaling_factor=1, use_expert_bias=True, conv_L_cache=3,
+                conv_bias=False, vocab_size=256, torch_dtype="float32",
+                tie_word_embeddings=True, max_position_embeddings=256)
+    monkeypatch.setattr(lfm2_moe, "QUERY_BLOCK", 8)
+    kw = dict(mix=((21, 8), (8, 10), (30, 4), (2, 6)), max_batch=2,
+              page_size=8)
+    out = chip_smoke.phase_serve_lfm2(tiny, gap_limit=1e-3, **kw)
+    assert 0 <= out["widest_gap"] <= 1e-3
+    from apex_tpu.models import generate
+
+    true = generate._prefill_attend
+    monkeypatch.setattr(generate, "_prefill_attend",
+                        lambda lp, cfg, length=None: true(lp, cfg))
+    with pytest.raises(AssertionError, match="over the limit"):
+        chip_smoke.phase_serve_lfm2(tiny, gap_limit=1e-3, **kw)
+
+
 def test_phases_refuse_the_jnp_path():
     """Outside interpret mode on the CPU the kernels take their jnp path;
     the HLO check must catch that, not pass it."""

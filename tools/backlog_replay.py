@@ -1,9 +1,10 @@
 #!/usr/bin/env python3
-"""python tools/backlog_replay.py [--orders N]
+"""python tools/backlog_replay.py [--orders N] [--mix NAME]
 
-How far the place where `--seed` enters the cycle of lengths of the expert
-cell's backlog (`trinity_large_ep8_d5.longctx_backlog`) moves
-`serve_tokens_per_s`, without a chip: the engine's schedule replayed on the
+How far the place where `--seed` enters the cycle of lengths of an expert
+cell's backlog (`trinity_large_ep8_d5.longctx_backlog`, or with `--mix
+docextract_backlog` the hybrid cell's, `lfm2_24b_a2b_d9.docextract_backlog`)
+moves `serve_tokens_per_s`, without a chip: the engine's schedule replayed on the
 host for every entry at once. Rows are filled first come first served, every
 admission costs its prompt, every decode step a constant and a price for each
 page its rows attend to (a windowed model's sliding layers count the pages of
@@ -34,6 +35,13 @@ PRICES = dict(step_ms=9.83, page_ms=0.00119, prompt_ms_per_ktok=19.46,
               admit_ms=9.34)
 LAYERS = dict(sliding=4, full=1, window=4096)     # its `layer_types`
 MIX = "longctx_backlog"
+# the hybrid cell's (PR 35, PERF.md §6): two full attention layers hold
+# pages, seven conv layers none; prices from the spans of its chip runs
+CELLS = {MIX: (PRICES, LAYERS),
+         "docextract_backlog": (
+             dict(step_ms=15.6, page_ms=0.0006, prompt_ms_per_ktok=8.2,
+                  admit_ms=39.0),
+             dict(sliding=0, full=2, window=1 << 30))}
 
 
 def replay(prompts, outputs, starts, mix, seconds, step_ms, page_ms,
@@ -90,16 +98,17 @@ def spread(rates):
             float((rates.max() - rates.min()) / np.mean(rates)))
 
 
-def of_order(mix, order_seed, seconds):
+def of_order(mix, order_seed, seconds, name=MIX):
     """`spread` over every entry of the mix's cycle laid out by
-    `order_seed`, at the cell's prices."""
+    `order_seed`, at the prices of the cell of mix `name`."""
     from perfbench import traffic
 
     n = int(mix["arrivals"]["requests"])
     prompts, outputs = traffic._lengths(
         mix, n, np.random.default_rng(int(order_seed)))
+    prices, layers = CELLS[name]
     return spread(replay(prompts, outputs, np.arange(n), mix, seconds,
-                         **PRICES))
+                         layers=layers, **prices))
 
 
 def main(argv=None):
@@ -108,11 +117,12 @@ def main(argv=None):
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--orders", type=int, default=1,
                         help="also replay the orders of seeds 0..N-1")
+    parser.add_argument("--mix", default=MIX, choices=sorted(CELLS))
     args = parser.parse_args(argv)
-    mix = traffic.load_mix(harness.ROOT, MIX)
+    mix = traffic.load_mix(harness.ROOT, args.mix)
     seconds = harness.load_json(harness.ROOT, "BENCHMARK.json")["run_seconds"]
     own = int(mix["order_seed"])
-    found = {seed: of_order(mix, seed, seconds)
+    found = {seed: of_order(mix, seed, seconds, args.mix)
              for seed in {own, *range(args.orders)}}
     for seed, (iqr, dev, span) in sorted(found.items(),
                                          key=lambda kv: kv[1][1])[:10]:

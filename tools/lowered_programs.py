@@ -43,7 +43,8 @@ HERE = pathlib.Path(__file__).resolve().parent.parent
 SERVING_CELLS = (("mistral7b_v03_d16", "longgen_backlog"),
                  ("mistral7b_v03_d16", "longprompt_poisson"),
                  ("ouro_2p6b", "reasoning_backlog"),
-                 ("trinity_large_ep8_d5", "longctx_backlog"))
+                 ("trinity_large_ep8_d5", "longctx_backlog"),
+                 ("lfm2_24b_a2b_d9", "docextract_backlog"))
 
 
 def serving_programs():
@@ -54,7 +55,7 @@ def serving_programs():
 
     from apex_tpu.models import llama
     from apex_tpu.ops import pallas_config
-    from apex_tpu.serving import scheduler as sched
+    from apex_tpu.serving import kv_cache, scheduler as sched
     from perfbench import harness
     import importlib
 
@@ -85,13 +86,23 @@ def serving_programs():
             lambda a: struct(a.shape, a.dtype),
             jax.eval_shape(
                 lambda: llama.init_params(jax.random.PRNGKey(0), cfg)))
-        pages = struct((cfg.cache_layers, eng["num_pages"] + 1, page,
-                        cfg.num_kv_heads, cfg.head_dim), cfg.dtype)
+        # a page's minor dims as the cache lays them (heads side by side
+        # where they are narrow), and a conv model's state buffer beside
+        dims = (kv_cache.page_dims(cfg) if hasattr(kv_cache, "page_dims")
+                else (cfg.num_kv_heads, cfg.head_dim))
+        pages = struct((cfg.cache_layers, eng["num_pages"] + 1, page, *dims),
+                       cfg.dtype)
+        held = [pages, pages]
         bucket = eng["max_prompt_len"]
         step = sched.build_decode_step(cfg, page)
+        if "conv_state" in inspect.signature(step).parameters:
+            # since PR 35: the conv layers' state, None where there are none
+            held.append(struct((cfg.conv_layers, cfg.conv_L_cache - 1, rows,
+                                cfg.hidden_size), cfg.dtype)
+                        if cfg.hybrid else None)
         batch = [struct((rows,), jnp.int32), struct((rows, table), jnp.int32),
                  struct((rows,), jnp.int32), struct((rows,), jnp.bool_)]
-        if len(inspect.signature(step).parameters) == 10:
+        if len(inspect.signature(step).parameters) >= 10:
             # since PR 34: the step before's tokens as the device holds them
             # (the counts an expert model appends with them), and the host's
             # patch for the rows admitted since
@@ -100,8 +111,9 @@ def serving_programs():
             batch += [struct((rows,), jnp.bool_), struct((rows,), jnp.int32)]
         with pallas_config.force("on"):
             programs = {
-                "decode": jax.jit(step, donate_argnums=(2, 3)).lower(
-                    params, {}, pages, pages, *batch),
+                "decode": jax.jit(step, donate_argnums=tuple(
+                    range(2, 2 + len(held)))).lower(
+                    params, {}, *held, *batch),
                 f"prefill{bucket}": sched.build_prefill(cfg, bucket).lower(
                     params, {}, struct((1, bucket), jnp.int32),
                     struct((), jnp.int32))}
