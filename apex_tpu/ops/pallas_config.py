@@ -59,7 +59,7 @@ _KERNEL_AUTO_EVIDENCE = {
 # else is a typo that would silently never be consulted
 KNOWN_KERNELS = frozenset(
     {"flash_attention", "layer_norm", "rms_norm", "fused_softmax",
-     "flat_adam", "fp8_cast"})
+     "flat_adam", "fp8_cast", "gmm"})
 
 
 def _env_json(name: str, shape_hint: str):
@@ -140,7 +140,7 @@ def use_pallas(kernel: str | None = None) -> bool:
     """Should fused ops take their Pallas path right now?
 
     ``kernel`` (optional) names the caller ('layer_norm', 'rms_norm',
-    'flash_attention', 'fused_softmax', 'flat_adam') so measured
+    'flash_attention', 'fused_softmax', 'flat_adam', 'gmm') so measured
     per-kernel verdicts from :data:`_KERNEL_AUTO` apply under 'auto' —
     including verdicts the persistent tuning cache supplies for the
     current device generation (see :func:`_ensure_tuning_applied`).

@@ -31,11 +31,13 @@ products over the assignments sorted by expert.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.ops import grouped_matmul, pallas_config
 # dispatch/combine/expert einsums contract over the (large) token and
 # capacity axes — bf16 partial sums there lose real gate mass, so the
 # accumulator is pinned >= fp32 (apex_tpu.analysis lowprec-accum)
@@ -299,17 +301,20 @@ def dropless_experts(x, weights, idx, experts, held=None, valid=None, at=0):
 
     ``experts`` may hold the experts of several layers end to end, ``[G, h,
     f]`` with this layer's ``count`` from group ``at`` on (``at`` may be
-    traced: a scan step's own). The products then run over all ``G`` groups,
-    every group of another layer empty: a layer scan reads its experts where
-    they lie, with no ``[count, h, f]`` slice cut out of the stack a step.
+    traced: a scan step's own), every group of another layer empty: a layer
+    scan reads its experts where they lie, with no ``[count, h, f]`` slice
+    cut out of the stack a step.
 
     No capacity and no drop, at static shapes: the ``T * k`` assignments
     are sorted by expert, those to experts not held last; each of the three
-    products is one ``jax.lax.ragged_dot`` over the sorted rows, grouped by
-    the held experts' counts; the rows are put back in order, weighted and
-    summed a token (in float32, slot by slot: a token's result is made of
-    its own rows alone). ``valid [T]`` leaves a token's assignments out (a
-    padded position, an empty batch row).
+    products is one grouped product over the sorted rows, grouped by the
+    held experts' counts: on the TPU the Pallas kernel ``apex_gmm``
+    (``ops/grouped_matmul.gmm``, which walks this layer's ``count`` groups
+    and the rows they have, under ``pallas_config.use_pallas("gmm")``),
+    elsewhere ``jax.lax.ragged_dot`` over all ``G`` groups; the rows are put
+    back in order, weighted and summed a token (in float32, slot by slot: a
+    token's result is made of its own rows alone). ``valid [T]`` leaves a
+    token's assignments out (a padded position, an empty batch row).
 
     Returns ``(y [T, h], counts)``; ``counts`` is int32 ``[2]``: the
     assignments that fell on held experts, and the held experts that got at
@@ -329,9 +334,15 @@ def dropless_experts(x, weights, idx, experts, held=None, valid=None, at=0):
         sizes = jnp.zeros(groups + 1, jnp.int32).at[key].add(1)[:groups]
         xs = x[order // k]
         wg, wu, wd = (experts[n].astype(x.dtype) for n in ("wg", "wu", "wd"))
-        g = jax.lax.ragged_dot(xs, wg, sizes)
-        u = jax.lax.ragged_dot(xs, wu, sizes)
-        ys = jax.lax.ragged_dot(jax.nn.silu(g) * u, wd, sizes)
+        if pallas_config.use_pallas("gmm"):
+            # the layer's own groups alone, found in the stack at `at`
+            product = functools.partial(grouped_matmul.gmm, sizes=sizes,
+                                        at=at, count=count)
+        else:
+            product = functools.partial(jax.lax.ragged_dot,
+                                        group_sizes=sizes)
+        g, u = product(xs, wg), product(xs, wu)
+        ys = product(jax.nn.silu(g) * u, wd)
         back = jnp.zeros_like(order).at[order].set(
             jnp.arange(t * k, dtype=jnp.int32))
         # a row past the groups belongs to no product: whatever it holds,

@@ -113,7 +113,7 @@ KERNEL_SHAPES = dict(
     flash=(2, 2048, 16, 8, 128), prefill_len=520,
     norm_rows=8192, ln_hidden=1024, rms_hidden=2048,
     causal_softmax=(128, 1024), masked_softmax=(64, 512),
-    adam_n=1_000_000, fp8=(2048, 2048))
+    adam_n=1_000_000, fp8=(2048, 2048), gmm=(4096, 2048, 1536, 64))
 
 
 def _pallas_vs_jnp(fn, *args):
@@ -332,6 +332,28 @@ def _kernel_checks(shapes):
         _close(got_y, want_y, rtol=0.13, atol=2.0 ** -9, what="fp8 y")
         _close(got_amax, want_amax, rtol=0, atol=0, what="fp8 amax")
 
+    def grouped_matmul():
+        # the dropless expert layers' product: sorted rows on some of a
+        # layer's groups, the layer found at a traced offset in a stack of
+        # three; rows of no group are unspecified and left out
+        from apex_tpu.ops.grouped_matmul import gmm
+        m, k, n, held = shapes["gmm"]
+        x = jax.random.normal(jax.random.PRNGKey(18), (m, k), jnp.bfloat16)
+        w = jax.random.normal(jax.random.PRNGKey(19), (3 * held, k, n),
+                              jnp.bfloat16) * k ** -0.5
+        own = np.random.default_rng(0).multinomial(
+            m - m // 8, np.full(held, 1 / held)) * (np.arange(held) % 5 > 0)
+        sizes = jnp.zeros(3 * held, jnp.int32).at[held:2 * held].set(own)
+        with pallas_config.force(_kernel_mode()):
+            product = jax.jit(lambda x, w, sizes, at: gmm(x, w, sizes, at,
+                                                          held))
+            _expect_mosaic(product.lower(x, w, sizes, jnp.int32(held))
+                           .as_text(), "apex_gmm")
+            got = sync(product(x, w, sizes, jnp.int32(held)))
+        want = sync(jax.jit(jax.lax.ragged_dot)(x, w, sizes))
+        rows = int(own.sum())
+        _close(got[:rows], want[:rows], what="grouped matmul")
+
     return [("flash_fwd_causal_gqa", flash_fwd),
             ("flash_bwd_causal_gqa", flash_bwd),
             ("flash_fwd_prefill_len", flash_prefill),
@@ -344,7 +366,8 @@ def _kernel_checks(shapes):
             ("masked_softmax", masked_softmax),
             ("softmax_bwd", softmax_bwd),
             ("flat_adam_kernel", flat_adam),
-            ("fp8_cast_kernel", fp8_cast)]
+            ("fp8_cast_kernel", fp8_cast),
+            ("grouped_matmul", grouped_matmul)]
 
 
 def phase_kernels(shapes=KERNEL_SHAPES) -> dict:

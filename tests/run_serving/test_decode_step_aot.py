@@ -31,6 +31,7 @@ from jax.sharding import SingleDeviceSharding
 
 from apex_tpu.models import generate as gen
 from apex_tpu.models import llama
+from apex_tpu.ops import pallas_config
 from apex_tpu.serving import scheduler as sched
 from perfbench import harness
 from perfbench.references import llama_dense, ouro_looped
@@ -51,6 +52,12 @@ def one_chip():
 
 def read(kind, name):
     return harness.load_json(harness.ROOT, "perfbench", kind, name + ".json")
+
+
+def gmm_calls(text):
+    """The compiled program's calls of the Pallas grouped product: Mosaic
+    custom calls named after `pallas_call(name="apex_gmm")`."""
+    return re.findall(r"%apex_gmm[\w.\-]* = .* custom-call\(", text)
 
 
 def computations(text):
@@ -122,8 +129,9 @@ def test_the_expert_models_step_reads_its_experts_where_they_lie(one_chip):
     layers holds the page buffers once too, and no layer's experts are cut
     out of their stack: a scan that took the `[4, 32, 3072, 3072]` weights as
     its `xs` compiled to a 604 MB dynamic-slice a weight and layer (0.57 GiB
-    of temporaries, 14 GB moved a step). The grouped products are XLA's
-    ragged dot over the whole stack, one a weight."""
+    of temporaries, 14 GB moved a step). The grouped products are the Pallas
+    kernel `apex_gmm` (ISSUE 36), one a weight, which finds the layer's 32
+    groups in the whole stack; XLA's ragged dot is in the step no more."""
     from perfbench.runners import serve_afmoe
 
     cfg = serve_afmoe.model_config(read("configs", "trinity_large_ep8_d5"))
@@ -148,12 +156,13 @@ def test_the_expert_models_step_reads_its_experts_where_they_lie(one_chip):
     step = jax.jit(sched.build_decode_step(cfg, page), donate_argnums=(2, 3))
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        compiled = step.lower(
-            params, {}, pages, pages, None,
-            struct((rows + len(sched.EXPERT_COUNTS),), jnp.int32),
-            struct((rows, table), jnp.int32), struct((rows,), jnp.int32),
-            struct((rows,), jnp.bool_), struct((rows,), jnp.bool_),
-            struct((rows,), jnp.int32)).compile()
+        with pallas_config.force("on"):      # as on the chip: 'auto' sees a CPU
+            compiled = step.lower(
+                params, {}, pages, pages, None,
+                struct((rows + len(sched.EXPERT_COUNTS),), jnp.int32),
+                struct((rows, table), jnp.int32), struct((rows,), jnp.int32),
+                struct((rows,), jnp.bool_), struct((rows,), jnp.bool_),
+                struct((rows,), jnp.int32)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
     memory = compiled.memory_analysis()
@@ -163,7 +172,7 @@ def test_the_expert_models_step_reads_its_experts_where_they_lie(one_chip):
     weights = memory.argument_size_in_bytes - memory.alias_size_in_bytes
     assert 8.0 < weights / 2 ** 30 < 8.1
     text = compiled.as_text()
-    assert len(re.findall(r" custom-call\(.*ragged-dot", text)) >= 3
+    assert len(gmm_calls(text)) >= 3 and "ragged-dot" not in text
     assert not re.findall(
         r"= bf16\[(?:1,)?32,3072,3072\]\S* (?:dynamic-slice|copy|fusion)\(",
         text)
@@ -212,15 +221,16 @@ def test_the_hybrid_models_step_holds_its_state_buffer_once(one_chip):
                    donate_argnums=(2, 3, 4))
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        compiled = step.lower(
-            params, {}, pages, pages, state,
-            struct((rows + len(sched.EXPERT_COUNTS),), jnp.int32),
-            struct((rows, table), jnp.int32), struct((rows,), jnp.int32),
-            struct((rows,), jnp.bool_), struct((rows,), jnp.bool_),
-            struct((rows,), jnp.int32)).compile()
-        prefill = sched.build_prefill(cfg, 4096).lower(
-            params, {}, struct((1, 4096), jnp.int32),
-            struct((), jnp.int32)).compile()
+        with pallas_config.force("on"):      # as on the chip: 'auto' sees a CPU
+            compiled = step.lower(
+                params, {}, pages, pages, state,
+                struct((rows + len(sched.EXPERT_COUNTS),), jnp.int32),
+                struct((rows, table), jnp.int32), struct((rows,), jnp.int32),
+                struct((rows,), jnp.bool_), struct((rows,), jnp.bool_),
+                struct((rows,), jnp.int32)).compile()
+            prefill = sched.build_prefill(cfg, 4096).lower(
+                params, {}, struct((1, 4096), jnp.int32),
+                struct((), jnp.int32)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
     memory = compiled.memory_analysis()
@@ -232,7 +242,8 @@ def test_the_hybrid_models_step_holds_its_state_buffer_once(one_chip):
     assert 9.6 < weights / 2 ** 30 < 9.7
     text = compiled.as_text()
     assert "jit__decode_step" in text
-    assert len(re.findall(r" custom-call\(.*ragged-dot", text)) >= 3
+    assert len(gmm_calls(text)) >= 3 and "ragged-dot" not in text
+    assert len(gmm_calls(prefill.as_text())) >= 3
     # neither the state buffer, the pool nor a layer's experts is ever
     # copied whole
     assert not re.findall(

@@ -4,7 +4,9 @@ the selection only; `route_norm` and `route_scale`; every token on one expert
 and none dropped; the softmax mode against `generate._moe_router_weights`;
 the experts of several layers end to end; and the add-up test: the shares of
 an expert-parallel deployment, each computed alone, with the shared expert
-counted once, give the uncut layer of the plain reference."""
+counted once, give the uncut layer of the plain reference. Every test runs
+twice (ISSUE 36): over `jax.lax.ragged_dot`, the grouped product off the TPU,
+and over the body of the Pallas kernel `apex_gmm`, interpreted."""
 
 import jax
 import jax.numpy as jnp
@@ -12,10 +14,18 @@ import numpy as np
 import pytest
 
 from apex_tpu.models import generate as gen, llama
+from apex_tpu.ops import pallas_config
 from apex_tpu.transformer.moe import dropless_experts, route
 from perfbench.references import afmoe as ref
 
 T, H, F, E, K = 24, 16, 12, 8, 2
+
+
+@pytest.fixture(autouse=True, params=["ragged_dot", "apex_gmm"])
+def grouped_product(request):
+    with pallas_config.force(
+            "interpret" if request.param == "apex_gmm" else "off"):
+        yield
 
 
 def weights(seed=0, e=E):

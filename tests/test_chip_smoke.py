@@ -22,7 +22,7 @@ TINY_KERNELS = dict(
     flash=(2, 64, 4, 2, 16), prefill_len=40,
     norm_rows=64, ln_hidden=128, rms_hidden=128,
     causal_softmax=(4, 32), masked_softmax=(8, 32),
-    adam_n=5000, fp8=(64, 128))
+    adam_n=5000, fp8=(64, 128), gmm=(80, 32, 128, 5))
 # heads divide tp=2, the vocabulary divides tp x 8 chunks, batch divides dp=4
 TINY_GPT2 = gpt2.tiny(vocab_size=256, hidden_size=64, num_layers=2,
                       num_heads=4, max_seq_len=32)
@@ -35,7 +35,7 @@ def interpret():
 
 
 def test_phase_kernels_tiny(interpret):
-    assert chip_smoke.phase_kernels(TINY_KERNELS) == {"checks": 13}
+    assert chip_smoke.phase_kernels(TINY_KERNELS) == {"checks": 14}
 
 
 def test_phase_train_then_mesh_tiny():

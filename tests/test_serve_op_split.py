@@ -52,6 +52,7 @@ def test_operations_are_booked_to_their_program_and_named_by_its_scopes():
                      ("jit__decode_step", 60 * ms, 12 * ms),
                      ("jit__serving_write_pages", 80 * ms, ms)]},
         ops={0: [(conv, ms, 2 * ms), ("ragged-dot-none.1", 4 * ms, 3 * ms),
+                 ("%apex_gmm.7", 8 * ms, ms), ("apex_gmm", 66 * ms, 4 * ms),
                  (conv, 61 * ms, 4 * ms), ("no_such_op", 25 * ms, 5 * ms),
                  ("outside_any_program", 90 * ms, ms)]})
     got = tool.split(trace, texts)
@@ -59,5 +60,7 @@ def test_operations_are_booked_to_their_program_and_named_by_its_scopes():
     scope = next(s for s in got["decode"]["scopes"] if "short_conv" in s)
     assert got["decode"]["scopes"][scope] == 3.0
     assert got["decode"]["scopes"]["ragged-dot"] == 1.5
+    # the Pallas grouped product by its name, whatever scope it stands in
+    assert got["decode"]["scopes"]["apex_gmm"] == 2.5
     assert got["prefill8"] == {"runs": 1, "ms": 30.0,
                                "scopes": {"(no scope)": 5.0}}

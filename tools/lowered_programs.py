@@ -17,7 +17,9 @@ One file a program, StableHLO without locations:
   ``--aot`` also compiles them for the v5e and writes ``<name>.aot.json``:
   ``memory_analysis()``, the compiled program's instructions by kind, and
   the SHA-256 of its text without metadata: equal digests are one schedule,
-  one set of fusions under the same names.
+  one set of fusions under the same names. Each serving program's line, and
+  its ``.aot.json``, says how many grouped products of the expert layers it
+  holds and what computes them (``apex_gmm``, ``ragged_dot``).
 - ``generate.<stack>``: ``models.generate.generate`` at ``llama.tiny()``.
 - ``grad.<stack>.<mesh>``: the gradient of ``llama.loss_fn`` at
   ``llama.tiny()``, unbound and under a two-device ``shard_map`` on the CPU.
@@ -217,17 +219,51 @@ def without_kernel_locations(text):
     return re.sub(r'(\\22body\\22: \\22|"body":")([A-Za-z0-9+/=]+)', digest, text)
 
 
+def grouped_products(text):
+    """How many grouped products of the dropless expert layers a lowered
+    program holds, by what computes them: the Pallas kernel `apex_gmm`
+    (`ops/grouped_matmul`) or XLA's `ragged_dot`. The choice is static a
+    program, so this is the count that says the kernel engages: three a scan
+    body of expert layers, none in a dense model's programs. A kernel stands
+    once in the function that holds it (`grouped_matmul._call` is jitted), so
+    an occurrence counts as often as its function is called."""
+    bodies = dict(re.findall(
+        r"func\.func \w+ @([\w.]+)\((.*?)(?=\n  func\.func |\Z)", text, re.S))
+    calls = {name: collections.Counter(re.findall(r"call @([\w.]+)\(", body))
+             for name, body in bodies.items()}
+
+    def times_run(name, seen=()):
+        if name == "main":
+            return 1
+        return sum(n[name] * times_run(caller, seen + (name,))
+                   for caller, n in calls.items()
+                   if n[name] and caller not in seen)
+
+    def count(pattern):
+        return sum(len(re.findall(pattern, body)) * times_run(name)
+                   for name, body in bodies.items())
+
+    return {"apex_gmm": count(r'kernel_name = "apex_gmm"'),
+            "ragged_dot": count(r"= \"?chlo\.ragged_dot\b")}
+
+
 def write(out, which, aot):
     out.mkdir(parents=True, exist_ok=True)
     sources = {"cpu": cpu_programs, "serving": serving_programs}
     for source in sources if which == "all" else (which,):
         for name, lowered in sources[source]():
-            (out / f"{name}.mlir").write_text(
-                without_kernel_locations(lowered.as_text()))
-            if aot and source == "serving":
-                (out / f"{name}.aot.json").write_text(
-                    json.dumps(aot_record(lowered), indent=1))
-            print("wrote", name, flush=True)
+            text = without_kernel_locations(lowered.as_text())
+            (out / f"{name}.mlir").write_text(text)
+            said = ""
+            if source == "serving":
+                products = grouped_products(text)
+                said = ": " + ", ".join(f"{n} {what}" for what, n
+                                        in products.items())
+                if aot:
+                    (out / f"{name}.aot.json").write_text(json.dumps(
+                        {**aot_record(lowered),
+                         "grouped_products": products}, indent=1))
+            print(f"wrote {name}{said}", flush=True)
 
 
 def ops(text):

@@ -7,11 +7,14 @@ only), then every device operation of the traced window is booked to the
 program it ran in (`_decode_step`, or a prefill bucket) and named by the
 `jax.named_scope` its instruction carries in that program's compiled text
 (`llama/attention`, `llama/short_conv`, `llama/head`, `moe/route`,
-`moe/experts`, `moe/shared`; `observability.profiling.hlo_scopes`). XLA's
-grouped products are custom calls and carry no scope: they are booked by
-their name, `ragged-dot`. Prints, a program kind, its runs, its mean device
-milliseconds and the milliseconds of each scope in a run; the same as one
-JSON object to `--out`. PERF.md's section 5 is written from this.
+`moe/experts`, `moe/shared`; `observability.profiling.hlo_scopes`). The
+grouped products of the expert layers are booked by their name whatever scope
+they stand in: `apex_gmm`, the Pallas kernel (`ops/grouped_matmul`, since PR
+36), and `ragged-dot`, XLA's custom call, which carries no scope (a program
+from before PR 36, or one that took the fallback). Prints, a program kind, its
+runs, its mean device milliseconds and the milliseconds of each scope in a
+run; the same as one JSON object to `--out`. PERF.md's section 5 is written
+from this.
 """
 
 import argparse
@@ -23,6 +26,9 @@ import re
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# device operations booked by name, not by scope
+GROUPED_PRODUCTS = ("apex_gmm", "ragged-dot")
 
 
 def programs_text(scheduler):
@@ -79,7 +85,8 @@ def split(trace, texts):
             for at, name, took in ops:
                 if start <= at < start + dur:
                     stem = name.lstrip("%")
-                    scope = "ragged-dot" if "ragged-dot" in stem else (
+                    scope = next(
+                        (p for p in GROUPED_PRODUCTS if p in stem),
                         scopes[kind].get(stem) or "(no scope)")
                     row["scopes"][scope] += took / 1e6
         break                                   # the first device that ran
