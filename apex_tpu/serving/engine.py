@@ -44,7 +44,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from apex_tpu.observability import host_span
+from apex_tpu.observability import get_tracer, host_span
 from apex_tpu.resilience.loop import Preempted
 from apex_tpu.resilience.preemption import EXIT_PREEMPTED
 from apex_tpu.serving.kv_cache import derive_page_budget
@@ -184,6 +184,8 @@ class ServingEngine:
         self._next_rid = 0
         self._occ_sum = 0.0
         self._occ_steps = 0
+        # when the engine last ran out of work (ns), until the next submit
+        self._empty_since: Optional[int] = None
         self._config = {
             "page_size": page_size, "max_batch": max_batch,
             "num_pages": num_pages, "max_prompt_len": max_prompt_len,
@@ -213,6 +215,10 @@ class ServingEngine:
                       submit_s=time.monotonic())
         self.scheduler.submit(req)
         self.metrics.submitted()
+        if self._empty_since is not None:
+            get_tracer().record("serving/empty", self._empty_since,
+                                time.monotonic_ns())
+            self._empty_since = None
         return rid
 
     # ------------------------------------------------------------ loop
@@ -223,7 +229,10 @@ class ServingEngine:
         finished this iteration: by their own prefill, or by the tokens
         that landed. In the span ring it is one ``serving/step`` whose
         children say what it did: a ``serving/admit`` per admission, a
-        ``serving/decode`` if it dispatched a step."""
+        ``serving/decode`` if it dispatched a step. The iteration that
+        leaves nothing queued, running or unlanded opens an interval with
+        nothing to run, which the next :meth:`submit` closes: one detached
+        ``serving/empty`` record, stamped at those two moments only."""
         with host_span("serving/step"):
             self._poll_preemption()
             admitted, finished = self.scheduler.try_admit()
@@ -241,6 +250,8 @@ class ServingEngine:
                                  sched.rows_past_eos - before[1])
             for req in finished:
                 self._finish(req)
+            if self._empty_since is None and not self.pending:
+                self._empty_since = time.monotonic_ns()
             self.iteration += 1
             return finished
 

@@ -40,6 +40,7 @@ not padded).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
@@ -211,6 +212,21 @@ class PageAllocator:
         return sorted(p for pages in self._owned.values() for p in pages)
 
 
+# Each serving program's dispatches so far in this process, by the name its
+# module carries in a device trace: a span that dispatches one records the
+# count before its call as ``program_seq``, so a reader pairs the k-th such
+# program of a trace with the k-th dispatch by order, not by nearest time
+DISPATCHES: "collections.Counter[str]" = collections.Counter()
+
+
+def count_dispatch(program: str, n: int = 1) -> int:
+    """Count ``n`` dispatches of ``program`` (:data:`DISPATCHES`) and return
+    the ordinal of the first: the ``program_seq`` of the span queuing it."""
+    seq = DISPATCHES[program]
+    DISPATCHES[program] = seq + n
+    return seq
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _serving_write_pages(buf, idx, payload):
     """``buf[:, idx] = payload`` on the donated buffer, so in place: the
@@ -305,6 +321,7 @@ class PagedKVCache:
         idx = jnp.asarray(pages, jnp.int32)
         self.k_pages = _serving_write_pages(self.k_pages, idx, ks)
         self.v_pages = _serving_write_pages(self.v_pages, idx, vs)
+        count_dispatch("_serving_write_pages", 2)
 
     def gather_pages(self, pages: List[int]):
         """Fetch ``pages`` to host as ``(k, v)`` numpy arrays
@@ -325,6 +342,7 @@ class PagedKVCache:
         idx = jnp.asarray(pages, jnp.int32)
         self.k_pages = _serving_write_pages(self.k_pages, idx, k)
         self.v_pages = _serving_write_pages(self.v_pages, idx, v)
+        count_dispatch("_serving_write_pages", 2)
 
     # -------------------------------------------------------- conv state
 
@@ -339,6 +357,7 @@ class PagedKVCache:
                              f"kept, hidden)")
         self.conv_state = _serving_write_state(
             self.conv_state, np.int32(slot), jnp.asarray(state))
+        count_dispatch("_serving_write_state")
 
     def gather_state(self, slot: int):
         """Batch slot ``slot``'s conv state to the host, as

@@ -85,7 +85,12 @@ import numpy as np
 from apex_tpu.models import generate as _gen
 from apex_tpu.models import llama as _llama
 from apex_tpu.observability import get_tracer, host_span
-from apex_tpu.serving.kv_cache import PagedKVCache, state_hbm_bytes
+from apex_tpu.serving.kv_cache import (
+    DISPATCHES,
+    PagedKVCache,
+    count_dispatch,
+    state_hbm_bytes,
+)
 
 __all__ = [
     "ContinuousBatchScheduler",
@@ -671,7 +676,9 @@ class ContinuousBatchScheduler:
                 get_tracer().record("serving/queue_wait",
                                     _ns(req.submit_s), _ns(req.admit_s),
                                     rid=req.rid)
-            with host_span("serving/prefill_dispatch", rid=req.rid):
+            with host_span("serving/prefill_dispatch", rid=req.rid,
+                           bucket=s_pad, program_seq=count_dispatch(
+                               f"_serving_prefill_s{s_pad}")):
                 pages = self.cache.alloc.alloc(self.pages_needed(req),
                                                req.rid)
                 prompt = np.zeros((1, s_pad), np.int32)
@@ -681,8 +688,10 @@ class ContinuousBatchScheduler:
                     np.int32(p))
             self.prefill_count += 1
             n_prompt = s_pad // self.page_size
+            # the cache counts its two page writes, K's and then V's
             with host_span("serving/write_prompt", rid=req.rid,
-                           pages=n_prompt, cache_layers=self.cache.layers):
+                           pages=n_prompt, cache_layers=self.cache.layers,
+                           program_seq=DISPATCHES["_serving_write_pages"]):
                 self.cache.write_prompt(pages[:n_prompt], ks, vs)
             with host_span("serving/first_token_fetch", rid=req.rid):
                 first = np.asarray(first)
@@ -706,7 +715,8 @@ class ContinuousBatchScheduler:
         if state is not None:
             with host_span("serving/write_state", rid=req.rid,
                            conv_layers=self.cfg.conv_layers,
-                           bytes=self._row_state_bytes):
+                           bytes=self._row_state_bytes,
+                           program_seq=DISPATCHES["_serving_write_state"]):
                 self.cache.write_state(slot, state)
         self.slots[slot] = req
         req.state = "active"
@@ -746,7 +756,10 @@ class ContinuousBatchScheduler:
         record**, the step before's (zeros when nothing was unlanded):
         ``rows_past_eos``, the rows of the dispatched step that the landed
         tokens show to be past their EOS, and a dropless expert model's
-        :data:`EXPERT_COUNTS`."""
+        :data:`EXPERT_COUNTS`. Its ``serving/decode_upload`` carries
+        ``program_seq``, the step's ordinal among the process's dispatches
+        of ``_decode_step``, as the prefill's, the page writes' and the
+        state write's spans carry theirs."""
         if not self._active.any():
             return self.land()
         # pages_gathered: what the step reads in every cache layer
@@ -773,7 +786,8 @@ class ContinuousBatchScheduler:
                        pages_live=live,
                        pages_gathered=pages_read(live, self._tables.size),
                        in_flight=in_flight, **fields):
-            with host_span("serving/decode_upload"):
+            with host_span("serving/decode_upload",
+                           program_seq=count_dispatch("_decode_step")):
                 # copies: the mirrors change while the step is queued, and
                 # a transfer may read its host array after the call returns
                 mirrors = (jnp.asarray(a.copy()) for a in (

@@ -15,7 +15,7 @@ import pytest
 from apex_tpu import observability as obs
 from apex_tpu.models import llama
 from apex_tpu.observability import SpanTracer, set_tracer
-from apex_tpu.serving import ServingEngine, scheduler
+from apex_tpu.serving import ServingEngine, kv_cache, scheduler
 
 PAGE = 8
 JOBS = ((3, 4), (8, 7), (11, 4), (5, 7), (8, 4), (20, 1))
@@ -127,8 +127,9 @@ def test_every_request_has_its_spans_under_one_rid(served):
         assert admit.args["prompt_tokens"] == p
         assert admit.args["bucket"] == math.ceil(p / PAGE) * PAGE
         assert 0 <= admit.args["rows"] < 3
-        assert mine["serving/write_prompt"].args == {
-            "pages": math.ceil(p / PAGE), "cache_layers": 2}
+        writes = mine["serving/write_prompt"].args
+        assert writes == {"pages": math.ceil(p / PAGE), "cache_layers": 2,
+                          "program_seq": writes["program_seq"]}
         assert (admit.args["layer_passes"], admit.args["cache_layers"]) \
             == (2, 2)
     # the request that finished at its first token never decoded
@@ -239,3 +240,86 @@ def test_every_decode_record_carries_every_field(kind, tracer):
         assert (got[0] == 0).all() and (got[1:] == own[:-1]).all()
         assert (got.sum(axis=0) == own.sum(axis=0) - own[-1]).all()
         assert own.sum() > 0
+
+
+def test_each_dispatching_span_says_which_program_it_queued(model, tracer):
+    """`program_seq` is the ordinal of the program a span dispatched among
+    the process's dispatches of that program: one counter a kind (a bucket's
+    prefill, the decode step, the page writes, two a prompt), counted
+    across admissions and decode steps alike, so a device trace's k-th
+    program of a kind pairs with the span of ordinal k + a shift."""
+    params, cfg = model
+    before = dict(kv_cache.DISPATCHES)
+    engine = ServingEngine(params, cfg, page_size=PAGE, max_batch=3,
+                           num_pages=32, max_prompt_len=24, max_new_cap=16,
+                           registry=obs.MetricRegistry())
+    rng = np.random.default_rng(3)
+    for p, max_new in JOBS + JOBS:
+        engine.submit(rng.integers(0, cfg.vocab_size, size=p).astype(
+            np.int32), max_new)
+    engine.run()
+    spans = sorted(tracer.completed(), key=lambda s: s.start_ns)
+    after = kv_cache.DISPATCHES
+
+    def seqs(name):
+        return [s.args["program_seq"] for s in spans if s.name == name]
+
+    uploads = seqs("serving/decode_upload")
+    assert len(uploads) == engine.scheduler.decode_steps
+    assert uploads == list(range(before.get("_decode_step", 0),
+                                 after["_decode_step"]))
+    writes = seqs("serving/write_prompt")
+    assert writes == list(range(before.get("_serving_write_pages", 0),
+                                after["_serving_write_pages"], 2))
+    admits = [s for s in spans if s.name == "serving/admit"]
+    assert len(writes) == len(admits) == 2 * len(JOBS)
+    dispatches = [s for s in spans if s.name == "serving/prefill_dispatch"]
+    by_id = {s.id: s for s in admits}
+    for bucket in {s.args["bucket"] for s in dispatches}:
+        mine = [s.args["program_seq"] for s in dispatches
+                if s.args["bucket"] == bucket]
+        program = f"_serving_prefill_s{bucket}"
+        assert mine == list(range(before.get(program, 0), after[program]))
+    assert all(by_id[s.parent].args["bucket"] == s.args["bucket"]
+               for s in dispatches)
+
+
+@pytest.mark.parametrize("ends_by", ["length", "eos"])
+def test_an_engine_that_runs_dry_records_the_time_it_had_nothing_to_run(
+        model, tracer, ends_by):
+    """One detached `serving/empty` a dry spell: from the iteration that left
+    nothing queued, running or unlanded to the next submit. A row that ends
+    by its EOS leaves a step in flight that the next iteration lands with
+    nothing finished: the spell begins there."""
+    params, cfg = model
+    prompt = np.arange(5, dtype=np.int32) % cfg.vocab_size
+
+    def engine_for(eos_id=None):
+        return ServingEngine(params, cfg, page_size=PAGE, max_batch=3,
+                             num_pages=32, max_prompt_len=24, max_new_cap=16,
+                             eos_id=eos_id, registry=obs.MetricRegistry())
+
+    eos = None
+    if ends_by == "eos":
+        probe = engine_for()
+        rid = probe.submit(prompt, 8)
+        tokens = probe.run()[rid]["tokens"]
+        eos = next(t for i, t in enumerate(tokens) if i >= 2
+                   and t not in tokens[:i])
+    engine = engine_for(eos)
+    engine.submit(prompt, 8)
+    mark = tracer.mark()
+    engine.run()
+    if ends_by == "eos":
+        assert engine.scheduler.rows_past_eos == 1
+    last = by_name(tracer.completed(mark), "serving/step")[-1]
+    assert not by_name(tracer.completed(), "serving/empty")
+    engine.submit(prompt, 3)
+    (empty,) = by_name(tracer.completed(), "serving/empty")
+    req = engine.scheduler.queue[-1]
+    assert empty.detached and empty.args == {}
+    assert last.start_ns <= empty.start_ns <= last.end_ns
+    assert int(req.submit_s * 1e9) <= empty.end_ns
+    engine.run()
+    # no second spell is closed until something is submitted again
+    assert len(by_name(tracer.completed(), "serving/empty")) == 1
