@@ -62,14 +62,15 @@ def tp_size(tp_axis) -> int:
     return jax.lax.axis_size(tp_axis) if _axis_bound(tp_axis) else 1
 
 
-def packed_qkv_attention(x, lp, num_heads, head_dim, softmax_fn, tp_axis):
+def packed_qkv_attention(x, lp, num_heads, head_dim, attend, tp_axis):
     """Megatron packed-qkv attention shared by the gpt2/bert families.
 
     ``lp`` carries wqkv [h, 3, h] / bqkv [3, h] / wo / bo; sharding the LAST
     dim of wqkv with P(..., 'tp') gives each rank its heads of all of q, k
     and v, so the flattened local kernel is q|k|v blocks and a thirds-split
-    of the local gemm output is exact. ``softmax_fn(scores, scale) -> probs``
-    injects the mask flavour (causal for gpt2, padding for bert).
+    of the local gemm output is exact. ``attend(q, k, v) -> o``, all
+    ``[b, s, n, d]``, is the attention core (causal flash attention for
+    gpt2, a padding-masked softmax for bert), as ``llama.block`` takes it.
     """
     b, s, h = x.shape
     n = num_heads // tp_size(tp_axis)
@@ -78,14 +79,15 @@ def packed_qkv_attention(x, lp, num_heads, head_dim, softmax_fn, tp_axis):
     w = lp["wqkv"].reshape(h, -1)   # local [h, 3·h/tp]: q|k|v blocks
     qkv = column_parallel_linear(x, w, lp["bqkv"].reshape(-1),
                                  gather_output=False, axis_name=tp_axis)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, s, n, d)
-    k = k.reshape(b, s, n, d)
-    v = v.reshape(b, s, n, d)
+    # heads-major [3, b, n, s, d] straight from the product, handed on as
+    # [b, s, n, d] views: XLA then writes the product in the layout a
+    # heads-major core reads (the flash kernels' [b·n, s, d]); split as
+    # [b, s, n, d] first, each of q, k, v and their gradients took a
+    # transposing copy in every pass
+    q, k, v = (t.swapaxes(1, 2) for t in
+               qkv.reshape(b, s, 3, n, d).transpose(2, 0, 3, 1, 4))
 
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
-    probs = softmax_fn(scores, d ** -0.5).astype(v.dtype)
-    o = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, s, n * d)
+    o = attend(q, k, v).reshape(b, s, n * d)
     return row_parallel_linear(o, lp["wo"], lp["bo"], input_is_parallel=True,
                                axis_name=tp_axis)
 

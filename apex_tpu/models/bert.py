@@ -116,13 +116,15 @@ _ln = layer_norm
 
 
 def _attention(x, lp, cfg: BertConfig, pad_mask, tp_axis):
-    def padding_softmax(scores, scale):
+    def attend(q, k, v):
         # mask: True = masked-out key (ref scaled_masked_softmax semantics)
         mask = None if pad_mask is None else pad_mask[:, None, None, :]
-        return scaled_masked_softmax(scores, mask, scale)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+        probs = scaled_masked_softmax(scores, mask, q.shape[-1] ** -0.5)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
 
-    return packed_qkv_attention(x, lp, cfg.num_heads, cfg.head_dim,
-                                padding_softmax, tp_axis)
+    return packed_qkv_attention(x, lp, cfg.num_heads, cfg.head_dim, attend,
+                                tp_axis)
 
 
 def _mlp(x, lp, tp_axis):

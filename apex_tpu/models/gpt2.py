@@ -1,11 +1,13 @@
 """GPT-2 family — Megatron-style TP transformer with learned positions.
 
 Corresponds to the reference's GPT-2 345M benchmark config (Apex transformer
-primitives assembled Megatron-LM-style: fused softmax + LayerNorm + TP linear
-layers — ref apex/transformer/tensor_parallel/layers.py,
-apex/transformer/functional/fused_softmax.py). Same functional conventions
-as :mod:`apex_tpu.models.llama`: stacked [L, ...] layer params under
-``lax.scan``, collectives no-op when the tp axis is unbound.
+primitives assembled Megatron-LM-style: LayerNorm + TP linear layers — ref
+apex/transformer/tensor_parallel/layers.py — with causal self-attention
+through the flash kernels, ref apex/contrib/fmha, so no [s, s] score square
+reaches HBM in the forward, its recomputation or the backward). Same
+functional conventions as :mod:`apex_tpu.models.llama`: stacked [L, ...]
+layer params under ``lax.scan``, collectives no-op when the tp axis is
+unbound.
 """
 
 from __future__ import annotations
@@ -24,9 +26,7 @@ from apex_tpu.models._common import (
 )
 
 from apex_tpu.observability import scope
-from apex_tpu.transformer.functional.fused_softmax import (
-    scaled_upper_triang_masked_softmax,
-)
+from apex_tpu.ops.flash_attention import flash_attention
 from apex_tpu.transformer.tensor_parallel.mappings import (
     _axis_bound,
 )
@@ -113,16 +113,13 @@ def param_specs(cfg: GPT2Config, tp_axis: str = "tp"):
 _ln = layer_norm
 
 
-def _causal_softmax(scores, scale):
-    b, n, s, sk = scores.shape
-    return scaled_upper_triang_masked_softmax(
-        scores.reshape(b * n, s, sk), None, scale
-    ).reshape(b, n, s, sk)
+def _causal_attend(q, k, v):
+    return flash_attention(q, k, v, causal=True, scale=q.shape[-1] ** -0.5)
 
 
 def _attention(x, lp, cfg: GPT2Config, tp_axis):
     return packed_qkv_attention(x, lp, cfg.num_heads, cfg.head_dim,
-                                _causal_softmax, tp_axis)
+                                _causal_attend, tp_axis)
 
 
 def _mlp(x, lp, tp_axis):

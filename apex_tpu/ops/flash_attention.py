@@ -5,7 +5,11 @@ Design (TPU-first, not a CUDA port):
 - grid = (batch*heads, q_blocks, k_blocks), k innermost so the online
   softmax state (m, l, acc) lives in VMEM scratch across the k sweep.
 - one q tile is [BLOCK_Q, d] in VMEM; each step streams one [BLOCK_K, d]
-  k/v tile through the MXU (q @ k^T then p @ v), fp32 accumulation.
+  k/v tile through the MXU (q @ k^T then p @ v). Every product takes its
+  operands in the inputs' dtype and accumulates in fp32 (:func:`_mxu`):
+  bf16 inputs feed the MXU bf16, as XLA's own bf16 dots do, with p and ds
+  cast to the dtype of the operand they meet; the scale, the running max,
+  the sum, lse, delta and the accumulators stay fp32.
 - causal masking is positional (iota compare) — no mask tensor ever
   materializes in HBM (the reference's kernels read a cu_seqlens array;
   fixed-shape batched input is the TPU-friendly layout).
@@ -66,6 +70,20 @@ def _keep_mask(seed, bh, q_pos, k_pos, p_drop):
     return x31 > jnp.int32(min(int(p_drop * 2147483648.0), 2147483647))
 
 
+def _mxu(a, b, dims):
+    """``dot_general(a, b, dims)`` with both operands in their common
+    dtype and fp32 accumulation: bf16 operands go to the MXU as they are,
+    fp32 ones stay fp32."""
+    dt = jnp.promote_types(a.dtype, b.dtype)
+    return jax.lax.dot_general(a.astype(dt), b.astype(dt), (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))   # a @ b^T
+_NN = ((1,), (0,))   # a @ b
+_TN = ((0,), (0,))   # a^T @ b
+
+
 def _fwd_kernel(causal, scale, block_q, block_k, sq, sk, varlen, p_drop,
                 window, q_ref, k_ref, v_ref, *refs):
     refs = list(refs)
@@ -101,11 +119,7 @@ def _fwd_kernel(causal, scale, block_q, block_k, sq, sk, varlen, p_drop,
 
     @pl.when(run)
     def _step():
-        q = q_ref[0].astype(jnp.float32) * scale          # [bq, d]
-        k = k_ref[0].astype(jnp.float32)                  # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bq, bk]
+        s = scale * _mxu(q_ref[0], k_ref[0], _NT)         # [bq, bk]
         if causal:
             s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
         if window is not None:
@@ -124,22 +138,24 @@ def _fwd_kernel(causal, scale, block_q, block_k, sq, sk, varlen, p_drop,
         alpha = jnp.exp(m_prev - m_new)
         l_sc[:, 0] = l_sc[:, 0] * alpha + jnp.sum(p, axis=-1)
         # dropout applies to the NORMALIZED probs (torch semantics:
-        # dropout(softmax) @ v), so the numerator is masked+rescaled while
-        # the normalizer l accumulates the raw probs
+        # dropout(softmax) @ v), so the numerator is masked while the
+        # normalizer l accumulates the raw probs; the keep rescale
+        # 1/(1-p_drop) is a constant, applied in fp32 with l at the end
+        # (folded into a bf16 operand it would round every kept prob)
         pv = p
         if p_drop:
             keep = _keep_mask(seed_ref[0, 0], bh_idx.astype(jnp.uint32),
                               q_pos, k_pos, p_drop)
-            pv = jnp.where(keep, p / (1.0 - p_drop), 0.0)
-        acc_sc[:] = acc_sc[:] * alpha[:, None] + jax.lax.dot_general(
-            pv, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            pv = jnp.where(keep, p, 0.0)
+        acc_sc[:] = acc_sc[:] * alpha[:, None] + _mxu(
+            pv.astype(v_ref.dtype), v_ref[0], _NN)
         m_sc[:, 0] = m_new
 
     @pl.when(ki == nk - 1)
     def _finish():
         l = jnp.maximum(l_sc[:, 0], 1e-30)
-        o_ref[0] = (acc_sc[:] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_sc[:] / (l[:, None] * (1.0 - p_drop))
+                    ).astype(o_ref.dtype)
         # exact per-row logsumexp — the backward's p-block recompute key.
         # lse rides as [bh, sq, 1]: a (1, bq) block over [bh, sq] violates
         # Mosaic's last-two-dims rule (second-to-last must divide 8 or
@@ -253,12 +269,15 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None, p_drop=0.0,
     ``kv_lens`` [bh]: varlen key bound per row (finite fill — empty
     sequences stay NaN-free through autodiff). Dropout uses the SAME
     counter-based mask as the Pallas kernels, so both backends produce
-    bit-identical masks for a given seed."""
+    bit-identical masks for a given seed. Both products round where the
+    kernels' do (:func:`_mxu`): operands in the inputs' dtype, p cast to
+    v's, fp32 accumulation; float32 inputs stay float32 throughout."""
     bh, sq, d = q.shape
     bh_kv, sk, _ = k.shape
     rep = bh // bh_kv
-    qg = q.reshape(bh_kv, rep, sq, d).astype(jnp.float32)
-    s = jnp.einsum("grqd,gkd->grqk", qg, k.astype(jnp.float32)) * scale
+    qg = q.reshape(bh_kv, rep, sq, d)
+    s = jnp.einsum("grqd,gkd->grqk", qg, k,
+                   preferred_element_type=jnp.float32) * scale
     if causal:
         qpos = jnp.arange(sq)[:, None]
         kpos = jnp.arange(sk)[None, :]
@@ -280,7 +299,8 @@ def _reference_attention(q, k, v, causal, scale, kv_lens=None, p_drop=0.0,
             jnp.arange(sq, dtype=jnp.uint32)[None, None, :, None],
             jnp.arange(sk, dtype=jnp.uint32)[None, None, None, :], p_drop)
         p = jnp.where(keep, p / (1.0 - p_drop), 0.0)
-    o = jnp.einsum("grqk,gkd->grqd", p, v.astype(jnp.float32))
+    o = jnp.einsum("grqk,gkd->grqd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
     return o.reshape(bh, sq, d).astype(q.dtype)
 
 
@@ -316,13 +336,8 @@ def _bwd_dq_kernel(causal, scale, bq, bk, sk, varlen, p_drop,
 
     @pl.when(run)
     def _step():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bk]
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        s = scale * _mxu(q, k, _NT)                        # [bq, bk]
         p = jnp.exp(s - lse_ref[0])
         if causal or varlen or p_drop or sk % bk:
             q_pos = qi * bq + jax.lax.broadcasted_iota(
@@ -335,9 +350,7 @@ def _bwd_dq_kernel(causal, scale, bq, bk, sk, varlen, p_drop,
             p = jnp.where(k_pos < sk, p, 0.0)
         if varlen:
             p = jnp.where(k_pos < kvlen_ref[0, 0, 0], p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bk]
+        dp = _mxu(do, v, _NT)                              # [bq, bk]
         if p_drop:
             # o = (p∘m)@v with m = keep/(1-pd): dL/dp = m∘(do@vᵀ), and the
             # softmax-backward row term stays D = rowsum(do∘o) because
@@ -346,9 +359,7 @@ def _bwd_dq_kernel(causal, scale, bq, bk, sk, varlen, p_drop,
                               q_pos, k_pos, p_drop)
             dp = jnp.where(keep, dp / (1.0 - p_drop), 0.0)
         ds = p * (dp - dl_ref[0]) * scale
-        acc_sc[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_sc[:] += _mxu(ds.astype(k.dtype), k, _NN)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -380,13 +391,8 @@ def _bwd_dkv_kernel(causal, scale, bq, bk, sk, rep, nq, varlen, p_drop,
 
     @pl.when(run)
     def _step():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bq, bk]
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        s = scale * _mxu(q, k, _NT)                        # [bq, bk]
         p = jnp.exp(s - lse_ref[0])
         if causal or varlen or p_drop or sk % bk:
             q_pos = qi * bq + jax.lax.broadcasted_iota(
@@ -403,26 +409,20 @@ def _bwd_dkv_kernel(causal, scale, bq, bk, sk, rep, nq, varlen, p_drop,
             # same counter-based mask as the forward: bh = g*rep + r here
             bh_idx = (g_idx * rep + r).astype(jnp.uint32)
             keep = _keep_mask(seed_ref[0, 0], bh_idx, q_pos, k_pos, p_drop)
-            pm = jnp.where(keep, p / (1.0 - p_drop), 0.0)
+            pm = jnp.where(keep, p, 0.0)   # 1/(1-p_drop) at the end
         else:
             pm = p
-        dv_sc[:] += jax.lax.dot_general(
-            pm, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dv_sc[:] += _mxu(pm.astype(do.dtype), do, _TN)    # [bk, d]
+        dp = _mxu(do, v, _NT)
         if p_drop:
             dp = jnp.where(keep, dp / (1.0 - p_drop), 0.0)
         ds = p * (dp - dl_ref[0]) * scale
-        dk_sc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [bk, d]
+        dk_sc[:] += _mxu(ds.astype(q.dtype), q, _TN)      # [bk, d]
 
     @pl.when((r == rep - 1) & (qi == nq - 1))
     def _finish():
         dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
+        dv_ref[0] = (dv_sc[:] / (1.0 - p_drop)).astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",

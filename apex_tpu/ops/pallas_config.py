@@ -27,6 +27,13 @@ _MODE = "auto"  # auto | off | on | interpret
 # 512/256 were hardcoded at flash_attention.py:389,405).
 _FLASH_BLOCKS = {"fwd": None, "bwd": None}
 _FLASH_DEFAULTS = {"fwd": (512, 512), "bwd": (256, 256)}
+# Heads of 64 or fewer: a grid step's products are half as deep, so the
+# blocks grow until a step's work stands well above its fixed cost (a v5e at
+# GPT-2 345M's causal [256, 1024, 64], tools/flash_sweep.py --tiles: forward
+# 2.50 ms at 512 x 512, 1.62 at 1024 x 1024; backward 8.49 ms at 256 x 256,
+# 5.11 at 512 x 512, 4.32 at 1024 x 1024, though the whole square is then
+# computed and masked where causal blocks of 512 skip a quarter of it).
+_FLASH_NARROW = {"fwd": (1024, 1024), "bwd": (1024, 1024)}
 # Below this many rows a flash block is not shrunk further to divide the
 # sequence; the sequence is padded instead (flash_attention._tile). One
 # lane-width: every block the kernels then see is a whole number of
@@ -392,7 +399,8 @@ def flash_blocks(kind: str, sq: int, sk: int, d: int) -> tuple:
     sweep-time pin rides the same consult); otherwise a per-shape pick
     that keeps the kernel's VMEM residency (q/k/v/acc tiles + the
     [bq, bk] fp32 score block) around ~4 MiB so double-buffered
-    pipelining still fits a ~16 MiB VMEM."""
+    pipelining still fits a ~16 MiB VMEM; heads of 64 or fewer take the
+    larger blocks of ``_FLASH_NARROW``."""
     override = _FLASH_BLOCKS.get(kind)
     if override is not None:
         return override
@@ -401,7 +409,7 @@ def flash_blocks(kind: str, sq: int, sk: int, d: int) -> tuple:
     tuned = tuning_geometry.flash_tiles(kind, sq, sk, d)
     if tuned is not None:
         return tuned
-    bq, bk = _FLASH_DEFAULTS[kind]
+    bq, bk = (_FLASH_NARROW if d <= 64 else _FLASH_DEFAULTS)[kind]
     # score block bq*bk*4B dominates at d=128; wide heads add bq*d + 2*bk*d
     # tile bytes, so shrink until the whole residency fits ~2 MiB
     while d >= 256 and (bq * bk + (bq + 2 * bk) * d) * 4 >= 2 ** 21 \

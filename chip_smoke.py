@@ -107,10 +107,12 @@ def _expect_mosaic(text: str, *kernels: str) -> None:
 # ------------------------------------------------------------------ kernels
 
 # Shapes the two models below hand the kernels (flash: B, S, q heads, kv
-# heads, head dim — GQA 16/8 x 128 is flagship_0p9b; LayerNorm 8192 x 1024
-# and the [128, 1024, 1024] causal softmax are GPT-2 345M at B=8).
+# heads, head dim — GQA 16/8 x 128 is flagship_0p9b, 16 x 64 over 1,024
+# is GPT-2 345M's training attention; LayerNorm 8192 x 1024 and the
+# [128, 1024, 1024] causal softmax are GPT-2 345M at B=8).
 KERNEL_SHAPES = dict(
-    flash=(2, 2048, 16, 8, 128), prefill_len=520,
+    flash=(2, 2048, 16, 8, 128), flash_gpt2=(4, 1024, 16, 16, 64),
+    prefill_len=520,
     norm_rows=8192, ln_hidden=1024, rms_hidden=2048,
     causal_softmax=(128, 1024), masked_softmax=(64, 512),
     adam_n=1_000_000, fp8=(2048, 2048), gmm=(4096, 2048, 1536, 64))
@@ -202,6 +204,16 @@ def _kernel_checks(shapes):
         got, want = _pallas_vs_jnp(grad3(causal), *_qkv(flash, 1))
         for name, g, w in zip("qkv", got, want):
             _close_flash_bwd(g, w, f"flash d{name}")
+
+    def flash_gpt2():
+        # forward and backward at GPT-2's head width: bf16 to the MXU
+        shape = shapes["flash_gpt2"]
+        scaled = functools.partial(flash_attention, causal=True,
+                                   scale=shape[-1] ** -0.5)
+        _close(*_pallas_vs_jnp(scaled, *_qkv(shape, 5)), what="flash gpt2")
+        got, want = _pallas_vs_jnp(grad3(scaled), *_qkv(shape, 6))
+        for name, g, w in zip("qkv", got, want):
+            _close_flash_bwd(g, w, f"flash gpt2 d{name}")
 
     def flash_prefill():
         # a prompt bucket that is no multiple of any block: the kernel
@@ -356,6 +368,7 @@ def _kernel_checks(shapes):
 
     return [("flash_fwd_causal_gqa", flash_fwd),
             ("flash_bwd_causal_gqa", flash_bwd),
+            ("flash_fwd_bwd_gpt2", flash_gpt2),
             ("flash_fwd_prefill_len", flash_prefill),
             ("flash_varlen", flash_varlen),
             ("flash_dropout_fwd_bwd", flash_dropout),
@@ -478,7 +491,8 @@ def phase_train(cfg=None, batch=8, steps=8) -> dict:
     compiled = step.lower(*state, data).compile()
     say(f"  train step compiled in {time.perf_counter() - t0:.1f}s")
     _expect_mosaic(compiled.as_text(), "apex_ln_fwd", "apex_ln_bwd",
-                   "apex_causal_softmax", "apex_softmax_bwd")
+                   "apex_flash_fwd", "apex_flash_bwd_dq",
+                   "apex_flash_bwd_dkv")
     state, losses = _run_steps(compiled, state, data, steps)
     # ln(vocab) plus half the init's logit variance (~1)
     ln_v = float(np.log(cfg.vocab_size))

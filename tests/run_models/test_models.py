@@ -156,6 +156,45 @@ class TestGPT2:
                                    np.asarray(l2[0, :10]), atol=1e-5)
         assert not np.allclose(np.asarray(l1[0, 10:]), np.asarray(l2[0, 10:]))
 
+    @pytest.mark.parametrize("kernels", ["off", "interpret"])
+    def test_flash_attention_matches_the_materialized_causal_softmax(
+            self, monkeypatch, kernels):
+        """The loss and every gradient through the flash core (its jnp
+        path, and the Pallas kernels' bodies) against the composition the
+        model ran before: scores, the causal fused softmax, PV."""
+        from apex_tpu.ops import pallas_config
+        from apex_tpu.transformer.functional.fused_softmax import (
+            scaled_upper_triang_masked_softmax,
+        )
+
+        def materialized(q, k, v):
+            b, s, n, d = q.shape
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+            probs = scaled_upper_triang_masked_softmax(
+                scores.reshape(b * n, s, s), None, d ** -0.5)
+            return jnp.einsum("bhqk,bkhd->bqhd",
+                              probs.reshape(b, n, s, s).astype(v.dtype), v)
+
+        cfg = gpt2.tiny()
+        params = gpt2.init_params(jax.random.PRNGKey(0), cfg)
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 48), 0,
+                                    cfg.vocab_size)
+        grad = jax.value_and_grad(
+            lambda p: gpt2.loss_fn(p, (tokens, jnp.roll(tokens, -1, -1)),
+                                   cfg, tp_axis=None))
+        with pallas_config.force(kernels):
+            loss, grads = grad(params)
+        monkeypatch.setattr(gpt2, "_causal_attend", materialized)
+        with pallas_config.force("off"):
+            want, want_grads = grad(params)
+        np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+        for path, g in jax.tree_util.tree_leaves_with_path(grads):
+            w = functools.reduce(lambda t, k: t[k.key], path, want_grads)
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(w), rtol=2e-4,
+                atol=2e-5 * float(np.abs(np.asarray(w)).max()),
+                err_msg=jax.tree_util.keystr(path))
+
 
 # -------------------------------------------------------------------- bert
 
@@ -191,6 +230,33 @@ class TestBert:
         )(params, (tokens, tokens, loss_mask))
         np.testing.assert_allclose(float(loss), float(ref), atol=2e-4,
                                    rtol=2e-4)
+
+    def test_attention_is_the_padding_masked_softmax_composition(self):
+        """BERT's attention core is what it was: scores, the padding-masked
+        fused softmax, PV, with q, k, v and the output projection spelled
+        out here."""
+        from apex_tpu.transformer.functional.fused_softmax import (
+            scaled_masked_softmax,
+        )
+
+        cfg = bert.tiny()
+        params = bert.init_params(jax.random.PRNGKey(0), cfg)
+        lp = jax.tree_util.tree_map(lambda a: a[0], params["layers"])
+        b, s, h, n = 2, 16, cfg.hidden_size, cfg.num_heads
+        d = h // n
+        x = jax.random.normal(jax.random.PRNGKey(2), (b, s, h), cfg.dtype)
+        mask = jnp.zeros((b, s), bool).at[1, 11:].set(True)
+        got = bert._attention(x, lp, cfg, mask, None)
+
+        qkv = x @ lp["wqkv"].reshape(h, 3 * h) + lp["bqkv"].reshape(-1)
+        q, k, v = (t.reshape(b, s, n, d) for t in jnp.split(qkv, 3, -1))
+        probs = scaled_masked_softmax(
+            jnp.einsum("bqhd,bkhd->bhqk", q, k), mask[:, None, None, :],
+            d ** -0.5)
+        o = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
+        want = o.reshape(b, s, h) @ lp["wo"] + lp["bo"]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
 
     def test_bidirectional(self):
         """Unlike GPT-2, early positions DO see later-token changes."""

@@ -19,7 +19,7 @@ from apex_tpu.runtime import compile_cache
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 TINY_KERNELS = dict(
-    flash=(2, 64, 4, 2, 16), prefill_len=40,
+    flash=(2, 64, 4, 2, 16), flash_gpt2=(1, 64, 2, 2, 64), prefill_len=40,
     norm_rows=64, ln_hidden=128, rms_hidden=128,
     causal_softmax=(4, 32), masked_softmax=(8, 32),
     adam_n=5000, fp8=(64, 128), gmm=(80, 32, 128, 5))
@@ -35,7 +35,7 @@ def interpret():
 
 
 def test_phase_kernels_tiny(interpret):
-    assert chip_smoke.phase_kernels(TINY_KERNELS) == {"checks": 14}
+    assert chip_smoke.phase_kernels(TINY_KERNELS) == {"checks": 15}
 
 
 def test_phase_train_then_mesh_tiny():
